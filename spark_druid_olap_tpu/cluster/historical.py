@@ -125,6 +125,9 @@ def main(argv: Optional[list] = None) -> int:
         "(how the bench driver finds ephemeral ports)",
     )
     args = ap.parse_args(argv)
+    from ..utils import compile_cache
+
+    compile_cache.enable()
     node = HistoricalNode(
         args.node_id, args.storage_dir, host=args.host, port=args.port
     ).start()

@@ -326,7 +326,6 @@ class AdaptiveDomainMixin:
             strat == "dense"
             and g_compact <= SCATTER_CUTOVER
             and pallas_available()
-            and not self._pallas_broken
         ):
             strat = "pallas"
         return strat
@@ -337,10 +336,7 @@ class AdaptiveDomainMixin:
         from ..ops.groupby import partial_aggregate
         from ..ops.pallas_groupby import pallas_available
 
-        pallas_ok = not self._pallas_broken and pallas_available()
-        # pallas_ok participates in the key: after a Mosaic failure flips
-        # _pallas_broken, the rebuilt program must not reuse the cached one
-        # with Pallas strategies baked in
+        pallas_ok = pallas_available()
         key = _query_key(q, ds) + ("adaptive-presence", pallas_ok)
         from ..obs import prof
 
@@ -464,8 +460,7 @@ class AdaptiveDomainMixin:
             # the try below: an injected transient must decline this
             # dispatch (caller falls through to sparse/dense, whose
             # sites feed the retry/breaker machinery) — not be misread
-            # as a Mosaic failure that memo-declines the query shape or
-            # pins _pallas_broken for the engine's lifetime.
+            # as a static failure that memo-declines the query shape.
             fire("device_dispatch")
             try:
                 counts = run_presence()
@@ -475,23 +470,10 @@ class AdaptiveDomainMixin:
                 # every later (unbudgeted) run of the same query
                 raise
             except Exception:
-                # mirror _call_segment_program: a Mosaic failure of a
-                # Pallas presence kernel downgrades to the XLA strategies
-                # once; anything else (or a second failure) memo-declines
-                # so the broken pass is not re-dispatched every execution
-                from ..ops.pallas_groupby import pallas_available
-
-                if self._pallas_broken or not pallas_available():
-                    self._adaptive_declined.add(qkey)
-                    raise
-                self._pallas_broken = True
-                try:
-                    counts = run_presence()
-                except DeadlineExceeded:
-                    raise
-                except Exception:
-                    self._adaptive_declined.add(qkey)
-                    raise
+                # memo-decline so a pass that cannot run is not
+                # re-dispatched on every execution of this query shape
+                self._adaptive_declined.add(qkey)
+                raise
             kept = [
                 np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
                 for c in counts

@@ -245,13 +245,19 @@ def _member_init(lowering):
     import jax.numpy as jnp
 
     la, G = lowering.la, lowering.num_groups
-    z = (
-        jnp.zeros((G, len(la.sum_names)), jnp.float32),
-        jnp.zeros((G, len(la.min_names)), jnp.float32),
-        jnp.zeros((G, len(la.max_names)), jnp.float32),
-        jnp.asarray(False),
-    )
-    return z + z  # (t_s, t_mn, t_mx, t_live, b_s, b_mn, b_mx, b_live)
+
+    def zeros():
+        return (
+            jnp.zeros((G, len(la.sum_names)), jnp.float32),
+            jnp.zeros((G, len(la.min_names)), jnp.float32),
+            jnp.zeros((G, len(la.max_names)), jnp.float32),
+            jnp.asarray(False),
+        )
+
+    # two SEPARATE sets of buffers: the carry is donated on TPU/GPU, and
+    # one buffer appearing twice in a donated argument is refused there
+    # (`Attempt to donate the same buffer twice`)
+    return zeros() + zeros()  # (t_s, t_mn, t_mx, t_live, b_s, b_mn, b_mx, b_live)
 
 
 def _fold_block(carry_i, block_state, start_b, memb_b):

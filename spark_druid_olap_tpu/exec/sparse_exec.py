@@ -3,7 +3,7 @@
 The capacity-ladder dispatch/evict/fetch machinery for the high-cardinality
 GroupBy path — an engine-within-the-engine that round 2's review flagged for
 extraction (VERDICT r2 #9).  `Engine` mixes this in; every attribute it
-touches (`_query_fn_cache`, `_pallas_broken`, `_sparse_row_capacity`,
+touches (`_query_fn_cache`, `_sparse_row_capacity`,
 segment iteration, metrics) lives on the engine instance, so this is purely
 a file split: same methods, same behavior, pinned by the existing
 tests/test_sparse_groupby.py suite.
@@ -48,9 +48,7 @@ class SparseExecMixin:
         from ..ops.pallas_groupby import pallas_available
 
         auto_upgrade = (
-            self.strategy in ("auto", "dense")
-            and pallas_available()
-            and not self._pallas_broken
+            self.strategy in ("auto", "dense") and pallas_available()
         )
         return (
             lowering.num_groups > SCATTER_CUTOVER
@@ -83,14 +81,9 @@ class SparseExecMixin:
         # and at `slots` segments CPU scatter is cheap).  Past SPARSE_SLOTS
         # a non-scatter inner routes to the segmented-reduce-over-ranks
         # kernel inside sparse_partial_aggregate (the sort-agg tier).
-        inner = (
-            "pallas"
-            if not self._pallas_broken and pallas_available()
-            else "segment"
-        )
+        inner = "pallas" if pallas_available() else "segment"
         # structured key, NOT an f-string: interpolation collapses distinct
-        # identities (None vs "None") and the pallas-eviction scan matches
-        # on the rendered tuple (graftlint jit-cache/GL103)
+        # identities (None vs "None") (graftlint jit-cache/GL103)
         key = _query_key(q, ds) + ("sparse", inner, row_capacity, slots)
         from ..obs import prof
 
@@ -167,9 +160,7 @@ class SparseExecMixin:
             # it down exactly like the dense engine's — otherwise a
             # breaker half-open probe routed to a sparse-strategy query
             # succeeds and closes the breaker while the device is dead.
-            # Placed OUTSIDE resolve()'s Mosaic-downgrade retry so the
-            # injected transient declines this execution only and never
-            # pins _pallas_broken (same contract as engine.py's site).
+            # The injected transient declines this execution only.
             fire("device_dispatch")
             seg_fn = self._sparse_program(
                 q, ds, lowering, row_capacity=row_capacity, slots=slots
@@ -379,13 +370,7 @@ class SparseExecMixin:
 
         # phase 1: dispatch (async — no fetch).  Exceptions are deferred
         # into resolve() so batch callers see the same decline protocol as
-        # execution failures.  Record which inner kernel THIS dispatch used:
-        # in batch mode an earlier query's resolve may flip _pallas_broken
-        # between our dispatch and our resolve, and the downgrade retry must
-        # key on what we actually ran, not the current flag.
-        from ..ops.pallas_groupby import pallas_available
-
-        used_pallas_inner = not self._pallas_broken and pallas_available()
+        # execution failures.
         state = dispatch_exc = None
         try:
             state = dispatch(row_capacity=cap, slots=slots0)
@@ -416,35 +401,7 @@ class SparseExecMixin:
             except Exception:  # fault-ok: returns "error"; caller logs + falls back
                 state = None
                 evict()
-                # mirror _call_segment_program: a Mosaic failure of the
-                # Pallas inner kernel downgrades to the scatter inner, not
-                # to the whole-query scatter path
-                if not used_pallas_inner or not pallas_available():
-                    return None, "error"
-                we_broke_it = not self._pallas_broken
-                self._pallas_broken = True
-                try:
-                    # the failed attempt may already have learned the right
-                    # row-capacity / slot rungs; retry there, not at the
-                    # stale ones
-                    retry_cap = self._sparse_row_capacity.get(qkey, cap)
-                    retry_slots = self._sparse_slots.get(qkey, slots0)
-                    host, _ = fetch_slot_laddered(
-                        dispatch(row_capacity=retry_cap, slots=retry_slots),
-                        retry_cap,
-                        retry_slots,
-                    )
-                except DeadlineExceeded:
-                    if we_broke_it:
-                        self._pallas_broken = False
-                    raise  # a deadline is never a Pallas verdict
-                except Exception:  # fault-ok: returns "error"; caller logs + falls back
-                    # only unflag if WE set the flag — an earlier query may
-                    # have legitimately discovered the broken kernel
-                    if we_broke_it:
-                        self._pallas_broken = False
-                    evict()
-                    return None, "error"
+                return None, "error"
             if host is None:
                 # a partial drain stopped a ladder rerun mid-scope:
                 # decline (never error-counted) — the dense drain

@@ -195,8 +195,6 @@ class StreamExecutor:
         eng = self.engine
 
         prep = self._prep_fn(ds.time_column, chunk_rows)
-        build_mesh_run = None
-        dist_run = None
         if self.mesh is not None:
             # per-chunk SPMD program shared with DistributedEngine:
             # partials on each device's row shard (kernel routed by the
@@ -214,13 +212,10 @@ class StreamExecutor:
             if ds.time_column and ds.time_column in need:
                 col_keys.append("__time")
 
-            def build_mesh_run(strategy):
-                return dist._spmd_fn(
-                    lowering, chunk_rows // nd, ds, tuple(col_keys),
-                    strategy=strategy,
-                )
-
-            dist_run = build_mesh_run(strat)
+            dist_run = dist._spmd_fn(
+                lowering, chunk_rows // nd, ds, tuple(col_keys),
+                strategy=strat,
+            )
             run = lambda dev, base, nrows: dist_run(prep(dev, base, nrows))
         else:
             # prep (time reconstruction + validity) FUSED into the chunk
@@ -261,13 +256,7 @@ class StreamExecutor:
             with span(SPAN_STREAM_CHUNK, chunk=self.stats.chunks):
                 from ..obs import prof
 
-                try:
-                    s, mn, mx, sk = run(dev, base, nrows)
-                except Exception:  # fault-ok: _downgrade_pallas re-raises non-Pallas errors
-                    run = self._downgrade_pallas(
-                        q, ds, lowering, prep, build_mesh_run, strat
-                    )
-                    s, mn, mx, sk = run(dev, base, nrows)
+                s, mn, mx, sk = run(dev, base, nrows)
                 # sampled query: honest device split on the chunk span
                 # (obs/prof.py; a strict no-op at the default rate)
                 s = prof.dispatch_sync(s, t0)
@@ -323,11 +312,7 @@ class StreamExecutor:
             from ..ops.groupby import SCATTER_CUTOVER
             from ..ops.pallas_groupby import pallas_available
 
-            if (
-                G <= SCATTER_CUTOVER
-                and pallas_available()
-                and not eng._pallas_broken
-            ):
+            if G <= SCATTER_CUTOVER and pallas_available():
                 strat = "pallas"
         return strat
 
@@ -358,30 +343,6 @@ class StreamExecutor:
 
         eng._query_fn_cache[key] = fused
         return fused
-
-    def _downgrade_pallas(
-        self, q, ds, lowering, prep, build_mesh_run, strat
-    ):
-        """Mirror Engine._call_segment_program's Mosaic-failure downgrade
-        for the streaming program (local AND mesh): flag Pallas broken,
-        evict, rebuild on the XLA dense kernel — the same class — and let
-        the retry surface real errors."""
-        from ..ops.pallas_groupby import pallas_available
-
-        eng = self.engine
-        if eng._pallas_broken or not pallas_available() or strat != "pallas":
-            raise  # re-raise the active exception: not a Pallas downgrade
-        eng._pallas_broken = True
-        for k in [
-            k
-            for k in eng._query_fn_cache
-            if any("pallas" in str(p) for p in k[2:]) or "stream-fused" in k
-        ]:
-            eng._query_fn_cache.pop(k)
-        if build_mesh_run is not None:
-            fresh = build_mesh_run("dense")
-            return lambda dev, base, nrows: fresh(prep(dev, base, nrows))
-        return self._fused_local_fn(q, ds, lowering, prep, "dense")
 
     # -- chunk plumbing ------------------------------------------------------
 

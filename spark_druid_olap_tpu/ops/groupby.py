@@ -48,9 +48,7 @@ _LANE = 128
 # this constant bounds G for the dense strategy overall.
 DENSE_MAX_GROUPS = 1 << 17
 
-# ESTIMATED dense-vs-scatter crossover for a v5e-class chip (no committed
-# TPU artifact backs this yet — see BENCH_r*.json history; rounds 1-2 never
-# reached the hardware).  The estimate follows the cost-model formula
+# ESTIMATED dense-vs-scatter crossover for a v5e-class chip.  The estimate follows the cost-model formula
 # (G/128 <= 4 * scatter_cost_per_row); `plan/calibrate.py` replaces it with
 # a measured value the first time it runs on the real backend, and the
 # calibrated crossover is what the planner actually uses
@@ -216,9 +214,7 @@ def scatter_partial_aggregate(
     return sums, mins, maxs
 
 
-def resolve_strategy(
-    strategy: str, num_groups: int, pallas_ok: bool = True
-) -> str:
+def resolve_strategy(strategy: str, num_groups: int) -> str:
     """Single source of truth for 'auto' strategy resolution (shared by this
     dispatcher and Engine's program-cache keying)."""
     if strategy != "auto":
@@ -227,7 +223,7 @@ def resolve_strategy(
         return "segment"
     from .pallas_groupby import pallas_available
 
-    if pallas_ok and pallas_available():
+    if pallas_available():
         return "pallas"
     return "dense"
 
@@ -258,11 +254,12 @@ def partial_aggregate(
     if strategy == "pallas":
         from .pallas_groupby import pallas_available, pallas_partial_aggregate
 
-        interpret = not pallas_available()
+        # interpret mode is what CPU tests ask for by naming the strategy;
+        # on a TPU the kernel is always compiled
         return pallas_partial_aggregate(
             gid, mask, sum_values, minmax_values, minmax_masks,
             num_groups=num_groups, num_min=num_min, num_max=num_max,
-            interpret=interpret,
+            interpret=not pallas_available(),
         )
     if strategy in ("dense", "onehot"):
         br = block_rows or choose_block_rows(gid.shape[0], num_groups)

@@ -107,7 +107,6 @@ from .mesh import (
     SLICE_AXIS,
     make_mesh,
     row_axes,
-    shard_map_compat,
 )
 from .multihost import initialize as multihost_initialize, put_sharded
 
@@ -419,11 +418,12 @@ class DistributedEngine:
         gspec = P(GROUPS_AXIS) if ng > 1 else P()
         out_spec = (gspec, gspec, gspec, {a.name: gspec for a in sketches})
         run = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=(specs,),
                 out_specs=out_spec,
+                check_vma=False,
             )
         )
         self._spmd_cache[cache_key] = run
@@ -517,11 +517,12 @@ class DistributedEngine:
             {k: gspec for k in _SPARSE_FLAG_KEYS},
         )
         run = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=(specs,),
                 out_specs=out_spec,
+                check_vma=False,
             )
         )
         self._spmd_cache[cache_key] = run
@@ -578,11 +579,12 @@ class DistributedEngine:
 
         specs = {n: P(DATA_AXIS) for n in col_keys}
         run = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=(specs,),
                 out_specs=[P() for _ in lowering.dims],
+                check_vma=False,
             )
         )
         self._spmd_cache[cache_key] = run

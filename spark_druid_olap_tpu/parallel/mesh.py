@@ -95,26 +95,6 @@ def row_axes(mesh: Mesh) -> Tuple[str, ...]:
     return (DATA_AXIS,)
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across JAX versions: the new top-level API takes
-    `check_vma`; older releases (<=0.4.x, this container's 0.4.37) only
-    ship `jax.experimental.shard_map.shard_map` with the `check_rep`
-    spelling.  Every SPMD program builds through here so a JAX upgrade or
-    downgrade degrades to the available API instead of AttributeError-ing
-    the whole distributed path."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def row_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(DATA_AXIS))
 

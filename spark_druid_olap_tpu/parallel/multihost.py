@@ -82,6 +82,20 @@ def initialize(
             )
         ):
             return False
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            # a marker alone does not make a pod: a single TPU host that
+            # drives all its chips from one process carries TPU_WORKER_ID
+            # too (met on the four-chip v5e host).  This process already
+            # holds its backend, so no rendezvous can be formed any more:
+            # it is single-process.  A multi-host launcher calls this
+            # first thing, before any other JAX call.
+            log.info(
+                "cluster markers in the environment, but the XLA backend "
+                "is already up: staying single-process"
+            )
+            return False
     try:
         # CPU backend: cross-process collectives need the Gloo transport
         # ("Multiprocess computations aren't implemented on the CPU

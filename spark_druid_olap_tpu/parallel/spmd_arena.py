@@ -52,7 +52,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..utils.log import get_logger
-from .mesh import DATA_AXIS, SLICE_AXIS, row_axes, shard_map_compat
+from .mesh import DATA_AXIS, SLICE_AXIS, row_axes
 
 log = get_logger("parallel.spmd_arena")
 
@@ -248,8 +248,9 @@ def build_spmd_arena_program(
     out_specs = tuple((P(), P(), P(), P()) for _ in range(n))
     # graftlint: disable=jit-cache -- caller caches under a query key
     return jax.jit(
-        shard_map_compat(
-            shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+        jax.shard_map(
+            shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
     )
 
@@ -321,8 +322,9 @@ def build_spmd_chunk_program(mesh, lowerings, strategies, share=None):
     donate = {"donate_argnums": (0,)} if _donate_carry() else {}
     # graftlint: disable=jit-cache -- caller caches under a query key
     return jax.jit(
-        shard_map_compat(
-            shard_fn, mesh=mesh, in_specs=in_specs, out_specs=P(row_el)
+        jax.shard_map(
+            shard_fn, mesh=mesh, in_specs=in_specs, out_specs=P(row_el),
+            check_vma=False,
         ),
         **donate,
     )
@@ -345,7 +347,8 @@ def build_spmd_merge_program(mesh, lowerings, tree: str = "flat"):
     out_specs = tuple((P(), P(), P(), P()) for _ in range(n))
     # graftlint: disable=jit-cache -- caller caches under a query key
     return jax.jit(
-        shard_map_compat(
-            shard_fn, mesh=mesh, in_specs=(P(row_el),), out_specs=out_specs
+        jax.shard_map(
+            shard_fn, mesh=mesh, in_specs=(P(row_el),), out_specs=out_specs,
+            check_vma=False,
         )
     )

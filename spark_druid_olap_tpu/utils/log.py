@@ -11,7 +11,7 @@ etiquette), so output appears only when the application enables it:
     logging.basicConfig()
 
 Conventions: plan/rewrite decisions -> DEBUG; per-query completion with the
-QueryMetrics one-liner -> INFO; retries/fallbacks (pallas downgrade, sparse
+QueryMetrics one-liner -> INFO; retries/fallbacks (sparse
 overflow, transient re-dispatch) -> WARNING.
 """
 

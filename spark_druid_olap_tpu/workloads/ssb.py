@@ -524,11 +524,28 @@ QUERIES: Dict[str, str] = {
 # ---------------------------------------------------------------------------
 
 
-def flat_frame_chunk(tables, lo):
+def oracle_categories(tables):
+    """Per string attribute: (sorted distinct values, per-dim-row codes),
+    from `np.unique` over the small dimension tables — the oracle's own
+    encoding, independent of the engine's dictionaries."""
+    out = {}
+    for attr, (table, _) in DIM_ATTRS.items():
+        vals = np.asarray(tables[table][attr])
+        if vals.dtype.kind in ("U", "S", "O"):
+            out[attr] = np.unique(vals.astype(str), return_inverse=True)
+    return out
+
+
+def flat_frame_chunk(tables, lo, categories=None):
     """Decoded flat pandas frame for ONE fact chunk (the chunked-oracle
-    unit; string attrs materialize only chunk-wide)."""
+    unit).  String attributes are pandas categoricals over their decoded
+    values: the fact rows gather small int codes through the FK, never
+    strings.  A chunked caller passes `oracle_categories(tables)` once
+    instead of paying the dimension-table sort per chunk."""
     import pandas as pd
 
+    if categories is None:
+        categories = oracle_categories(tables)
     data = {
         "lo_orderdate": lo["lo_orderdate"],
         **{m: np.asarray(lo[m], dtype=np.float64) for m in FLAT_METRICS},
@@ -539,7 +556,13 @@ def flat_frame_chunk(tables, lo):
             idx_cache[table] = _fk_row_index(
                 lo, fk_col, table, tables["dwdate"]
             )
-        data[attr] = np.asarray(tables[table][attr])[idx_cache[table]]
+        if attr in categories:
+            values, codes = categories[attr]
+            data[attr] = pd.Categorical.from_codes(
+                codes[idx_cache[table]], categories=values
+            )
+        else:
+            data[attr] = np.asarray(tables[table][attr])[idx_cache[table]]
     return pd.DataFrame(data)
 
 

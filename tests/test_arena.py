@@ -508,6 +508,19 @@ def test_donation_requested_exactly_off_cpu(monkeypatch):
         _exact_equal(got, off.execute(q, ds))
 
 
+def test_donated_carry_holds_no_buffer_twice():
+    """A TPU refuses a donated argument in which one buffer appears twice
+    (`Attempt to donate the same buffer twice`, met on the first chip run
+    of the arena); the CPU backend never donates, so only this sees it."""
+    import types
+
+    la = types.SimpleNamespace(
+        sum_names=("s",), min_names=("mn",), max_names=()
+    )
+    carry = arena._member_init(types.SimpleNamespace(la=la, num_groups=8))
+    assert len({id(leaf) for leaf in carry}) == len(carry) == 8
+
+
 def test_no_donation_on_cpu_backend(monkeypatch):
     import jax
 

@@ -1,0 +1,250 @@
+"""Deviceless v5e compiles of the Pallas group-by kernel and of the engine's
+real programs around it.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described and not attached; nothing runs.  These are what guard the
+kernel now that no engine path gives way to XLA when Mosaic refuses it.
+
+The topology is described inside a module-scoped fixture — never at
+import — so every xdist worker collects the same tests and only the
+worker that runs this file loads the TPU library.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from spark_druid_olap_tpu.ops.pallas_groupby import pallas_partial_aggregate
+
+R_KERNEL = 1 << 20  # rows of one bare-kernel call
+R_SEGMENT = 1 << 19  # rows of one SSB segment (ssb.register_streamed)
+ARENA_BLOCKS = 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a deviceless compile can be written to the persistent cache but not
+    # read back without a chip: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_args(lead, G, Ms, Mn, Mx, sharding):
+    return (
+        _spec(lead, jnp.int32, sharding),
+        _spec(lead, jnp.bool_, sharding),
+        _spec(lead + (Ms,), jnp.float32, sharding),
+        _spec(lead + (Mn + Mx,), jnp.float32, sharding),
+        _spec(lead + (Mn + Mx,), jnp.bool_, sharding),
+    )
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (G, Ms, Mn, Mx): SSB q1 (one group), TPC-H Q1, a min/max mix at the
+# 1024-group tile edge, the widest single tile, q2's 8008 (two tiles)
+KERNEL_SHAPES = [
+    (1, 1, 0, 0),
+    (12, 4, 0, 0),
+    (1024, 3, 1, 1),
+    (4096, 2, 0, 0),
+    (8008, 1, 0, 0),
+]
+
+
+@pytest.mark.parametrize("G,Ms,Mn,Mx", KERNEL_SHAPES)
+def test_kernel_compiles_for_v5e(one_chip, G, Ms, Mn, Mx):
+    compiled = pallas_partial_aggregate.lower(
+        *_kernel_args((R_KERNEL,), G, Ms, Mn, Mx, one_chip),
+        num_groups=G, num_min=Mn, num_max=Mx,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+SCAN_G, SCAN_MS = 1024, 2
+
+
+@jax.jit
+def _scan_fold(gid, mask, sv, mmv, mmm):
+    def body(acc, xs):
+        s, _, _ = pallas_partial_aggregate(
+            *xs, num_groups=SCAN_G, num_min=0, num_max=0
+        )
+        return acc + s, None
+
+    acc, _ = lax.scan(
+        body, jnp.zeros((SCAN_G, SCAN_MS), jnp.float32),
+        (gid, mask, sv, mmv, mmm),
+    )
+    return acc
+
+
+def test_kernel_compiles_inside_scan(one_chip):
+    """The arena's shape of use: the kernel as the body of a `lax.scan`
+    over stacked `[B, R]` segment blocks."""
+    compiled = _scan_fold.lower(
+        *_kernel_args(
+            (ARENA_BLOCKS, R_SEGMENT), SCAN_G, SCAN_MS, 0, 0, one_chip
+        )
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.fixture(scope="module")
+def ssb_ctx():
+    """SSB with the dimension tables at SF1 — every dictionary is already
+    at the cardinality it has at SF10, so the lowerings (and G) are the
+    real ones — over a fact small enough to build in a test."""
+    import spark_druid_olap_tpu as sd
+    from spark_druid_olap_tpu.workloads import ssb
+
+    rng = np.random.default_rng(7)
+    tables = ssb.gen_dim_tables(1.0, rng)
+    tables["lineorder"] = ssb._gen_fact(
+        4096, rng, tables["dwdate"]["d_datekey"],
+        len(tables["customer"]["c_custkey"]),
+        len(tables["supplier"]["s_suppkey"]),
+        len(tables["part"]["p_partkey"]),
+    )
+    ctx = sd.TPUOlapContext()
+    ssb.register(ctx, tables=tables)
+    return ctx
+
+
+def _lowered_query(ctx, name):
+    from spark_druid_olap_tpu.exec.lowering import lower_groupby
+    from spark_druid_olap_tpu.sql.parser import parse_sql
+    from spark_druid_olap_tpu.workloads import ssb
+
+    lp, _, _ = parse_sql(ssb.QUERIES[name])
+    rw = ctx._planner().plan(lp)
+    ds = ctx.catalog.get(rw.datasource)
+    return rw.query, ds, lower_groupby(rw.query, ds)
+
+
+def _segment_col_specs(ctx, ds, names, lead, sharding):
+    """The engine's own per-segment device columns, as shapes of a real
+    SSB segment on the described chip."""
+    cols = ctx.engine._cols_for_segment(ds.segments[0], ds, list(names))
+    return {
+        n: _spec(lead, a.dtype, sharding) for n, a in cols.items()
+    }
+
+
+@pytest.fixture
+def pallas_on(monkeypatch):
+    """The engine asks `pallas_available()` (a live-backend question) to
+    choose between the compiled kernel and interpret mode; under a
+    deviceless compile the live backend is the CPU, so steer it here."""
+    from spark_druid_olap_tpu.ops import pallas_groupby
+
+    monkeypatch.setattr(pallas_groupby, "pallas_available", lambda: True)
+
+
+# q1_1: G=1; q4_1: G=208 — the dense class the engine upgrades to the
+# kernel on a TPU (Engine._resolve_strategy)
+@pytest.mark.parametrize("name,G", [("q1_1", 1), ("q4_1", 208)])
+def test_engine_segment_program_compiles(one_chip, ssb_ctx, pallas_on, name, G):
+    from spark_druid_olap_tpu.exec.engine import Engine
+
+    q, ds, lowering = _lowered_query(ssb_ctx, name)
+    assert lowering.num_groups == G
+    eng = Engine(strategy="pallas")
+    assert eng._resolve_strategy(G) == "pallas"
+    seg_fn = eng._segment_program(q, ds, lowering)
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (R_SEGMENT,), one_chip
+    )
+    _assert_kernel(seg_fn.lower([cols, cols]).compile())
+
+
+@pytest.mark.parametrize("name", ["q1_1", "q4_1"])
+def test_engine_arena_scan_compiles(one_chip, ssb_ctx, pallas_on, name):
+    """The one-dispatch arena program Engine(strategy="pallas") builds:
+    the scanned fold over `[B, R]` stacks."""
+    from spark_druid_olap_tpu.exec import arena
+    from spark_druid_olap_tpu.exec.engine import Engine
+
+    q, ds, lowering = _lowered_query(ssb_ctx, name)
+    program = Engine(strategy="pallas")._arena_program(
+        q, ds, lowering, "pallas"
+    )
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), one_chip
+    )
+    carry = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        (arena._member_init(lowering),),
+    )
+    compiled = program.lower(
+        carry, cols,
+        _spec((ARENA_BLOCKS,), jnp.bool_, one_chip),
+        _spec((ARENA_BLOCKS, 1), jnp.bool_, one_chip),
+    ).compile()
+    _assert_kernel(compiled)
+
+
+def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
+    """q2_1 (G=8008, past the one-hot cutover) routes to the adaptive
+    tier; its phase-A presence pass counts each grouping dim's codes
+    with the kernel."""
+    q, ds, lowering = _lowered_query(ssb_ctx, "q2_1")
+    assert lowering.num_groups == 8008
+    eng = ssb_ctx.engine
+    seg_fn = eng._presence_program(q, ds, lowering)
+    need = eng._presence_columns(q, lowering, ds)
+    cols = _segment_col_specs(ssb_ctx, ds, need, (R_SEGMENT,), one_chip)
+    _assert_kernel(seg_fn.lower([cols]).compile())
+
+
+def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
+    """The mesh path `chip_smoke.py --chips 4` drives: the shard_mapped
+    arena scan plus its psum boundary merge, one program over a (4, 1)
+    mesh of the described chips, at SF10's stacked shape."""
+    from spark_druid_olap_tpu.parallel import spmd_arena
+    from spark_druid_olap_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    mesh = make_mesh(n_data=4, devices=topo.devices)
+    rows = NamedSharding(mesh, P(DATA_AXIS, None))
+    steps = 29  # 115 SF10 segments over 4 row devices
+    q, ds, lowering = _lowered_query(ssb_ctx, "q4_1")
+    program = spmd_arena.build_spmd_arena_program(
+        mesh, [lowering], ["pallas"], Lk=steps
+    )
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (4 * steps, R_SEGMENT), rows
+    )
+    compiled = program.lower(
+        cols,
+        _spec((), jnp.int32, NamedSharding(mesh, P())),
+        _spec((4 * steps, 1), jnp.bool_, rows),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text

@@ -293,7 +293,7 @@ def test_calibration_meta_applied(tmp_path):
 
 
 def test_slope_fallback_guards_inverted_measurements():
-    """Round-5 tunnel lesson: an inverted two-size slope (t_hi <= t_lo,
+    """An inverted two-size slope (t_hi <= t_lo,
     jitter or rung-padding) must fall back to single-point-minus-RTT, never
     persist as 'this kernel is free' (a 1e-9 us/row sparse constant would
     route every query onto the sort path)."""

@@ -317,23 +317,21 @@ _MATRIX = {
             ),
         ],
         "clean": [
-            # the shim modules themselves are the sanctioned owners
-            {"spark_druid_olap_tpu/parallel/mesh.py": """
-                from jax.experimental.shard_map import shard_map
-            """},
+            # the kernel module owns the one scoped 32-bit trace
             {"spark_druid_olap_tpu/ops/pallas_groupby.py": """
                 import jax
 
-                def _enable_x64_compat(flag):
-                    from jax.experimental import enable_x64
-                    return enable_x64(flag)
+                def traced(fn):
+                    with jax.enable_x64(False):
+                        return fn()
             """},
             {"pkg/user.py": """
-                from spark_druid_olap_tpu.parallel.mesh import shard_map_compat
+                import jax
 
                 def build(fn, mesh, specs):
-                    return shard_map_compat(
-                        fn, mesh=mesh, in_specs=specs, out_specs=specs
+                    return jax.shard_map(
+                        fn, mesh=mesh, in_specs=specs, out_specs=specs,
+                        check_vma=False,
                     )
             """},
         ],

@@ -24,6 +24,22 @@ def test_initialize_is_safe_noop_single_process():
     assert info["global_devices"] == len(jax.devices())
 
 
+def test_initialize_stays_single_process_once_backend_is_up(monkeypatch):
+    """A single TPU host carries TPU_WORKER_ID like a pod worker does (met
+    on the four-chip v5e host): constructing a DistributedEngine there,
+    after JAX has been used, must not attempt a rendezvous that can no
+    longer be formed — and must never ask a metadata server."""
+    jax.devices()  # the backend is up, as in any process that loaded data
+
+    def no_rendezvous(*a, **kw):
+        raise AssertionError("jax.distributed.initialize must not be called")
+
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    monkeypatch.setattr(multihost, "_initialized", False)
+    monkeypatch.setattr(jax.distributed, "initialize", no_rendezvous)
+    assert multihost.initialize() is False
+
+
 def test_hybrid_mesh_single_process_equals_make_mesh():
     m = multihost.hybrid_mesh(n_groups=2)
     assert dict(m.shape) == dict(make_mesh(n_groups=2).shape)

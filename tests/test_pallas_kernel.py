@@ -1,12 +1,8 @@
 """Pallas fused GroupBy kernel vs the XLA dense path (bit-parity contract).
 
-Runs in interpret mode on the CPU test mesh; under SDOL_TEST_TPU=1 on a
-real chip the same cases compile through Mosaic (interpret=False), so the
-suite doubles as hardware evidence for the TPU watch loop."""
+Runs in interpret mode on the CPU test mesh; tests/test_chip_compile.py
+compiles the same kernel for a described v5e."""
 
-import os
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,12 +10,7 @@ import pytest
 from spark_druid_olap_tpu.ops.groupby import dense_partial_aggregate
 from spark_druid_olap_tpu.ops.pallas_groupby import pallas_partial_aggregate
 
-# Mosaic-compile (interpret=False) only when explicitly pointed at a real
-# accelerator; plain CPU runs use the Pallas interpreter.
-INTERPRET = not (
-    os.environ.get("SDOL_TEST_TPU") == "1"
-    and jax.devices()[0].platform != "cpu"
-)
+INTERPRET = True
 
 
 def _mk(R, G, Ms, Mn, Mx, seed=0, mask_p=0.8):

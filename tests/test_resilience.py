@@ -22,7 +22,7 @@ def _clean_injector():
 
 def test_classify_error():
     assert R.classify_error(RuntimeError("device blip")) == "transient"
-    assert R.classify_error(OSError("tunnel down")) == "transient"
+    assert R.classify_error(OSError("link down")) == "transient"
     assert R.classify_error(R.InjectedFault("x")) == "transient"
     assert R.classify_error(R.CircuitOpenError("x")) == "transient"
     assert R.classify_error(NotImplementedError("no such op")) == "static"

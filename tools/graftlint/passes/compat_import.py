@@ -1,21 +1,16 @@
 """Compat-import discipline pass.
 
-The repo runs on jax 0.4.x AND newer releases only because two
-version-compat shims own every cross-version API:
-`parallel/mesh.py:shard_map_compat` (jax.shard_map vs
-jax.experimental.shard_map, check_vma vs check_rep) and
-`ops/pallas_groupby.py:_enable_x64_compat` (jax.enable_x64 vs
-jax.experimental.enable_x64).  A direct use ANYWHERE else silently
-un-fixes the virtual-mesh distributed path or the pallas kernel on one
-side of the version split.  Checks (outside the shim allowlist):
+The repo targets ONE JAX (the installed one): SPMD programs build
+through `jax.shard_map(..., check_vma=False)` and the Pallas kernel
+traces its grid arithmetic under `jax.enable_x64(False)`.  Checks:
 
-* **GL401** — any import or attribute use of
-  `jax.experimental.shard_map` (route through `shard_map_compat`).
+* **GL401** — any import or attribute use of the retired
+  `jax.experimental.shard_map` API (call `jax.shard_map`).
 * **GL402** — `*.config.update("jax_enable_x64", ...)` or any use of
-  `jax.enable_x64` / `jax.experimental.enable_x64` (route through the
-  `_enable_x64_compat` shim; the package-level global enable in
-  `__init__.py` is the single sanctioned exception, grandfathered in
-  the baseline).
+  `jax.enable_x64` / `jax.experimental.enable_x64` outside
+  `ops/pallas_groupby.py`, the one scoped 32-bit trace (the
+  package-level global enable in `__init__.py` is the single sanctioned
+  exception, grandfathered in the baseline).
 """
 
 from __future__ import annotations
@@ -30,10 +25,7 @@ _X64_ATTRS = ("jax.enable_x64", "jax.experimental.enable_x64")
 class CompatImportPass(LintPass):
     name = "compat-import"
     default_config = {
-        "allow_paths": (
-            "spark_druid_olap_tpu/parallel/mesh.py",
-            "spark_druid_olap_tpu/ops/pallas_groupby.py",
-        ),
+        "allow_paths": ("spark_druid_olap_tpu/ops/pallas_groupby.py",),
     }
 
     def applies_to(self, relpath: str) -> bool:
@@ -48,9 +40,8 @@ class CompatImportPass(LintPass):
             if alias.name.startswith("jax.experimental.shard_map"):
                 self.report(
                     ctx, node, "GL401",
-                    "direct import of jax.experimental.shard_map bypasses "
-                    "the version-compat shim — use "
-                    "parallel.mesh.shard_map_compat",
+                    "jax.experimental.shard_map is the retired API — call "
+                    "jax.shard_map(..., check_vma=False)",
                 )
 
     def on_ImportFrom(self, node: ast.ImportFrom, ctx: ModuleContext):
@@ -61,17 +52,16 @@ class CompatImportPass(LintPass):
         ):
             self.report(
                 ctx, node, "GL401",
-                "direct import of jax.experimental.shard_map bypasses the "
-                "version-compat shim — use parallel.mesh.shard_map_compat",
+                "jax.experimental.shard_map is the retired API — call "
+                "jax.shard_map(..., check_vma=False)",
             )
         if mod == "jax.experimental" and any(
             a.name == "enable_x64" for a in node.names
         ):
             self.report(
                 ctx, node, "GL402",
-                "direct import of jax.experimental.enable_x64 bypasses the "
-                "version-compat shim — use "
-                "ops.pallas_groupby._enable_x64_compat",
+                "jax.experimental.enable_x64 outside the kernel's scoped "
+                "32-bit trace (ops/pallas_groupby.py)",
             )
 
     def on_Attribute(self, node: ast.Attribute, ctx: ModuleContext):
@@ -79,14 +69,14 @@ class CompatImportPass(LintPass):
         if dn == "jax.experimental.shard_map":
             self.report(
                 ctx, node, "GL401",
-                "jax.experimental.shard_map used directly — route through "
-                "parallel.mesh.shard_map_compat",
+                "jax.experimental.shard_map is the retired API — call "
+                "jax.shard_map(..., check_vma=False)",
             )
         elif dn in _X64_ATTRS:
             self.report(
                 ctx, node, "GL402",
-                f"{dn} used directly — route through "
-                "ops.pallas_groupby._enable_x64_compat",
+                f"{dn} outside the kernel's scoped 32-bit trace "
+                "(ops/pallas_groupby.py)",
             )
 
     # -- GL402 ----------------------------------------------------------------
@@ -105,7 +95,7 @@ class CompatImportPass(LintPass):
         ):
             self.report(
                 ctx, node, "GL402",
-                'config.update("jax_enable_x64", ...) outside the x64 shim: '
+                'config.update("jax_enable_x64", ...) outside __init__: '
                 "flipping x64 mid-process invalidates every traced program "
                 "and splits dtype semantics across modules",
             )
