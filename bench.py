@@ -752,7 +752,7 @@ def bench_topn_hll(scale: float):
     f = ssb.flat_frame(tables)
 
     def pandas_topn():
-        g = f.groupby("c_city").agg(
+        g = f.groupby("c_city", observed=True).agg(
             revenue=("lo_revenue", "sum"),
             uniq_custs=("lo_custkey", "nunique"),
         )
@@ -1033,7 +1033,7 @@ def bench_cube_theta(scale: float):
         for r in range(len(dims) + 1):
             for sub in itertools.combinations(dims, r):
                 if sub:
-                    g = f.groupby(list(sub)).agg(
+                    g = f.groupby(list(sub), observed=True).agg(
                         revenue=("lo_revenue", "sum"),
                         uniq_custs=("lo_custkey", "nunique"),
                     ).reset_index()

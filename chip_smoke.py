@@ -6,17 +6,17 @@
     python chip_smoke.py --chips 4    the SPMD mesh over four chips, and
                                       nothing else
 
-One process; it is the only one that touches JAX.  Without a TPU it exits
-non-zero before any query runs.  `--rehearse` lifts that one check (CPU
-rehearsal and the tests); the last line then names the platform it really
-ran on.  One JSON line per phase; the last line is
+One process; it is the only one that touches JAX.  Without a TPU, or on a
+machine whose chip count is not the phase's, it exits non-zero before any
+query runs.  `--rehearse` lifts that check (CPU rehearsal and the tests,
+on however many virtual devices); the last line then names the platform
+it really ran on.  One JSON line per phase; the last line is
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": N}}`.
 """
 
 import argparse
 import http.client
 import json
-import logging
 import os
 import resource
 import sys
@@ -47,24 +47,6 @@ def peak_rss_mb():
 
 def cache_entries(cache_dir):
     return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
-
-
-class EngineWarnings(logging.Handler):
-    """WARNING and above from the package's loggers.  The engine logs at
-    that level exactly when a tier gives way to another path (a declined
-    sparse ladder, a failed adaptive pass, a retried dispatch): the
-    answer stays right, so only this shows it."""
-
-    def __init__(self):
-        super().__init__(logging.WARNING)
-        self.seen = []
-
-    def emit(self, record):
-        self.seen.append(f"{record.name}: {record.getMessage()}")
-
-    def drain(self):
-        out, self.seen = self.seen, []
-        return out
 
 
 def load_ssb(ctx, scale, seed):
@@ -167,11 +149,16 @@ def parity(got, want, exact=()):
     return None
 
 
-def metrics_faults(m):
-    """What in a query's QueryMetrics says it left the device path."""
+def metrics_faults(m, distributed=False):
+    """What in a query's QueryMetrics says it left the path this phase
+    proves: the device, undegraded, first time, on one chip or the mesh.
+    A tier never gives way to another on a device error (it raises into
+    the retry machinery), so `retries` covers that too."""
     if m is None:
         return ["no metrics"]
     out = []
+    if bool(m.distributed) != distributed:
+        out.append(f"distributed={m.distributed} mesh_shape={m.mesh_shape}")
     if m.executor != "device":
         out.append(f"executor={m.executor}")
     if m.degraded:
@@ -196,7 +183,7 @@ def post(port, path, body):
         conn.close()
 
 
-def serve_phase(ctx, want, want_native, platform, warned):
+def serve_phase(ctx, want, want_native, platform):
     """All 13 SSB queries twice (cold, warm) plus one native groupBy over
     HTTP from a client thread; returns the list of failures.  Stops at the
     first query that fails: the run is lost anyway, and a query that left
@@ -228,7 +215,6 @@ def serve_phase(ctx, want, want_native, platform, warned):
             cold_ms, _, mc, bad_c = sql_once(name)
             warm_ms, out, mw, bad_w = sql_once(name)
             bad = [f"cold {b}" for b in bad_c] + [f"warm {b}" for b in bad_w]
-            bad += [f"engine warned: {w}" for w in warned.drain()]
             if mw is not None and mw.segments:
                 # (a filter that prunes every segment builds no program)
                 if not mw.program_cache_hit:
@@ -265,7 +251,6 @@ def serve_phase(ctx, want, want_native, platform, warned):
             diff = parity(got, want_native, exact=("n",))
             if diff:
                 bad.append("parity: " + diff)
-        bad += [f"engine warned: {w}" for w in warned.drain()]
         say(
             phase="native_groupby", ms=round(ms, 2),
             rows_out=len(out) if code == 200 else None,
@@ -305,7 +290,7 @@ def serve_phase(ctx, want, want_native, platform, warned):
     return failures
 
 
-def mesh_phase(ctx, want, n_chips, warned):
+def mesh_phase(ctx, want, n_chips):
     """The 13 queries through the SPMD mesh over `n_chips` devices."""
     import jax
 
@@ -326,15 +311,12 @@ def mesh_phase(ctx, want, n_chips, warned):
             df = ctx._post_process(rw, ds, dist.execute(rw.query, ds))
             times.append((time.perf_counter() - t0) * 1e3)
         m = dist.last_metrics
-        bad = metrics_faults(m)
-        if not m.distributed or tuple(m.mesh_shape or ()) != (n_chips, 1):
-            bad.append(
-                f"distributed={m.distributed} mesh_shape={m.mesh_shape}"
-            )
+        bad = metrics_faults(m, distributed=True)
+        if tuple(m.mesh_shape or ()) != (n_chips, 1):
+            bad.append(f"mesh_shape={m.mesh_shape}")
         diff = parity(df, want[name])
         if diff:
             bad.append("parity: " + diff)
-        bad += [f"engine warned: {w}" for w in warned.drain()]
         say(
             phase="mesh_query", query=name, cold_ms=round(times[0], 2),
             warm_ms=round(times[1], 2), strategy=m.strategy,
@@ -391,10 +373,15 @@ def main(argv=None):
             f"(platform {dev.platform!r})", file=sys.stderr,
         )
         return 2
-    if len(jax.devices()) < args.chips:
+    n_dev = len(jax.devices())
+    # on the chip the last line's count IS the phase: the served phase is
+    # proven on a one-chip machine, the mesh on a four-chip one.  A
+    # rehearsal only needs enough (virtual) devices.
+    if n_dev < args.chips or (n_dev != args.chips and not args.rehearse):
         print(
-            f"chip_smoke: --chips {args.chips} but JAX sees "
-            f"{len(jax.devices())} device(s)", file=sys.stderr,
+            f"chip_smoke: --chips {args.chips} but JAX sees {n_dev} "
+            f"device(s): run this phase on a machine with {args.chips}",
+            file=sys.stderr,
         )
         return 2
 
@@ -408,6 +395,11 @@ def main(argv=None):
     # every request must execute: a warm pass answered from the result
     # cache would say nothing about the program cache or residency
     cfg.result_cache_entries = 0
+    if args.chips == 1:
+        # the served phase proves the single-device engine: a rehearsal
+        # host that shows more (virtual) devices must not be planned onto
+        # a mesh.  With one device this changes nothing.
+        cfg.prefer_distributed = False
     ctx = TPUOlapContext(cfg)
     say(
         phase="start", device=str(dev), kind=dev.device_kind,
@@ -418,12 +410,10 @@ def main(argv=None):
 
     tables = load_ssb(ctx, args.scale, args.seed)
     want, want_native = compute_oracle(tables, args.scale, args.seed)
-    warned = EngineWarnings()
-    logging.getLogger("spark_druid_olap_tpu").addHandler(warned)
     if args.chips == 1:
-        failures = serve_phase(ctx, want, want_native, dev.platform, warned)
+        failures = serve_phase(ctx, want, want_native, dev.platform)
     else:
-        failures = mesh_phase(ctx, want, args.chips, warned)
+        failures = mesh_phase(ctx, want, args.chips)
 
     say(
         phase="end", wall_s=round(time.perf_counter() - t_start, 2),
@@ -438,7 +428,7 @@ def main(argv=None):
         return 1
     say(ok=True, device={
         "platform": dev.platform, "kind": dev.device_kind,
-        "count": len(jax.devices()),
+        "count": n_dev,
     })
     return 0
 

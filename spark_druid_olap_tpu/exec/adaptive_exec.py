@@ -456,24 +456,12 @@ class AdaptiveDomainMixin:
 
             # fault-injection site: phase A dispatches the presence
             # program to the device (phase B goes through the engine's
-            # _call_segment_program, which has its own site).  OUTSIDE
-            # the try below: an injected transient must decline this
-            # dispatch (caller falls through to sparse/dense, whose
-            # sites feed the retry/breaker machinery) — not be misread
-            # as a static failure that memo-declines the query shape.
+            # _call_segment_program, which has its own site).  A failure
+            # of the pass, injected or real, raises into the engine's
+            # retry machinery like any other device error: it is never a
+            # reason to hand the query to another tier.
             fire("device_dispatch")
-            try:
-                counts = run_presence()
-            except DeadlineExceeded:
-                # a deadline is a property of THIS request, not of the
-                # query shape: memo-declining would pin adaptive off for
-                # every later (unbudgeted) run of the same query
-                raise
-            except Exception:
-                # memo-decline so a pass that cannot run is not
-                # re-dispatched on every execution of this query shape
-                self._adaptive_declined.add(qkey)
-                raise
+            counts = run_presence()
             kept = [
                 np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
                 for c in counts
@@ -498,8 +486,9 @@ class AdaptiveDomainMixin:
         self, q: Q.GroupByQuery, ds: DataSource, lowering: GroupByLowering
     ):
         """Adaptive-compaction attempt.  Returns None when declining at
-        dispatch time (caller falls through to the sparse/scatter paths in
-        the same phase), else resolve() -> (df, "ok"|"error")."""
+        dispatch time (no shrink to be had; caller falls through to the
+        sparse/scatter paths in the same phase), else resolve() -> df.
+        A device error in either phase raises."""
         segs = self._segments_in_scope(q, ds)
         if not segs:
             return None
@@ -515,9 +504,6 @@ class AdaptiveDomainMixin:
             if pc is None:
                 raise
             pc.trigger(err.site or "adaptive.presence_loop")
-            return None
-        except Exception:
-            log.warning("adaptive presence pass failed", exc_info=True)
             return None
         if kept is None:
             return None
@@ -541,7 +527,7 @@ class AdaptiveDomainMixin:
                 np.asarray(sums), np.asarray(mins), np.asarray(maxs),
                 {k: np.asarray(v) for k, v in sketch_states.items()},
             )
-            return lambda: (df, "ok")
+            return lambda: df
 
         clow = compacted_lowering(lowering, kept)
         cards = tuple(d.cardinality for d in clow.dims)
@@ -552,33 +538,20 @@ class AdaptiveDomainMixin:
         # a 60M-row phase B at G'=600 ran 49 s dense vs sub-second
         # scatter; on TPU the same choice lands on Pallas/dense)
         strat = self._adaptive_main_strategy(ds, clow.num_groups)
-        try:
-            state = self._partials_for_query(
-                q, ds, lowering=clow, key_extra=("adaptive",) + cards,
-                strategy_override=strat,
-            )
-        except DeadlineExceeded:
-            raise  # partial-capable loops absorb expiry; a raise is real
-        except Exception:
-            log.warning("adaptive compact dispatch failed", exc_info=True)
-            return None
+        state = self._partials_for_query(
+            q, ds, lowering=clow, key_extra=("adaptive",) + cards,
+            strategy_override=strat,
+        )
 
         def resolve():
-            try:
-                dims, la, G, sums, mins, maxs, sketch_states = state
-                sums, mins, maxs, sketch_states = jax.device_get(
-                    (sums, mins, maxs, sketch_states)
-                )
-                df = finalize_groupby(
-                    q, dims, la,
-                    np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                    {k: np.asarray(v) for k, v in sketch_states.items()},
-                )
-                return df, "ok"
-            except DeadlineExceeded:
-                raise  # never demote a deadline to an adaptive decline
-            except Exception:
-                log.warning("adaptive resolve failed", exc_info=True)
-                return None, "error"
+            dims, la, G, sums, mins, maxs, sketch_states = state
+            sums, mins, maxs, sketch_states = jax.device_get(
+                (sums, mins, maxs, sketch_states)
+            )
+            return finalize_groupby(
+                q, dims, la,
+                np.asarray(sums), np.asarray(mins), np.asarray(maxs),
+                {k: np.asarray(v) for k, v in sketch_states.items()},
+            )
 
         return resolve

@@ -294,11 +294,6 @@ def _platform_unroll_max() -> int:
         return CPU_SEGMENT_UNROLL_MAX
     return MULTI_SEGMENT_UNROLL_MAX
 
-# Consecutive sparse-path exception fallbacks before a query is pinned off
-# the accelerator (transient blips recover; deterministic failures stop
-# re-paying doomed trace+compiles).
-_SPARSE_ERROR_PIN_AFTER = 2
-
 
 def _segment_partials(
     lowering: "GroupByLowering", strategy: str, cols, memo=None, share=None
@@ -418,14 +413,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         self._retry_attempts = 2  # total attempts (2 = one retry)
         self._retry_backoff_ms = 25.0
         # queries pinned off the sparse accelerator because compaction
-        # deterministically overflowed SPARSE_SLOTS distinct groups.
-        # Exception fallbacks do NOT pin immediately (a transient device
-        # blip must not demote a query for the engine's lifetime) but are
-        # counted per query: repeated failures pin after
-        # _SPARSE_ERROR_PIN_AFTER so a deterministically-broken sparse
-        # lowering stops re-paying doomed trace+compiles every execution.
+        # deterministically overflowed SPARSE_SLOTS distinct groups
         self._sparse_disabled: set = set()
-        self._sparse_error_counts: Dict = {}
         # queries whose survivors overflowed the base row-compaction
         # capacity: the kernel reports the exact survivor count, the engine
         # picks the smallest adequate ROW_CAPACITY_LADDER rung (None = full
@@ -1555,6 +1544,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             resolves[i] = None  # release the closure (and its device state)
             if resolve is None:
                 out.append(self._execute_groupby(q, ds))
+                self.last_metrics.retries += 1  # the failed batch dispatch
                 continue
             try:
                 out.append(resolve())
@@ -1570,6 +1560,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     groupby_with_time_granularity(q), ds
                 )
                 out.append(self._execute_groupby(q, ds))
+                self.last_metrics.retries += 1  # the failed batch resolve
         return out
 
     def _dispatch_groupby_once(self, q: Q.GroupByQuery, ds: DataSource):
@@ -1698,24 +1689,12 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 # answer (is_partial stays False)
                 checkpoint_partial("engine.resolve")
                 if adaptive_resolve is not None:
-                    out, reason = adaptive_resolve()
-                    if out is not None:
-                        m.device_ms = (
-                            (_time.perf_counter() - t_resolve) * 1e3
-                            + dispatch_ms
-                        )
-                        return out
-                    # adaptive failed at resolve time: serial dense fallback
-                    # for THIS execution (no pin — transient errors only;
-                    # deterministic declines happened at dispatch time)
-                    m.strategy = self._resolve_strategy(lowering.num_groups)
-                    log.warning(
-                        "adaptive path failed (%s); falling back to %s",
-                        reason, m.strategy,
+                    out = adaptive_resolve()
+                    m.device_ms = (
+                        (_time.perf_counter() - t_resolve) * 1e3
+                        + dispatch_ms
                     )
-                    dense_state = self._partials_for_query(
-                        q, ds, lowering=lowering
-                    )
+                    return out
                 if sparse_resolve is not None:
                     out, reason = sparse_resolve()
                     if out is not None:
@@ -1723,28 +1702,20 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                             (_time.perf_counter() - t_resolve) * 1e3
                             + dispatch_ms
                         )
-                        self._sparse_error_counts.pop(qkey, None)
                         return out
-                    pinned = False
+                    # the two planned declines, neither a device error
+                    # (those raise): "overflow" is deterministic — more
+                    # distinct groups than slots — so the query is pinned
+                    # off this tier; "declined" is a partial drain with
+                    # nothing dispatched
                     if reason == "overflow":
-                        # deterministic: more distinct groups than slots
                         self._sparse_disabled.add(qkey)
-                        pinned = True
-                    elif reason != "declined":
-                        # "declined" = nothing dispatched (a partial-drain
-                        # pass), not a sparse failure: never error-count it
-                        # toward the pin
-                        n = self._sparse_error_counts.get(qkey, 0) + 1
-                        self._sparse_error_counts[qkey] = n
-                        if n >= _SPARSE_ERROR_PIN_AFTER:
-                            self._sparse_disabled.add(qkey)
-                            pinned = True
                     m.strategy = self._resolve_strategy(lowering.num_groups)
                     log.warning(
                         "sparse path declined (%s); falling back to %s%s",
                         reason,
                         m.strategy,
-                        " (pinned)" if pinned else "",
+                        " (pinned)" if reason == "overflow" else "",
                     )
                     # serial fallback dispatch (rare): sparse declined, so
                     # the dense program launches now

@@ -22,7 +22,6 @@ import numpy as np
 
 from ..catalog.segment import DataSource
 from ..models import query as Q
-from ..resilience import DeadlineExceeded
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
 from .lowering import GroupByLowering, _query_key, memo_key
@@ -136,11 +135,10 @@ class SparseExecMixin:
         Dispatches the tier-1 program asynchronously and returns
         `resolve() -> (df, reason)`: df is None when declining, with reason
         "overflow" (deterministic — more distinct groups than slots: the
-        caller pins the query off this path) or "error" (sparse program
-        failed even after the Pallas-inner retry: fall back this execution
-        only; correctness never depends on this path).  A trace/compile
-        failure at dispatch time is carried into resolve() and handled by
-        the same downgrade path as an execution failure."""
+        caller pins the query off this path) or "declined" (a partial
+        drain left nothing to answer from).  A failure of the sparse
+        program is not a decline: it raises, here or in resolve(), into
+        the engine's retry machinery like any other device error."""
         from ..ops.sparse_groupby import merge_sparse_states
 
         segs = self._segments_in_scope(q, ds)
@@ -160,7 +158,6 @@ class SparseExecMixin:
             # it down exactly like the dense engine's — otherwise a
             # breaker half-open probe routed to a sparse-strategy query
             # succeeds and closes the breaker while the device is dead.
-            # The injected transient declines this execution only.
             fire("device_dispatch")
             seg_fn = self._sparse_program(
                 q, ds, lowering, row_capacity=row_capacity, slots=slots
@@ -221,17 +218,6 @@ class SparseExecMixin:
                     pc.add_seen(len(batch), *_row_counts(batch))
             folder.drain()
             return state
-
-        def evict():
-            # only THIS query's sparse programs — other queries' compiled
-            # sparse programs are fine and expensive to rebuild
-            base = _query_key(q, ds)
-            for k in [
-                k
-                for k in self._query_fn_cache
-                if k[:2] == base and str(k[2]).startswith("sparse")
-            ]:
-                self._query_fn_cache.pop(k)
 
         # learned rungs key segment-set-independently (see lowering.memo_key):
         # appends must not forget them or leak one entry per delta publish
@@ -368,44 +354,25 @@ class SparseExecMixin:
                 )
             return host, slots
 
-        # phase 1: dispatch (async — no fetch).  Exceptions are deferred
-        # into resolve() so batch callers see the same decline protocol as
-        # execution failures.
-        state = dispatch_exc = None
-        try:
-            state = dispatch(row_capacity=cap, slots=slots0)
-        except Exception as exc:  # fault-ok: re-raised in resolve below
-            dispatch_exc = exc
+        # phase 1: dispatch (async — no fetch)
+        state = dispatch(row_capacity=cap, slots=slots0)
 
         def resolve():
             nonlocal state
+            if state is None:
+                # a partial drain armed BEFORE this dispatch started:
+                # nothing was dispatched, so there is no sparse state
+                # to answer from — decline and let the dense path
+                # produce the zero-coverage answer
+                return None, "declined"
             try:
-                if dispatch_exc is not None:
-                    raise dispatch_exc
-                if state is None:
-                    # a partial drain armed BEFORE this dispatch started:
-                    # nothing was dispatched, so there is no sparse state
-                    # to answer from — decline (never error-counted) and
-                    # let the dense path produce the zero-coverage answer
-                    return None, "declined"
                 host, _ = fetch_slot_laddered(state, cap, slots0)
+            finally:
                 state = None  # free the device partials promptly
-            except DeadlineExceeded:
-                # partial-result discipline (GL16xx): an expiry that the
-                # partial machinery did NOT absorb (no collector armed)
-                # must propagate as a deadline, never be swallowed into
-                # the generic sparse-decline path — retrying the whole
-                # scope on the dense engine would only time out slower
-                state = None
-                raise
-            except Exception:  # fault-ok: returns "error"; caller logs + falls back
-                state = None
-                evict()
-                return None, "error"
             if host is None:
                 # a partial drain stopped a ladder rerun mid-scope:
-                # decline (never error-counted) — the dense drain
-                # produces the best-effort answer
+                # decline — the dense drain produces the best-effort
+                # answer
                 return None, "declined"
             if bool(host["overflow"]):
                 return None, "overflow"

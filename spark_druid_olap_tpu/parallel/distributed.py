@@ -1005,25 +1005,15 @@ class DistributedEngine:
             from ..exec.adaptive_exec import presence_columns
 
             need = presence_columns(q, lowering, ds)
-            try:
-                cols, padded = self._place_shards(ds, need, m, q=q)
-                local_rows = padded // self.mesh.shape[DATA_AXIS]
-                run = self._presence_fn(
-                    lowering, local_rows, ds, tuple(cols.keys())
-                )
-                with span(SPAN_ADAPTIVE_PROBE):
-                    counts = jax.device_get(run(cols))
-            except RuntimeError:
-                # transient device failures belong to execute()'s
-                # evict-and-retry path, NOT a permanent decline (review r5)
-                raise
-            except Exception:
-                log.warning(
-                    "mesh adaptive presence pass failed; declining",
-                    exc_info=True,
-                )
-                self._adaptive_declined.add(qkey)
-                return None
+            # a failure of the pass raises (transient ones into execute()'s
+            # evict-and-retry path): it is never a reason to decline
+            cols, padded = self._place_shards(ds, need, m, q=q)
+            local_rows = padded // self.mesh.shape[DATA_AXIS]
+            run = self._presence_fn(
+                lowering, local_rows, ds, tuple(cols.keys())
+            )
+            with span(SPAN_ADAPTIVE_PROBE):
+                counts = jax.device_get(run(cols))
             kept = [
                 np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
                 for c in counts

@@ -26,6 +26,7 @@ bandwidth hierarchy the way SURVEY.md §5 prescribes.
 
 from __future__ import annotations
 
+import os
 from typing import Dict, Optional
 
 import jax
@@ -36,6 +37,38 @@ from ..utils.log import get_logger
 log = get_logger("parallel.multihost")
 
 _initialized = False
+
+
+def _env_names_a_cluster() -> bool:
+    """Whether the environment describes a multi-process runtime to join.
+
+    A coordinator address or a cluster launcher's job variables (the ones
+    jax auto-detects: SLURM / Open MPI) do.  A Cloud TPU worker id alone
+    does NOT: a single TPU host that drives all its chips from one process
+    carries `TPU_WORKER_ID=0` too, with one name in its worker list
+    (`TPU_WORKER_HOSTNAMES=localhost`, met on the four-chip v5e host).  It
+    is a pod only when that list (`TPU_PROCESS_ADDRESSES`, else
+    `TPU_WORKER_HOSTNAMES` — the order jax reads them in) names more than
+    one worker, or the job spans slices."""
+    env = os.environ
+    if any(
+        k in env
+        for k in (
+            "COORDINATOR_ADDRESS",
+            "JAX_COORDINATOR_ADDRESS",
+            "SLURM_JOB_ID",
+            "OMPI_COMM_WORLD_SIZE",
+        )
+    ):
+        return True
+    if "TPU_WORKER_ID" not in env and "CLOUD_TPU_TASK_ID" not in env:
+        return False
+    workers = env.get("TPU_PROCESS_ADDRESSES") or env.get(
+        "TPU_WORKER_HOSTNAMES", ""
+    )
+    n_workers = len([w for w in workers.split(",") if w.strip()])
+    slices = env.get("MEGASCALE_NUM_SLICES", "")
+    return n_workers > 1 or (slices.isdigit() and int(slices) > 1)
 
 
 def initialize(
@@ -64,37 +97,10 @@ def initialize(
         _initialized = True
         return True
     if coordinator_address is None and num_processes is None:
-        # no explicit rendezvous and no cluster metadata in the
-        # environment: stay single-process rather than hanging on a
-        # coordinator that will never answer.  The markers cover Cloud TPU
-        # pods plus the cluster launchers jax auto-detects (SLURM / OMPI).
-        import os
-
-        if not any(
-            k in os.environ
-            for k in (
-                "COORDINATOR_ADDRESS",
-                "JAX_COORDINATOR_ADDRESS",
-                "CLOUD_TPU_TASK_ID",
-                "TPU_WORKER_ID",
-                "SLURM_JOB_ID",
-                "OMPI_COMM_WORLD_SIZE",
-            )
-        ):
-            return False
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            # a marker alone does not make a pod: a single TPU host that
-            # drives all its chips from one process carries TPU_WORKER_ID
-            # too (met on the four-chip v5e host).  This process already
-            # holds its backend, so no rendezvous can be formed any more:
-            # it is single-process.  A multi-host launcher calls this
-            # first thing, before any other JAX call.
-            log.info(
-                "cluster markers in the environment, but the XLA backend "
-                "is already up: staying single-process"
-            )
+        # no explicit rendezvous and no cluster in the environment: stay
+        # single-process rather than hanging on a coordinator that will
+        # never answer
+        if not _env_names_a_cluster():
             return False
     try:
         # CPU backend: cross-process collectives need the Gloo transport
@@ -102,9 +108,7 @@ def initialize(
         # backend" otherwise) — must be set BEFORE the runtime forms.
         # Real TPU/GPU pods ignore it; a jax build without the flag (or
         # without Gloo) keeps the old failure mode at dispatch time.
-        import os as _os
-
-        if _os.environ.get("JAX_PLATFORMS", "") in ("", "cpu"):
+        if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu"):
             try:
                 jax.config.update(
                     "jax_cpu_collectives_implementation", "gloo"

@@ -540,8 +540,10 @@ def flat_frame_chunk(tables, lo, categories=None):
     """Decoded flat pandas frame for ONE fact chunk (the chunked-oracle
     unit).  String attributes are pandas categoricals over their decoded
     values: the fact rows gather small int codes through the FK, never
-    strings.  A chunked caller passes `oracle_categories(tables)` once
-    instead of paying the dimension-table sort per chunk."""
+    strings (group by them with `observed=True`, as `oracle` does: a
+    pandas that defaults to False emits every combination of categories).
+    A chunked caller passes `oracle_categories(tables)` once instead of
+    paying the dimension-table sort per chunk."""
     import pandas as pd
 
     if categories is None:
@@ -589,7 +591,7 @@ def merge_oracle_parts(parts):
     df = pd.concat(nonempty, ignore_index=True)
     vcol = df.columns[-1]  # oracle puts the measure last
     g = [c for c in df.columns if c != vcol]
-    return df.groupby(g, as_index=False)[vcol].sum()
+    return df.groupby(g, as_index=False, observed=True)[vcol].sum()
 
 
 def oracle(f, name: str):
@@ -617,7 +619,8 @@ def oracle(f, name: str):
         else:
             m = (f.p_brand1 == "MFGR#22-9") & (f.s_region == "EUROPE")
         return (
-            f[m].groupby(["d_year", "p_brand1"]).lo_revenue.sum()
+            f[m].groupby(["d_year", "p_brand1"], observed=True)
+            .lo_revenue.sum()
             .reset_index().rename(columns={"lo_revenue": "revenue"})
         )
     if name in ("q3_1", "q3_2", "q3_3", "q3_4"):
@@ -635,7 +638,7 @@ def oracle(f, name: str):
             m &= yr if name == "q3_3" else (f.d_yearmonth == "1997-12")
             g = ["c_city", "s_city", "d_year"]
         return (
-            f[m].groupby(g).lo_revenue.sum()
+            f[m].groupby(g, observed=True).lo_revenue.sum()
             .reset_index().rename(columns={"lo_revenue": "revenue"})
         )
     if name in ("q4_1", "q4_2", "q4_3"):
@@ -654,6 +657,7 @@ def oracle(f, name: str):
                  & f.d_year.isin([1997, 1998]) & (f.p_category == "MFGR#14"))
             g = ["d_year", "s_city", "p_brand1"]
         return (
-            f[m].assign(profit=prof).groupby(g).profit.sum().reset_index()
+            f[m].assign(profit=prof).groupby(g, observed=True)
+            .profit.sum().reset_index()
         )
     raise KeyError(name)

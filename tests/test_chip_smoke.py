@@ -77,6 +77,25 @@ def test_refuses_to_run_without_a_tpu(no_cache, capsys):
     assert "no TPU" in out.err
 
 
+@pytest.mark.parametrize("argv, n_chips", [([], 4), (["--chips", "4"], 1),
+                                           (["--chips", "4"], 8)])
+def test_refuses_a_machine_that_is_not_the_phases(
+    no_cache, monkeypatch, capsys, argv, n_chips
+):
+    """On the chip the last line's count is the phase: the served phase
+    on a four-chip host (where the planner would take the mesh) or the
+    mesh phase on another count is refused before anything runs."""
+    import types
+
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip] * n_chips)
+    smoke = _load("chip_smoke")
+    assert smoke.main(argv) != 0
+    out = capsys.readouterr()
+    assert out.out == ""
+    assert f"JAX sees {n_chips} device(s)" in out.err
+
+
 def test_degraded_query_fails_the_run(no_cache, clean_injector, capsys):
     """Every device dispatch fails -> the served answers come from the host
     fallback, still at parity — and the run must NOT pass on that."""
@@ -87,6 +106,33 @@ def test_degraded_query_fails_the_run(no_cache, clean_injector, capsys):
     assert "ok" not in lines[-1]  # no result line
     failures = lines[-1]["failures"]
     assert any("executor=fallback" in f or "degraded" in f for f in failures)
+
+
+def test_one_retried_dispatch_fails_the_run(no_cache, clean_injector, capsys):
+    """One transient device error: the engine retries, the answer is right
+    and comes from the device — and QueryMetrics.retries alone must fail
+    the run (nothing is read off the log)."""
+    clean_injector.arm("device_dispatch", "error", times=1)
+    smoke = _load("chip_smoke")
+    assert smoke.main(["--rehearse", "--scale", "0.01"]) != 0
+    lines = _lines(capsys)
+    assert "ok" not in lines[-1]
+    assert any("retries=1" in f for f in lines[-1]["failures"])
+
+
+def test_served_phase_stays_on_one_device(no_cache, capsys):
+    """The suite runs on eight virtual devices; the default phase must
+    still prove the single-device engine, not the mesh."""
+    assert len(jax.devices()) > 1
+    smoke = _load("chip_smoke")
+    seen = []
+    faults = smoke.metrics_faults
+    smoke.metrics_faults = lambda m, **kw: (
+        seen.append(m.distributed) or faults(m, **kw)
+    )
+    assert smoke.main(["--rehearse", "--scale", "0.01"]) == 0
+    capsys.readouterr()
+    assert seen and not any(seen)
 
 
 @pytest.fixture

@@ -142,3 +142,93 @@ def test_static_errors_do_not_retry(ds):
     with pytest.raises(ValueError):
         eng.execute(_q(), ds)
     assert calls["n"] == 1  # no second dispatch for non-transient errors
+
+
+# --- the adaptive and sparse tiers: a device error is never a reason to
+# hand the query to another tier (it would answer right and hide the fault)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """Combined domain 300 x 300 >> the scatter cutover, 700 pairs present:
+    the shape both the adaptive and the sparse tier take."""
+    from spark_druid_olap_tpu.catalog.segment import DimensionDict
+
+    n, da, db = 30_000, 300, 300
+    rng = np.random.default_rng(3)
+    pairs = rng.choice(da * db, size=700, replace=False)[
+        rng.integers(0, 700, size=n)
+    ]
+    cols = {
+        "a": (pairs // db).astype(np.int64),
+        "b": (pairs % db).astype(np.int64),
+        "v": np.ones(n, np.float32),
+    }
+    ds = build_datasource(
+        "wide", cols, dimension_cols=["a", "b"], metric_cols=["v"],
+        rows_per_segment=n // 3,
+        dicts={
+            "a": DimensionDict(values=tuple(range(da))),
+            "b": DimensionDict(values=tuple(range(db))),
+        },
+    )
+    hit = cols["a"] < 40
+    want_groups = len(np.unique(pairs[hit]))
+    return ds, want_groups, int(hit.sum())
+
+
+def _wide_q():
+    from spark_druid_olap_tpu.models.filters import And, Bound, InFilter
+
+    # few `a` codes pass, so the marginals shrink (adaptive does not
+    # decline); `b` stays unpinned, so adaptive must MEASURE its kept sets
+    # with a presence pass on the device instead of deriving them
+    return GroupByQuery(
+        datasource="wide",
+        dimensions=(DimensionSpec("a"), DimensionSpec("b")),
+        aggregations=(Count("n"), DoubleSum("s", "v")),
+        filter=And((
+            InFilter("a", tuple(range(40))),
+            Bound("v", lower=0, ordering="numeric"),
+        )),
+    )
+
+
+@pytest.fixture
+def armed():
+    from spark_druid_olap_tpu.resilience import injector
+
+    injector().disarm()
+    yield injector()
+    injector().disarm()
+
+
+@pytest.mark.parametrize("tier", ["adaptive", "sparse"])
+def test_transient_failure_in_a_tier_is_retried_on_it_and_counted(
+    wide, armed, tier
+):
+    ds, want_groups, want_rows = wide
+    eng = Engine(strategy=tier)
+    eng._retry_backoff_ms = 0.0
+    armed.arm("device_dispatch", "error", times=1)
+    got = eng.execute(_wide_q(), ds)
+    assert len(got) == want_groups and int(got["n"].sum()) == want_rows
+    m = eng.last_metrics
+    assert m.strategy == tier  # same tier answered, not the next one down
+    assert m.retries == 1  # and the re-dispatch shows
+
+
+@pytest.mark.parametrize(
+    "tier, program",
+    [("adaptive", "_presence_program"), ("sparse", "_sparse_program")],
+)
+def test_static_failure_in_a_tier_raises(wide, monkeypatch, tier, program):
+    eng = Engine(strategy=tier)
+
+    def refused(*a, **kw):
+        raise TypeError("the compiler refused this program")
+
+    monkeypatch.setattr(eng, program, refused)
+    with pytest.raises(TypeError, match="refused"):
+        eng.execute(_wide_q(), wide[0])
+    assert not eng._adaptive_declined and not eng._sparse_disabled

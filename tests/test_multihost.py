@@ -24,20 +24,68 @@ def test_initialize_is_safe_noop_single_process():
     assert info["global_devices"] == len(jax.devices())
 
 
-def test_initialize_stays_single_process_once_backend_is_up(monkeypatch):
-    """A single TPU host carries TPU_WORKER_ID like a pod worker does (met
-    on the four-chip v5e host): constructing a DistributedEngine there,
-    after JAX has been used, must not attempt a rendezvous that can no
-    longer be formed — and must never ask a metadata server."""
-    jax.devices()  # the backend is up, as in any process that loaded data
+_CLUSTER_VARS = (
+    "COORDINATOR_ADDRESS", "JAX_COORDINATOR_ADDRESS", "SLURM_JOB_ID",
+    "OMPI_COMM_WORLD_SIZE", "TPU_WORKER_ID", "CLOUD_TPU_TASK_ID",
+    "TPU_PROCESS_ADDRESSES", "TPU_WORKER_HOSTNAMES", "MEGASCALE_NUM_SLICES",
+)
 
+
+@pytest.fixture
+def cluster_env(monkeypatch):
+    """Set exactly the given cluster variables, with the backend up (as in
+    any process that loaded data before building a DistributedEngine) and
+    the module's latch cleared."""
+    def apply(env):
+        for k in _CLUSTER_VARS:
+            monkeypatch.delenv(k, raising=False)
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        monkeypatch.setattr(multihost, "_initialized", False)
+        jax.devices()
+
+    return apply
+
+
+@pytest.mark.parametrize(
+    "env",
+    [
+        {"TPU_WORKER_ID": "0", "TPU_WORKER_HOSTNAMES": "localhost"},
+        {"TPU_WORKER_ID": "0"},
+        {"CLOUD_TPU_TASK_ID": "0", "TPU_PROCESS_ADDRESSES": "localhost:8476"},
+    ],
+    ids=["one-hostname", "no-worker-list", "one-process-address"],
+)
+def test_single_tpu_host_is_not_a_pod(cluster_env, monkeypatch, env):
+    """A single TPU host carries TPU_WORKER_ID like a pod worker does (met
+    on the four-chip v5e host, with TPU_WORKER_HOSTNAMES=localhost): no
+    rendezvous is attempted and no metadata server is asked."""
     def no_rendezvous(*a, **kw):
         raise AssertionError("jax.distributed.initialize must not be called")
 
-    monkeypatch.setenv("TPU_WORKER_ID", "0")
-    monkeypatch.setattr(multihost, "_initialized", False)
+    cluster_env(env)
     monkeypatch.setattr(jax.distributed, "initialize", no_rendezvous)
     assert multihost.initialize() is False
+
+
+@pytest.mark.parametrize(
+    "env",
+    [
+        {"TPU_WORKER_ID": "1", "TPU_WORKER_HOSTNAMES": "host-a,host-b"},
+        {"TPU_WORKER_ID": "0", "TPU_WORKER_HOSTNAMES": "localhost",
+         "MEGASCALE_NUM_SLICES": "2"},
+        {"JAX_COORDINATOR_ADDRESS": "host-a:8476"},
+    ],
+    ids=["two-hostnames", "two-slices", "coordinator"],
+)
+def test_pod_worker_that_touched_jax_first_still_raises(cluster_env, env):
+    """On a real pod, a worker that used JAX before the rendezvous has an
+    ordering bug: it must fail loudly, never run on as a lone host over
+    its local chips."""
+    cluster_env(env)
+    with pytest.raises(RuntimeError, match="before any JAX calls"):
+        multihost.initialize()
+    assert multihost._initialized is False
 
 
 def test_hybrid_mesh_single_process_equals_make_mesh():

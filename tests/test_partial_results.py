@@ -274,12 +274,12 @@ def test_adaptive_empty_kept_set_not_flagged_partial():
     assert not m.partial
 
 
-def test_sparse_overflow_during_drain_declines_without_error_pin():
+def test_sparse_overflow_during_drain_declines_without_pin():
     """A partial drain that stops the sparse segment loop can leave the
     merged state overflowed; the slot/row ladder must NOT re-dispatch
     the already-stopped scope (dispatch would return None and crash the
-    fetch) — it declines un-error-counted, so a deadline can never pin
-    the query shape off the sparse tier."""
+    fetch) — it declines, so a deadline can never pin the query shape
+    off the sparse tier."""
     from spark_druid_olap_tpu.catalog.segment import (
         DimensionDict,
         build_datasource,
@@ -318,8 +318,7 @@ def test_sparse_overflow_during_drain_declines_without_error_pin():
         got = eng.execute(q, ds)  # must not raise
     assert pc.triggered and pc.is_partial
     assert set(got.columns) == {"a", "b", "n", "s"}
-    # declined, never error-counted: no pin bookkeeping was touched
-    assert not eng._sparse_error_counts
+    # declined, not overflowed: the query shape is not pinned off the tier
     assert not eng._sparse_disabled
 
 
