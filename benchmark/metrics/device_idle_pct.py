@@ -1,0 +1,7 @@
+def read(window):
+    """Share of the traced window in which no operation ran on the
+    device.  No device trace (a CPU rehearsal): nothing to read."""
+    t = window.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
