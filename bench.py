@@ -181,8 +181,8 @@ def _ensure_calibration():
 
 
 def _stream_bw():
-    """The calibrated streaming bandwidth of THIS backend (roofline
-    denominator), or None before calibration."""
+    """The calibrated streaming bandwidth of THIS backend (a host-clock
+    slope, for the cost model's arithmetic), or None before calibration."""
     return _cal_key("stream_bytes_per_s")
 
 
@@ -197,19 +197,6 @@ def _cal_key(key):
             return _json.load(f).get(key)
     except Exception:
         return None
-
-
-def _with_roofline(metrics_dict, bw):
-    """Annotate a QueryMetrics dict with achieved-vs-streaming-bandwidth
-    utilization (the number that says whether the scan is memory-bound or
-    overhead-bound)."""
-    if metrics_dict is None:
-        return None
-    if bw:
-        metrics_dict["roofline_util_pct"] = round(
-            100.0 * metrics_dict.get("scan_bytes_per_sec", 0) / bw, 1
-        )
-    return metrics_dict
 
 
 def _span_tree(ctx):
@@ -344,7 +331,6 @@ def bench_ssb_streamed(scale: float):
     # rep (this host's memory subsystem has ~2x run-to-run variance)
     # polluted round-4's first SF100 q4_1 reading by 3x
     reps = 3
-    bw = _stream_bw()
     per_q, tpu_times, ratios, errs = {}, [], [], []
     for name in ssb.QUERIES:
         got = ctx.sql(ssb.QUERIES[name])  # warmup + parity in one
@@ -362,9 +348,8 @@ def bench_ssb_streamed(scale: float):
             "tpu_ms": round(t_tpu * 1e3, 2),
             "pandas_ms": round(t_pd[name] * 1e3, 2),
             "max_rel_err": round(err, 8),
-            "metrics": _with_roofline(
-                ctx.last_metrics.to_dict() if ctx.last_metrics else None,
-                bw,
+            "metrics": (
+                ctx.last_metrics.to_dict() if ctx.last_metrics else None
             ),
             "receipt": receipt,
             "receipt_wall_ms": receipt_wall,
@@ -410,7 +395,6 @@ def bench_ssb(scale: float):
     n_rows = ctx.catalog.get("lineorder").num_rows
 
     f = ssb.flat_frame(tables)
-    bw = _stream_bw()
     per_q = {}
     tpu_times, ratios = [], []
     for name in ssb.QUERIES:
@@ -422,9 +406,8 @@ def bench_ssb(scale: float):
         per_q[name] = {
             "tpu_ms": round(t_tpu * 1e3, 2),
             "pandas_ms": round(t_pd * 1e3, 2),
-            "metrics": _with_roofline(
-                ctx.last_metrics.to_dict() if ctx.last_metrics else None,
-                bw,
+            "metrics": (
+                ctx.last_metrics.to_dict() if ctx.last_metrics else None
             ),
             "receipt": receipt,
             "receipt_wall_ms": receipt_wall,

@@ -39,6 +39,8 @@ from .obs import (
     SPAN_FALLBACK,
     SPAN_PARTIAL,
     SPAN_PLAN,
+    SPAN_ROUTE,
+    SPAN_SQL_PARSE,
     Tracer,
     current_query_id,
     record_partial,
@@ -517,6 +519,35 @@ class TPUOlapContext:
             len(jax.devices()),
         )
 
+    def _plan_cached(self, sql_text: str):
+        """(rewrite, logical plan, is-EXPLAIN, RewriteError or None) of
+        one SQL statement, through the plan cache: the one place SQL text
+        is parsed and planned (`sql`, `sql_progressive` and the server's
+        lane classifier all come here, so a served request's second
+        lookup hits what its first one stored).  The `plan` span's own
+        time is the cache key and lookup, the plan build and the
+        star-join collapse; the parse is its `sql_parse` child, the cost
+        model's choice its `route` child (plan/planner.py).  The rewrite
+        is None for an EXPLAIN and for a plan the rewriter refused."""
+        with span(SPAN_PLAN) as sp:
+            key = self._plan_cache_key(sql_text)
+            cached = self._plan_cache.get(key)
+            if sp is not None:
+                sp.attrs["cache_hit"] = cached is not None
+            if cached is not None:
+                rw, lp = cached
+                return rw, lp, False, None
+            with span(SPAN_SQL_PARSE):
+                lp, explain, _ = parse_sql(sql_text, views=self.views)
+            if explain:
+                return None, lp, True, None
+            try:
+                rw = self._planner().plan(lp)
+            except RewriteError as err:
+                return None, lp, False, err
+            self._plan_cache[key] = (rw, lp)
+            return rw, lp, False, None
+
     def sql(self, sql_text: str):
         from .resilience import deadline_scope, partial_scope
         from .sql.commands import parse_command, run_command
@@ -536,29 +567,13 @@ class TPUOlapContext:
         ), deadline_scope(self.config.query_timeout_ms), partial_scope(
             self.config.partial_results
         ):
-            plan_err = None
-            with span(SPAN_PLAN):
-                key = self._plan_cache_key(sql_text)
-                cached = self._plan_cache.get(key)
-                if cached is not None:
-                    rw, lp = cached
-                else:
-                    lp, explain, out_names = parse_sql(
-                        sql_text, views=self.views
-                    )
-                    planner = self._planner()
-                    if explain:
-                        import pandas as pd
+            rw, lp, explain, plan_err = self._plan_cached(sql_text)
+            if explain:
+                import pandas as pd
 
-                        return pd.DataFrame(
-                            {"plan": planner.explain(lp).split("\n")}
-                        )
-                    try:
-                        rw = planner.plan(lp)
-                    except RewriteError as err:
-                        rw, plan_err = None, err
-                    else:
-                        self._plan_cache[key] = (rw, lp)
+                return pd.DataFrame(
+                    {"plan": self._planner().explain(lp).split("\n")}
+                )
             if rw is None:
                 return self._stamp_receipt(
                     self._stamp_partial(self._run_fallback(lp, plan_err))
@@ -567,8 +582,6 @@ class TPUOlapContext:
                 df = self._stamp_partial(
                     self._execute_with_resilience(rw, lp)
                 )
-            # receipt stamped OUTSIDE the execute span so its live
-            # snapshot sees the span closed (device/host split complete)
             return self._stamp_receipt(df)
 
     def sql_progressive(self, sql_text: str):
@@ -585,19 +598,9 @@ class TPUOlapContext:
 
         if parse_command(sql_text) is not None:
             return None
-        key = self._plan_cache_key(sql_text)
-        cached = self._plan_cache.get(key)
-        if cached is not None:
-            rw, _lp = cached
-        else:
-            lp, explain, _ = parse_sql(sql_text, views=self.views)
-            if explain:
-                return None
-            try:
-                rw = self._planner().plan(lp)
-            except RewriteError:
-                return None  # fallback shapes answer buffered
-            self._plan_cache[key] = (rw, lp)
+        rw, _lp, _explain, _err = self._plan_cached(sql_text)
+        if rw is None:
+            return None  # EXPLAIN and fallback shapes answer buffered
         if rw.exact_distinct is not None or rw.grouping_sets:
             return None
         q = rw.query
@@ -818,23 +821,17 @@ class TPUOlapContext:
         return df
 
     def _stamp_receipt(self, df):
-        """Stamp the query's cost receipt (obs/prof.py, ISSUE 9) onto
-        the answer: `df.attrs["receipt"]` (the SQL-surface contract) and
-        `QueryMetrics.receipt` — the live snapshot; the trace doc gets
-        the final recomputation at trace close.  A no-op outside a
-        trace (direct engine use without a context)."""
-        from .obs.prof import live_receipt
+        """Ask the active trace to stamp its cost receipt (obs/prof.py,
+        ISSUE 9) onto the answer when it closes: `df.attrs["receipt"]`
+        (the SQL-surface contract) and `QueryMetrics.receipt` hold the
+        receipt of the CLOSED trace, the one the trace doc carries —
+        built once.  A no-op outside a trace (direct engine use without
+        a context)."""
+        from .obs import current_trace
 
-        rc = live_receipt()
-        if rc is None:
-            return df
-        m = self.last_metrics
-        if m is not None:
-            m.receipt = rc
-        try:
-            df.attrs["receipt"] = rc
-        except AttributeError:  # fault-ok: non-pandas results skip attrs
-            pass
+        tr = current_trace()
+        if tr is not None:
+            tr.stamp_receipt_on(metrics=self.last_metrics, frame=df)
         return df
 
     def execute_native_degraded(
@@ -1222,7 +1219,8 @@ class TPUOlapContext:
                         self.serve.store_result(rw, ds, rkey, df)
                 return df
 
-        engine = self._engine_for(rw)
+        with span(SPAN_ROUTE):
+            engine = self._engine_for(rw)
         state = None
         fusable = self._fusable(rw, ds)
         fused = (

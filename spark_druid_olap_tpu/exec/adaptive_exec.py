@@ -39,6 +39,7 @@ device at full scan speed.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -47,7 +48,21 @@ import numpy as np
 
 from ..catalog.segment import DataSource
 from ..models import query as Q
-from ..resilience import DeadlineExceeded, current_partial, fire
+from ..obs import (
+    SCOPE_KEPT_REMAP,
+    SCOPE_PRESENCE,
+    SPAN_ADAPTIVE_KEPT,
+    SPAN_ADAPTIVE_PROBE,
+    SPAN_DEVICE_FETCH,
+    SPAN_FINALIZE,
+    SPAN_PROGRAM_LOOKUP,
+    SPAN_ROUTE,
+    device_scope,
+    prof,
+    span,
+    span_around,
+)
+from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
 from .lowering import (
@@ -261,17 +276,20 @@ def compacted_lowering(
             # precisely because |kept| is small, so this is the common case.
             def codes_fn(cols, base=d.codes_fn, kd_list=kd.tolist()):
                 c = base(cols)
-                acc = jnp.zeros(c.shape, jnp.int32)
-                for i, k in enumerate(kd_list):
-                    acc = acc + jnp.where(c == k, jnp.int32(i + 1), 0)
-                return acc - 1  # absent codes -> -1, same as the LUT
+                with device_scope(SCOPE_KEPT_REMAP):
+                    acc = jnp.zeros(c.shape, jnp.int32)
+                    for i, k in enumerate(kd_list):
+                        acc = acc + jnp.where(c == k, jnp.int32(i + 1), 0)
+                    return acc - 1  # absent codes -> -1, same as the LUT
         else:
             lut = np.full(d.cardinality, -1, np.int32)
             lut[kd] = np.arange(len(kd), dtype=np.int32)
             lut_dev = jnp.asarray(lut)
 
             def codes_fn(cols, base=d.codes_fn, lut_dev=lut_dev):
-                return lut_dev[base(cols)]
+                c = base(cols)
+                with device_scope(SCOPE_KEPT_REMAP):
+                    return lut_dev[c]
 
         def decode(codes, base=d.decode, kd=kd):
             return base(kd[np.asarray(codes, dtype=np.int64)])
@@ -330,6 +348,7 @@ class AdaptiveDomainMixin:
             strat = "pallas"
         return strat
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _presence_program(self, q, ds, lowering: GroupByLowering):
         """Fused per-segment program: presence COUNTS per grouping dim under
         the query's row mask — one data read covers every dim."""
@@ -338,8 +357,6 @@ class AdaptiveDomainMixin:
 
         pallas_ok = pallas_available()
         key = _query_key(q, ds) + ("adaptive-presence", pallas_ok)
-        from ..obs import prof
-
         cached = self._query_fn_cache.get(key)
         if cached is not None:
             prof.note_program_cache("adaptive-presence", hit=True)
@@ -371,11 +388,12 @@ class AdaptiveDomainMixin:
                 zero_mmm = jnp.zeros((ones.shape[0], 0), jnp.bool_)
                 per = []
                 for d, strat in zip(lowering.dims, strategies):
-                    s, _, _ = partial_aggregate(
-                        d.codes_fn(cols), mask, ones, zero_mm, zero_mmm,
-                        num_groups=d.cardinality, num_min=0, num_max=0,
-                        strategy=strat,
-                    )
+                    with device_scope(SCOPE_PRESENCE):
+                        s, _, _ = partial_aggregate(
+                            d.codes_fn(cols), mask, ones, zero_mm, zero_mmm,
+                            num_groups=d.cardinality, num_min=0, num_max=0,
+                            strategy=strat,
+                        )
                     per.append(s[:, 0])
                 counts = (
                     per
@@ -391,7 +409,12 @@ class AdaptiveDomainMixin:
         self, q, ds, lowering: GroupByLowering, segs
     ) -> Optional[List[np.ndarray]]:
         """Phase A: measure (or recall) per-dim present code sets.  Returns
-        None when compaction should be declined for this query."""
+        None when compaction should be declined for this query.
+
+        The `adaptive_kept` span's own time is the memo lookup, the
+        dictionary derivation and the host `nonzero` over the presence
+        counts; the presence dispatches are its `adaptive_probe` children
+        (phase A), so a repeat shows none."""
         # The memo keys segment-set-independently (lowering.memo_key) so
         # continuous streamed ingest neither forgets query shapes nor
         # leaks one entry per published delta — but a MEASURED kept set
@@ -404,56 +427,9 @@ class AdaptiveDomainMixin:
         # extension, which changes the memo key, retires them).
         qkey = memo_key(q, ds)
         seg_sig = tuple(s.uid for s in segs)
-        entry = self._adaptive_kept.get(qkey)
-        kept = None
-        if entry is not None:
-            if entry[0] == "derived":
-                kept = entry[1]
-            elif entry[1] == seg_sig:
-                kept = entry[2]
-        if kept is None:
-            # dictionary-derived shortcut: when the filter itself pins
-            # every grouping dim, phase A needs NO device pass at all —
-            # O(cardinality) host work over the dictionaries replaces the
-            # full presence scan (and its dispatch round-trip)
-            kept = filter_derived_kept(q, lowering, ds)
-            if kept is not None:
-                self._adaptive_kept[qkey] = ("derived", kept)
-        if kept is None:
+
+        def measure():
             need = self._presence_columns(q, lowering, ds)
-
-            def run_presence():
-                from ..obs import SPAN_ADAPTIVE_PROBE, span
-                from ..resilience import checkpoint
-
-                seg_fn = self._presence_program(q, ds, lowering)
-                counts = None
-                for bi, batch in enumerate(
-                    self._segment_batches(segs, need)
-                ):
-                    # phase A dispatches the full segment scope too: a
-                    # deadlined query cancels between presence batches
-                    # (checkpoint-coverage/GL901)
-                    checkpoint("adaptive.presence_loop")
-                    with span(SPAN_ADAPTIVE_PROBE, batch=bi):
-                        import time as _time
-
-                        from ..obs import prof
-
-                        cols_list = [
-                            self._cols_for_segment(seg, ds, need)
-                            for seg in batch
-                        ]
-                        t_call = _time.perf_counter()
-                        out = seg_fn(cols_list)
-                        out = prof.dispatch_sync(out, t_call)
-                    counts = (
-                        out
-                        if counts is None
-                        else [a + b for a, b in zip(counts, out)]
-                    )
-                return counts
-
             # fault-injection site: phase A dispatches the presence
             # program to the device (phase B goes through the engine's
             # _call_segment_program, which has its own site).  A failure
@@ -461,26 +437,69 @@ class AdaptiveDomainMixin:
             # retry machinery like any other device error: it is never a
             # reason to hand the query to another tier.
             fire("device_dispatch")
-            counts = run_presence()
-            kept = [
+            seg_fn = self._presence_program(q, ds, lowering)
+            counts = None
+            for bi, batch in enumerate(self._segment_batches(segs, need)):
+                # phase A dispatches the full segment scope too: a
+                # deadlined query cancels between presence batches
+                # (checkpoint-coverage/GL901)
+                checkpoint("adaptive.presence_loop")
+                with span(SPAN_ADAPTIVE_PROBE, batch=bi, phase="A"):
+                    cols_list = [
+                        self._cols_for_segment(seg, ds, need)
+                        for seg in batch
+                    ]
+                    t_call = time.perf_counter()
+                    out = seg_fn(cols_list)
+                    out = prof.dispatch_sync(out, t_call)
+                counts = (
+                    out
+                    if counts is None
+                    else [a + b for a, b in zip(counts, out)]
+                )
+            return [
                 np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
                 for c in counts
             ]
-            self._adaptive_kept[qkey] = ("measured", seg_sig, kept)
-        Gc = 1
-        for kd in kept:
-            Gc *= len(kd)
-        if Gc > ADAPTIVE_MAX_COMPACT_GROUPS or (
-            Gc > ADAPTIVE_MIN_SHRINK * lowering.num_groups
-        ):
-            log.info(
-                "adaptive compaction declined: G'=%d of G=%d",
-                Gc, lowering.num_groups,
+
+        with span(SPAN_ADAPTIVE_KEPT) as sp:
+            entry = self._adaptive_kept.get(qkey)
+            kept, source = None, "memo"
+            if entry is not None:
+                if entry[0] == "derived":
+                    kept = entry[1]
+                elif entry[1] == seg_sig:
+                    kept = entry[2]
+            if kept is None:
+                # dictionary-derived shortcut: when the filter itself pins
+                # every grouping dim, phase A needs NO device pass at all —
+                # O(cardinality) host work over the dictionaries replaces
+                # the full presence scan (and its dispatch round-trip)
+                kept, source = filter_derived_kept(q, lowering, ds), "derived"
+                if kept is not None:
+                    self._adaptive_kept[qkey] = ("derived", kept)
+            if kept is None:
+                kept, source = measure(), "measured"
+                self._adaptive_kept[qkey] = ("measured", seg_sig, kept)
+            Gc = 1
+            for kd in kept:
+                Gc *= len(kd)
+            declined = Gc > ADAPTIVE_MAX_COMPACT_GROUPS or (
+                Gc > ADAPTIVE_MIN_SHRINK * lowering.num_groups
             )
-            self._adaptive_declined.add(qkey)
-            self._adaptive_kept.pop(qkey, None)
-            return None
-        return kept
+            if sp is not None:
+                sp.attrs.update(
+                    source=source, compact_groups=Gc, declined=declined
+                )
+            if declined:
+                log.info(
+                    "adaptive compaction declined: G'=%d of G=%d",
+                    Gc, lowering.num_groups,
+                )
+                self._adaptive_declined.add(qkey)
+                self._adaptive_kept.pop(qkey, None)
+                return None
+            return kept
 
     def _dispatch_groupby_adaptive(
         self, q: Q.GroupByQuery, ds: DataSource, lowering: GroupByLowering
@@ -537,21 +556,29 @@ class AdaptiveDomainMixin:
         # CPU backend is the wrong side of a ~200x inversion (measured:
         # a 60M-row phase B at G'=600 ran 49 s dense vs sub-second
         # scatter; on TPU the same choice lands on Pallas/dense)
-        strat = self._adaptive_main_strategy(ds, clow.num_groups)
+        with span(SPAN_ROUTE, tier="adaptive"):
+            strat = self._adaptive_main_strategy(ds, clow.num_groups)
         state = self._partials_for_query(
             q, ds, lowering=clow, key_extra=("adaptive",) + cards,
-            strategy_override=strat,
+            strategy_override=strat, span_attrs={"phase": "B"},
         )
 
         def resolve():
             dims, la, G, sums, mins, maxs, sketch_states = state
-            sums, mins, maxs, sketch_states = jax.device_get(
-                (sums, mins, maxs, sketch_states)
-            )
-            return finalize_groupby(
-                q, dims, la,
-                np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                {k: np.asarray(v) for k, v in sketch_states.items()},
-            )
+            with span(SPAN_DEVICE_FETCH):
+                prof.fetch_sync((sums, mins, maxs, sketch_states))
+                sums, mins, maxs, sketch_states = jax.device_get(
+                    (sums, mins, maxs, sketch_states)
+                )
+            t0 = time.perf_counter()
+            with span(SPAN_FINALIZE):
+                out = finalize_groupby(
+                    q, dims, la,
+                    np.asarray(sums), np.asarray(mins), np.asarray(maxs),
+                    {k: np.asarray(v) for k, v in sketch_states.items()},
+                )
+            if self._m is not None:
+                self._m.finalize_ms = (time.perf_counter() - t0) * 1e3
+            return out
 
         return resolve

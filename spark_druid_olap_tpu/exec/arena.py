@@ -58,7 +58,15 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..obs import SPAN_ARENA_BUILD, SPAN_SEGMENT_DISPATCH, prof, span
+from ..obs import (
+    SCOPE_ARENA_SCAN,
+    SCOPE_CARRY_MERGE,
+    SPAN_ARENA_BUILD,
+    SPAN_SEGMENT_DISPATCH,
+    device_scope,
+    prof,
+    span,
+)
 from ..resilience import checkpoint_partial, current_deadline, fire
 from ..utils.log import get_logger
 
@@ -325,13 +333,16 @@ def build_arena_program(lowerings, strategies, share=None):
                     memo=memo if share is not None else None,
                     share=share[i] + (0,) if share is not None else None,
                 )
-                out.append(
-                    _fold_block(
-                        c[i], (s, mn, mx), start_b, memb_b[i]
+                with device_scope(SCOPE_CARRY_MERGE):
+                    out.append(
+                        _fold_block(
+                            c[i], (s, mn, mx), start_b, memb_b[i]
+                        )
                     )
-                )
             return tuple(out), None
-        c2, _ = lax.scan(body, carry, (cols, start, memb))
+
+        with device_scope(SCOPE_ARENA_SCAN):
+            c2, _ = lax.scan(body, carry, (cols, start, memb))
         return c2
 
     # pure builder: every caller (Engine._arena_program /
@@ -394,7 +405,7 @@ def _chunk_bounds(plan: ArenaPlan, site: str = "") -> List[Tuple[int, int, int]]
 def run_plan(
     engine, ds, plan: ArenaPlan, names, program, lowerings,
     memb: Optional[np.ndarray] = None, pc=None, checkpoint_site="",
-    single_chunk: bool = False,
+    single_chunk: bool = False, span_attrs: Optional[dict] = None,
 ):
     """Build/fetch the stacked columns, then dispatch the scan program
     over the plan's chunks.  Returns (carries, batches_folded) — the
@@ -402,8 +413,9 @@ def run_plan(
     folded (fewer than planned on a deadline/partial truncation).
 
     The stack build lives under the `arena_build` receipt bucket; each
-    chunk dispatch is a `segment_dispatch` span, so `dispatch_count`
-    and the device/transfer attribution stay honest."""
+    chunk dispatch is a `segment_dispatch` span (with `span_attrs`, the
+    caller's word on what the dispatch is), so `dispatch_count` and the
+    device/transfer attribution stay honest."""
     import time as _time
 
     import jax.numpy as jnp
@@ -422,12 +434,7 @@ def run_plan(
         SPAN_ARENA_BUILD, blocks=len(plan.segs), batches=len(plan.batches),
     ):
         cols = stacked_cols(engine, ds, plan, names)
-    start = jnp.asarray(plan.start)
-    if memb is None:
-        memb_arr = jnp.ones((len(plan.segs), 1), dtype=bool)
-    else:
-        memb_arr = jnp.asarray(memb)
-    carries = tuple(_member_init(lw) for lw in lowerings)
+    start = memb_arr = carries = None
     # the fused path forces one chunk: its deadline contract is checked
     # once up front by the caller and an expiry re-routes members to
     # their serial partial-capable paths — no mid-scan truncation
@@ -441,7 +448,6 @@ def run_plan(
         # ci == 0 was checkpointed above, before the build
         if ci and checkpoint_site and checkpoint_partial(checkpoint_site):
             break
-        xs_cols = {n: a[lo:hi] for n, a in cols.items()}
         # the same fault-injection site every loop-path dispatch fires:
         # an injected (or real pre-dispatch) transient fault walks the
         # retry/breaker machinery whether or not the arena is on
@@ -451,7 +457,19 @@ def run_plan(
             SPAN_SEGMENT_DISPATCH,
             arena=hi - lo,
             chunk=f"{ci + 1}/{len(chunks)}",
+            **(span_attrs or {}),
         ):
+            # the program's other arguments (flags, the zero carry) and
+            # the chunk's slice of every stacked column are part of the
+            # launch: small eager device arrays, a launch each
+            if carries is None:
+                start = jnp.asarray(plan.start)
+                if memb is None:
+                    memb_arr = jnp.ones((len(plan.segs), 1), dtype=bool)
+                else:
+                    memb_arr = jnp.asarray(memb)
+                carries = tuple(_member_init(lw) for lw in lowerings)
+            xs_cols = {n: a[lo:hi] for n, a in cols.items()}
             # first call of a newly-built program = trace+compile:
             # attribute it exactly like _call_segment_program does
             t0 = (

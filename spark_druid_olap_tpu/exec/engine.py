@@ -64,15 +64,20 @@ from .finalize import (  # noqa: F401
     finalize_topn,
 )
 from ..obs import (
+    SCOPE_CARRY_MERGE,
     SPAN_DEVICE_FETCH,
     SPAN_FINALIZE,
     SPAN_H2D,
     SPAN_LOWER,
+    SPAN_PROGRAM_LOOKUP,
+    SPAN_ROUTE,
     SPAN_SEGMENT_DISPATCH,
     current_query_id,
     prof,
     record_query_metrics,
     span,
+    span_around,
+    device_scope,
 )
 from ..resilience import checkpoint, checkpoint_partial, current_partial, fire
 from ..utils.log import get_logger
@@ -737,6 +742,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         key_extra=(),
         strategy_override=None,
         segs=None,
+        span_attrs=None,
     ):
         """Compute merged partial state across local segments.
 
@@ -744,7 +750,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         over a rewritten lowering (adaptive domain compaction passes the
         compacted cardinalities).  `segs` overrides the scanned segment
         list (already scope-pruned) — the delta-aware result cache passes
-        just the freshly-appended segments.
+        just the freshly-appended segments.  `span_attrs` go on every
+        dispatch span (the adaptive tier marks its phase B with them).
 
         Returns (dims, la, G, sums[G, Ms], mins, maxs, sketch_states)."""
         if lowering is None:
@@ -803,6 +810,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # below with the fold continuing in canonical order, so results
         # stay byte-identical arena-on vs arena-off.  Sketch aggs decline
         # (their merge states carry no exact in-scan fold identity).
+        phase = span_attrs or {}
         plan = run = None
         if self.arena_execution and not la.sketch_aggs:
             from . import arena as _arena
@@ -829,7 +837,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             # the dispatch loop's
             carries, _done = _arena.run_plan(
                 self, ds, plan, need, program, [lowering], pc=pc,
-                checkpoint_site="engine.segment_loop",
+                checkpoint_site="engine.segment_loop", span_attrs=phase,
             )
             batches = plan.remainder
             if plan.folded:
@@ -869,7 +877,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     self._cols_for_segment(seg, ds, need) for seg in batch
                 ]
             run.advance(pos)
-            with span(SPAN_SEGMENT_DISPATCH, batch=bi, segments=len(batch)):
+            with span(
+                SPAN_SEGMENT_DISPATCH, batch=bi, segments=len(batch), **phase
+            ):
                 s, mn, mx, sk = self._call_segment_program(
                     seg_fn, cols_list
                 )
@@ -941,6 +951,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             return resolve_strategy("auto", num_groups)
         return resolve_strategy(self.strategy, num_groups)
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _segment_program(
         self,
         q: Q.GroupByQuery,
@@ -981,15 +992,17 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             sketch_states: Dict[str, Any] = {}
             for cols in cols_list:
                 s, mn, mx, sk = _segment_partials(lowering, strategy, cols)
-                sums = s if sums is None else sums + s
-                mins = mn if mins is None else jnp.minimum(mins, mn)
-                maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-                _merge_sketch_states(la, sketch_states, sk)
+                with device_scope(SCOPE_CARRY_MERGE):
+                    sums = s if sums is None else sums + s
+                    mins = mn if mins is None else jnp.minimum(mins, mn)
+                    maxs = mx if maxs is None else jnp.maximum(maxs, mx)
+                    _merge_sketch_states(la, sketch_states, sk)
             return sums, mins, maxs, sketch_states
 
         self._query_fn_cache[key] = seg_fn
         return seg_fn
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_program(
         self, q, ds, lowering, strategy: str, key_extra=()
     ) -> Callable:
@@ -1286,6 +1299,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         self.last_metrics = out[-1][2] if out else None
         return out
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _fused_program(self, members, ds, strategies, sel) -> Callable:
         """One jitted program computing EVERY member's partial state over
         one segment batch.  Cached in the engine's program cache under the
@@ -1337,16 +1351,18 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                         lowering, strategies[i], cols_list[j],
                         memo=memo, share=share[i] + (j,),
                     )
-                    sums = s if sums is None else sums + s
-                    mins = mn if mins is None else jnp.minimum(mins, mn)
-                    maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-                    _merge_sketch_states(lowering.la, sk, skj)
+                    with device_scope(SCOPE_CARRY_MERGE):
+                        sums = s if sums is None else sums + s
+                        mins = mn if mins is None else jnp.minimum(mins, mn)
+                        maxs = mx if maxs is None else jnp.maximum(maxs, mx)
+                        _merge_sketch_states(lowering.la, sk, skj)
                 outs.append((sums, mins, maxs, sk))
             return outs
 
         self._query_fn_cache[key] = fused_fn
         return fused_fn
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_fused_program(self, members, ds, strategies) -> Callable:
         """The one-dispatch arena program for a fused micro-batch (exec/
         arena.py): every member's fold over the stacked scope inside one
@@ -1582,9 +1598,24 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # streamed append neither forgets learned rungs nor grows the
         # memo dicts per batch
         qkey = memo_key(q, ds)
+        # which tier gets the query: adaptive first (it covers sketch
+        # aggs too, and repeats skip its presence pass via the kept-set
+        # memo), then sparse, else the dense partials path at `kernel`
+        with span(SPAN_ROUTE):
+            kernel = self._resolve_strategy(lowering.num_groups)
+            try_adaptive = bool(
+                segs
+                and self._adaptive_eligible(lowering)
+                and qkey not in self._adaptive_declined
+            )
+            try_sparse = bool(
+                segs
+                and self._sparse_eligible(lowering)
+                and qkey not in self._sparse_disabled
+            )
         m = self._m = QueryMetrics(
             query_type="groupBy",
-            strategy=self._resolve_strategy(lowering.num_groups),
+            strategy=kernel,
             datasource=ds.name,
             query_id=current_query_id(),
             rows_scanned=sum(s.num_rows for s in segs),
@@ -1632,25 +1663,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         sparse_resolve = None
         dense_state = None
         try:
-            # adaptive dictionary-domain compaction first: it covers sketch
-            # aggs too and repeats skip its presence pass via the kept-set
-            # cache.  A None return means it declined at dispatch time and
-            # the sparse/dense paths proceed as before.
-            if (
-                self._adaptive_eligible(lowering)
-                and segs
-                and qkey not in self._adaptive_declined
-            ):
+            # a None return from the adaptive tier means it declined at
+            # dispatch time and the sparse/dense paths proceed
+            if try_adaptive:
                 adaptive_resolve = self._dispatch_groupby_adaptive(
                     q, ds, lowering
                 )
                 if adaptive_resolve is not None:
                     m.strategy = "adaptive"
-            if adaptive_resolve is None and (
-                self._sparse_eligible(lowering)
-                and segs
-                and qkey not in self._sparse_disabled
-            ):
+            if adaptive_resolve is None and try_sparse:
                 m.strategy = "sparse"
                 sparse_resolve = self._dispatch_groupby_sparse(
                     q, ds, lowering

@@ -30,6 +30,7 @@ from ..models import aggregations as A
 from ..models import query as Q
 from ..models.dimensions import DimensionSpec
 from ..models.filters import Filter
+from ..obs import SCOPE_AGG_INPUTS, SCOPE_FILTER, SCOPE_GROUP_KEYS, device_scope
 from ..ops.filters import DecodedView, compile_filter
 from ..ops.groupby import combine_group_ids
 from ..plan.expr import compile_expr
@@ -368,6 +369,7 @@ class GroupByLowering:
     # vcol names that are ALSO read by a vcol expression (physical shadow)
     shadowed_inputs: frozenset = frozenset()
 
+    @device_scope(SCOPE_AGG_INPUTS)
     def add_virtual(self, cols: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
         """Compute virtual columns from the PHYSICAL inputs.  Idempotent:
         a virtual column that shadows a physical column it reads saves the
@@ -396,6 +398,7 @@ class GroupByLowering:
                 inputs[name] = out
         return cols
 
+    @device_scope(SCOPE_FILTER)
     def row_mask(self, cols) -> jnp.ndarray:
         mask = cols["__valid"]
         q = self.query
@@ -431,37 +434,43 @@ class GroupByLowering:
             mask = self.row_mask(cols)
         la = self.la
         if gid is None:
-            gid, _ = combine_group_ids(
-                [d.codes_fn(cols) for d in self.dims],
-                [d.cardinality for d in self.dims],
-            )
-            if not self.dims:
-                gid = jnp.zeros(mask.shape, jnp.int32)
-        R = mask.shape[0]
-        maskf = mask.astype(jnp.float32)
-        sum_cols = []
-        for n in la.sum_names:
-            base = la.value_fns[n](cols) if la.value_fns[n] is not None else None
-            v = maskf if base is None else base * maskf
-            mfn = la.mask_fns.get(n)
-            if mfn is not None:
-                v = v * mfn(cols).astype(jnp.float32)
-            sum_cols.append(v)
-        sum_values = jnp.stack(sum_cols, axis=1)
-        mm_names = la.min_names + la.max_names
-        if mm_names:
-            mm_vals, mm_masks = [], []
-            for n in mm_names:
-                mm_vals.append(la.value_fns[n](cols))
-                mfn = la.mask_fns.get(n)
-                mm_masks.append(
-                    mfn(cols) if mfn is not None else jnp.ones((R,), jnp.bool_)
+            with device_scope(SCOPE_GROUP_KEYS):
+                gid, _ = combine_group_ids(
+                    [d.codes_fn(cols) for d in self.dims],
+                    [d.cardinality for d in self.dims],
                 )
-            minmax_values = jnp.stack(mm_vals, axis=1)
-            minmax_masks = jnp.stack(mm_masks, axis=1)
-        else:
-            minmax_values = jnp.zeros((R, 0), jnp.float32)
-            minmax_masks = jnp.zeros((R, 0), jnp.bool_)
+                if not self.dims:
+                    gid = jnp.zeros(mask.shape, jnp.int32)
+        with device_scope(SCOPE_AGG_INPUTS):
+            R = mask.shape[0]
+            maskf = mask.astype(jnp.float32)
+            sum_cols = []
+            for n in la.sum_names:
+                base = (
+                    la.value_fns[n](cols)
+                    if la.value_fns[n] is not None else None
+                )
+                v = maskf if base is None else base * maskf
+                mfn = la.mask_fns.get(n)
+                if mfn is not None:
+                    v = v * mfn(cols).astype(jnp.float32)
+                sum_cols.append(v)
+            sum_values = jnp.stack(sum_cols, axis=1)
+            mm_names = la.min_names + la.max_names
+            if mm_names:
+                mm_vals, mm_masks = [], []
+                for n in mm_names:
+                    mm_vals.append(la.value_fns[n](cols))
+                    mfn = la.mask_fns.get(n)
+                    mm_masks.append(
+                        mfn(cols) if mfn is not None
+                        else jnp.ones((R,), jnp.bool_)
+                    )
+                minmax_values = jnp.stack(mm_vals, axis=1)
+                minmax_masks = jnp.stack(mm_masks, axis=1)
+            else:
+                minmax_values = jnp.zeros((R, 0), jnp.float32)
+                minmax_masks = jnp.zeros((R, 0), jnp.bool_)
         return gid, mask, sum_values, minmax_values, minmax_masks
 
 

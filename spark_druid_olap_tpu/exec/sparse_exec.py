@@ -22,6 +22,7 @@ import numpy as np
 
 from ..catalog.segment import DataSource
 from ..models import query as Q
+from ..obs import SPAN_PROGRAM_LOOKUP, span_around
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
 from .lowering import GroupByLowering, _query_key, memo_key
@@ -59,6 +60,7 @@ class SparseExecMixin:
             and (auto_upgrade or self.strategy in ("sparse", "adaptive"))
         )
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _sparse_program(
         self,
         q: Q.GroupByQuery,

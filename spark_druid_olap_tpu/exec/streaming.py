@@ -38,6 +38,7 @@ import numpy as np
 
 from ..catalog.segment import NULL_ID, ROW_PAD, DataSource
 from ..models import query as Q
+from ..obs import SPAN_PROGRAM_LOOKUP, span_around
 from .engine import (
     Engine,
     _merge_sketch_states,
@@ -316,6 +317,7 @@ class StreamExecutor:
                 strat = "pallas"
         return strat
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _fused_local_fn(self, q, ds, lowering, prep, strat=None):
         """One jitted program per (query, chunk shape): prep + partial
         aggregation, cached on the engine's program cache so repeats and

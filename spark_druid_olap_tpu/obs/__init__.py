@@ -33,6 +33,17 @@ from .registry import (  # noqa: F401
 )
 from . import prof  # noqa: F401  (performance attribution, ISSUE 9)
 from .trace import (  # noqa: F401
+    SCOPE_AGG_INPUTS,
+    SCOPE_ARENA_SCAN,
+    SCOPE_CARRY_MERGE,
+    SCOPE_FILTER,
+    SCOPE_GROUP_KEYS,
+    SCOPE_KEPT_REMAP,
+    SCOPE_NAMES,
+    SCOPE_PARTIAL_AGG,
+    SCOPE_PRESENCE,
+    SCOPE_SPARSE_SORT,
+    SPAN_ADAPTIVE_KEPT,
     SPAN_ADAPTIVE_PROBE,
     SPAN_ADMISSION,
     SPAN_ARENA_BUILD,
@@ -49,6 +60,7 @@ from .trace import (  # noqa: F401
     SPAN_FUSED_BATCH,
     SPAN_GATHER,
     SPAN_H2D,
+    SPAN_HTTP_READ,
     SPAN_INGEST,
     SPAN_INGEST_ENCODE,
     SPAN_LANE,
@@ -57,13 +69,17 @@ from .trace import (  # noqa: F401
     SPAN_PARTIAL,
     SPAN_PLAN,
     SPAN_PREFETCH,
+    SPAN_PROGRAM_LOOKUP,
     SPAN_QUERY,
+    SPAN_RESPOND,
     SPAN_RETRY,
     SPAN_ROLLUP,
+    SPAN_ROUTE,
     SPAN_SCATTER,
     SPAN_SEGMENT_DISPATCH,
     SPAN_SNAPSHOT_FLUSH,
     SPAN_SPARSE_DISPATCH,
+    SPAN_SQL_PARSE,
     SPAN_STREAM_CHUNK,
     SPAN_STREAM_FLUSH,
     SPAN_WAL_APPEND,
@@ -75,8 +91,10 @@ from .trace import (  # noqa: F401
     current_query_id,
     current_trace,
     default_tracer,
+    device_scope,
     new_query_id,
     span,
+    span_around,
     span_event,
     span_in,
 )

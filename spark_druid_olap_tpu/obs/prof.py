@@ -23,12 +23,16 @@ the numbers HONEST and turns them into per-query cost receipts:
   * **Per-query cost receipts** — `build_receipt` folds a finished span
     tree into {device_ms, host_ms, transfer_ms, unattributed_ms, ...}
     by summing each span's EXCLUSIVE time (duration minus children)
-    into a bucket by span name.  Only the root `query` span's exclusive
-    time is unattributed, so `device + host + transfer` vs `wall` is a
-    real claim about lifecycle coverage, not an identity.  Receipts are
-    stamped into the trace doc (served at `/druid/v2/trace/{id}`),
-    `QueryMetrics.receipt`, `df.attrs["receipt"]`, and — on sampled
-    queries — the `X-Druid-Response-Context` header.
+    into a bucket by span name, and keeps the same exclusive times by
+    name under `spans` ({name: {n, self_ms}}, adding up to `wall_ms`).
+    Only the root `query` span's exclusive time is unattributed, so
+    `device + host + transfer` vs `wall` is a real claim about
+    lifecycle coverage, not an identity.  The receipt is built ONCE,
+    when the trace closes, and stamped into the trace doc (served at
+    `/druid/v2/trace/{id}`), `QueryMetrics.receipt` and
+    `df.attrs["receipt"]`; a sampled query's `X-Druid-Response-Context`
+    header and a progressive stream's last line, which leave before
+    the close, carry a provisional one (`live_receipt`).
   * **Workload profiler** — a process-wide rolling window of finished
     queries behind `GET /status/profile`: top-K by device time,
     per-family compile totals, per-lane SLO burn-rate against the
@@ -49,7 +53,12 @@ from typing import Any, Dict, List, Optional
 
 from ..utils.log import get_logger
 from .registry import bounded_label, get_registry
-from .trace import current_query_id, current_span, current_trace
+from .trace import (
+    SPAN_PROGRAM_LOOKUP,
+    current_query_id,
+    current_span,
+    current_trace,
+)
 
 log = get_logger("obs.prof")
 
@@ -366,6 +375,14 @@ def note_program_cache(family: str, hit: bool) -> None:
         c[0 if hit else 1] += 1
         if not hit:
             ps.pending_family = family
+        # the lookups run inside a `program_lookup` span: say which
+        # family it looked up, and that a miss leaves a program to build
+        # (its compile is paid by the first call, in the dispatch span)
+        s = current_span()
+        if s is not None and s.name == SPAN_PROGRAM_LOOKUP:
+            s.attrs["family"] = family
+            if not hit:
+                s.attrs["compile"] = True
 
 
 def note_compile(ms: float, family: Optional[str] = None) -> None:
@@ -428,7 +445,18 @@ def _is_overlay(node: dict) -> bool:
     )
 
 
-def _walk_exclusive(node: dict, acc: Dict[str, float], depth: int) -> None:
+def _new_acc() -> Dict[str, Any]:
+    return {
+        "device": 0.0, "transfer": 0.0, "prefetch": 0.0, "host": 0.0,
+        "arena_build": 0.0, "unattributed": 0.0, "dispatch_count": 0,
+        "scatter": 0.0, "gather": 0.0, "cluster_merge": 0.0,
+        # span name -> [count, exclusive ms]: the same exclusive times
+        # as the buckets, kept by name (the per-layer metrics read these)
+        "spans": {},
+    }
+
+
+def _walk_exclusive(node: dict, acc: Dict[str, Any], depth: int) -> None:
     if _is_overlay(node):
         # concurrent overlay / remote clock: handled by
         # _walk_cluster_nodes into per-node attribution, never the
@@ -441,6 +469,9 @@ def _walk_exclusive(node: dict, acc: Dict[str, float], depth: int) -> None:
     child_sum = sum(float(c.get("duration_ms", 0.0)) for c in children)
     excl = max(0.0, dur - child_sum)
     name = str(node.get("name", ""))
+    by_name = acc["spans"].setdefault(name, [0, 0.0])
+    by_name[0] += 1
+    by_name[1] += excl
     if name in DISPATCH_SPANS:
         acc["dispatch_count"] += 1
     if depth == 0 and name == ROOT_SPAN:
@@ -478,11 +509,7 @@ def _fold_remote_buckets(graft: dict) -> Dict[str, float]:
             "host_ms": float(rc.get("host_ms", 0.0) or 0.0),
             "remote_wall_ms": float(rc.get("wall_ms", 0.0) or 0.0),
         }
-    acc = {
-        "device": 0.0, "transfer": 0.0, "prefetch": 0.0, "host": 0.0,
-        "arena_build": 0.0, "unattributed": 0.0, "dispatch_count": 0,
-        "scatter": 0.0, "gather": 0.0, "cluster_merge": 0.0,
-    }
+    acc = _new_acc()
     clean = dict(graft)
     attrs = dict(clean.get("attrs") or {})
     attrs.pop("remote", None)
@@ -567,11 +594,7 @@ def build_receipt(
     """Fold one trace document (obs.trace.QueryTrace.to_dict shape) into
     a cost receipt.  Pure function of the doc + scope counters, so it
     can run live (mid-query, provisional span ends) or at trace close."""
-    acc = {
-        "device": 0.0, "transfer": 0.0, "prefetch": 0.0, "host": 0.0,
-        "arena_build": 0.0, "unattributed": 0.0, "dispatch_count": 0,
-        "scatter": 0.0, "gather": 0.0, "cluster_merge": 0.0,
-    }
+    acc = _new_acc()
     cluster_nodes: Dict[str, Dict[str, Any]] = {}
     root = trace_doc.get("spans")
     if isinstance(root, dict):
@@ -597,6 +620,13 @@ def build_receipt(
         # device program launches this query paid (DISPATCH_SPANS): the
         # number the one-dispatch arena acceptance criterion reads
         "dispatch_count": int(acc["dispatch_count"]),
+        # exclusive time by span name; the `self_ms` add up to `wall_ms`
+        # (every span's children are taken out of it and counted in
+        # their own names), so nothing of the request is left unnamed
+        "spans": {
+            name: {"n": n, "self_ms": round(ms, 3)}
+            for name, (n, ms) in acc["spans"].items()
+        },
         "overlap_efficiency": (
             round(acc["device"] / busy_stall, 4) if busy_stall > 0 else 1.0
         ),
@@ -649,9 +679,10 @@ def build_receipt(
 
 def live_receipt() -> Optional[dict]:
     """Receipt of the ACTIVE query so far (unfinished spans measured to
-    'now' under the tracer's own clock) — what df.attrs, QueryMetrics,
-    and the response-context header carry; the trace doc gets the final
-    recomputation at close.  None outside a trace."""
+    'now' under the tracer's own clock) — for what leaves before the
+    trace closes: a sampled query's response-context header and a
+    progressive stream's last line.  The trace doc, QueryMetrics and
+    df.attrs get the closed trace's.  None outside a trace."""
     tr = current_trace()
     if tr is None:
         return None

@@ -20,9 +20,14 @@ deadline die?"; this module can:
     stamped onto `QueryMetrics.query_id`.
   * **Instrumentation that disappears when idle** — `span(name)` costs
     one contextvar read when no trace is active; with a trace it is two
-    clock reads and two list/lock operations.  The clock is injectable
-    (tests assert tracer overhead by *counting* clock calls, never by
-    timing wall-clock).
+    clock reads, two list/lock operations and one flag test.  The clock
+    is injectable (tests assert tracer overhead by *counting* clock
+    calls, never by timing wall-clock).
+  * **One timeline with the device** — while a `jax.profiler` session
+    is open (`exec/metrics.trace(logdir)`, the benchmark's `--trace 1`)
+    every span also writes itself into the profiler's trace as
+    `sdol:<name>`, on the clock of the device's `XLA Ops`; the traced
+    programs name their parts with `device_scope(SCOPE_*)`.
   * **Trace ring buffer** — finished traces serialize to JSON and land
     in a bounded FIFO ring served by `GET /druid/v2/trace/{query_id}`.
   * **Slow-query log** — a finished trace whose total exceeds
@@ -40,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import threading
 import time
 import uuid
@@ -91,6 +97,12 @@ SPAN_SCATTER = "scatter"  # broker: replica fetches in flight (cluster/)
 SPAN_GATHER = "gather"  # broker: decode + coverage of gathered replies
 SPAN_CLUSTER_MERGE = "cluster_merge"  # broker: ⊕ fold of replica states
 SPAN_CLUSTER_RPC = "cluster_rpc"  # broker: ONE replica attempt (pool thread)
+SPAN_HTTP_READ = "http_read"  # server: body read + JSON decode (before the root opens)
+SPAN_SQL_PARSE = "sql_parse"  # SQL text -> logical plan, on a plan-cache miss
+SPAN_ROUTE = "route"  # the cost model's choice of backend, tier and kernel
+SPAN_PROGRAM_LOOKUP = "program_lookup"  # program-cache lookup / jitted-fn build
+SPAN_ADAPTIVE_KEPT = "adaptive_kept"  # adaptive: kept-set memo, derive, nonzero
+SPAN_RESPOND = "respond"  # server: result frame -> buffered response bytes
 
 SPAN_NAMES = frozenset(
     {
@@ -128,8 +140,88 @@ SPAN_NAMES = frozenset(
         SPAN_GATHER,
         SPAN_CLUSTER_MERGE,
         SPAN_CLUSTER_RPC,
+        SPAN_HTTP_READ,
+        SPAN_SQL_PARSE,
+        SPAN_ROUTE,
+        SPAN_PROGRAM_LOOKUP,
+        SPAN_ADAPTIVE_KEPT,
+        SPAN_RESPOND,
     }
 )
+
+# ---------------------------------------------------------------------------
+# Device-scope registry: the names `device_scope(...)` puts into the HLO
+# metadata (`op_name`) of the traced programs, so that a device operation
+# in a profiler trace points at the part of the program it came from.
+# Trace-time only: a scope changes no compiled code and no run time.
+# ---------------------------------------------------------------------------
+
+SCOPE_ARENA_SCAN = "sdol.arena_scan"  # body of the arena's scan over blocks
+SCOPE_FILTER = "sdol.filter"  # intervals + the query's filter -> row mask
+SCOPE_GROUP_KEYS = "sdol.group_keys"  # per-dim codes packed into one group id
+SCOPE_AGG_INPUTS = "sdol.agg_inputs"  # virtual columns; metrics stacked for the kernel
+SCOPE_PARTIAL_AGG = "sdol.partial_agg"  # the partial-aggregate kernel call
+SCOPE_CARRY_MERGE = "sdol.carry_merge"  # cross-segment / cross-batch fold
+SCOPE_PRESENCE = "sdol.presence"  # adaptive phase A: per-dim presence counts
+SCOPE_KEPT_REMAP = "sdol.kept_remap"  # adaptive phase B: code -> compact code
+SCOPE_SPARSE_SORT = "sdol.sparse_sort"  # sparse tier: sort-compaction of keys
+
+SCOPE_NAMES = frozenset(
+    {
+        SCOPE_ARENA_SCAN,
+        SCOPE_FILTER,
+        SCOPE_GROUP_KEYS,
+        SCOPE_AGG_INPUTS,
+        SCOPE_PARTIAL_AGG,
+        SCOPE_CARRY_MERGE,
+        SCOPE_PRESENCE,
+        SCOPE_KEPT_REMAP,
+        SCOPE_SPARSE_SORT,
+    }
+)
+
+
+def device_scope(name: str):
+    """`jax.named_scope` under a registered `SCOPE_*` name: every
+    operation traced inside carries the name in its HLO metadata.  A
+    context manager, and a decorator for a function that is one scope."""
+    import jax
+
+    if name not in SCOPE_NAMES:
+        raise ValueError(f"unregistered device scope {name!r}")
+    return jax.named_scope(name)
+
+
+# ---------------------------------------------------------------------------
+# The profiler mirror: with a `jax.profiler` session open, every span also
+# lies on the `/host:CPU` plane of the profiler's trace, beside the
+# device's `XLA Ops` and on their clock, as `sdol:<name>`.  Without a
+# session it is one flag test.  (The tree keeps its own injectable clock.)
+# An annotation cannot be back-dated: the root's mirror `sdol:query` opens
+# where the trace does, so `sdol:http_read` (read before the query id is
+# known, hence `query_id=""`) lies BEFORE it, and belongs to the
+# `sdol:query` that begins next on its thread.  The tree's root, on its
+# own clock, starts where `http_read` did.
+# ---------------------------------------------------------------------------
+
+PROFILER_PREFIX = "sdol:"
+
+
+_NO_SESSION = contextlib.nullcontext()
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _mirror(name: str, query_id: str):
+    """Context manager: `TraceAnnotation("sdol:<name>")` while a profiler
+    session is open, nothing otherwise."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    if not _annotation.is_enabled():
+        return _NO_SESSION
+    return _annotation(PROFILER_PREFIX + name, query_id=query_id)
 
 
 def new_query_id() -> str:
@@ -228,6 +320,11 @@ class QueryTrace:
         # serving a broker RPC records the broker's span id here so the
         # OTLP export joins both processes into one tree
         self.parent_span_id: str = ""
+        # who wants the receipt of the CLOSED trace: `QueryMetrics`
+        # objects (their `.receipt`) and result frames' `.attrs` (their
+        # "receipt" key), registered by `stamp_receipt_on` while the
+        # query runs and stamped once, at close
+        self._receipt_sinks: List[Any] = []
 
     def start_span(
         self, name: str, parent: Optional[Span], attrs: Optional[dict] = None
@@ -257,6 +354,32 @@ class QueryTrace:
         like start_span — the scatter pool threads graft concurrently."""
         with self._lock:
             s.grafts.append(subtree)
+
+    def adopt_early(self, early: Span) -> None:
+        """Put a span that closed before this trace opened (the server's
+        `http_read`) first under the root, and start the root with it."""
+        with self._lock:
+            self.root.start = min(self.root.start, early.start)
+            self.root.children.insert(0, early)
+
+    def stamp_receipt_on(self, metrics=None, frame=None) -> None:
+        """Register who gets the closed trace's receipt: a
+        `QueryMetrics` and/or a result frame (anything with a dict
+        `.attrs`; other results are skipped)."""
+        with self._lock:
+            if metrics is not None:
+                self._receipt_sinks.append(metrics)
+            if isinstance(getattr(frame, "attrs", None), dict):
+                self._receipt_sinks.append(frame.attrs)
+
+    def _stamp_receipt(self) -> None:
+        with self._lock:
+            sinks, self._receipt_sinks = self._receipt_sinks, []
+        for sink in sinks:
+            if isinstance(sink, dict):  # a result frame's attrs
+                sink["receipt"] = self.receipt
+            else:  # a QueryMetrics
+                sink.receipt = self.receipt
 
     def finish(self) -> None:
         with self._lock:
@@ -362,7 +485,8 @@ def span(name: str, **attrs):
     s = tr.start_span(name, _active_span.get(), attrs or None)
     token = _active_span.set(s)
     try:
-        yield s
+        with _mirror(name, tr.query_id):
+            yield s
     finally:
         _active_span.reset(token)
         tr.end_span(s)
@@ -385,9 +509,27 @@ def span_in(trace: Optional[QueryTrace], parent: Optional[Span],
         return
     s = trace.start_span(name, parent, attrs or None)
     try:
-        yield s
+        with _mirror(name, trace.query_id):
+            yield s
     finally:
         trace.end_span(s)
+
+
+def span_around(name: str):
+    """Decorator form of `span(name)`: every call of the function runs
+    inside a child span (for functions that ARE one phase — the program
+    -cache lookups).  Same no-op without a trace, same name contract
+    (span-discipline/GL1101)."""
+
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+
+        return wrapped
+
+    return decorate
 
 
 def span_event(name: str, **attrs) -> None:
@@ -477,18 +619,37 @@ class Tracer:
         self.sampler.force_next()
 
     @contextlib.contextmanager
+    def early_span(self, name: str, **attrs):
+        """A span of work that has to happen BEFORE its trace can open:
+        the server reads and decodes the request body to learn the query
+        id the trace is opened under.  Yields a detached `Span` on this
+        tracer's clock; `query_trace(early=...)` then adopts it, so the
+        root starts where the request did and the read is a child like
+        any other phase.  Its profiler mirror carries no query id and
+        precedes its root's (see "The profiler mirror" above).
+        Same pairing contract as `span(...)` (span-discipline/GL1102)."""
+        s = Span(name, self.clock(), attrs or None)
+        try:
+            with _mirror(name, ""):
+                yield s
+        finally:
+            s.end = self.clock()
+
+    @contextlib.contextmanager
     def query_trace(
         self,
         query_id: Optional[str] = None,
         query_type: str = "",
         slow_ms: float = 0.0,
         parent_span_id: str = "",
+        early: Optional[Span] = None,
     ):
         """Open (or join) the per-query trace.  The OUTERMOST scope wins,
         exactly like `resilience.deadline_scope`: the server boundary
         starts the trace and `ctx.sql` inside it joins rather than
         nesting a second root.  `parent_span_id` stamps cross-process
-        parentage (a historical trace opened under a broker RPC span)."""
+        parentage (a historical trace opened under a broker RPC span);
+        `early` is a closed `early_span` the new root adopts."""
         existing = _active_trace.get()
         if existing is not None:
             yield existing
@@ -501,12 +662,15 @@ class Tracer:
         )
         if parent_span_id:
             tr.parent_span_id = str(parent_span_id)
+        if early is not None:
+            tr.adopt_early(early)
         tok_t = _active_trace.set(tr)
         tok_s = _active_span.set(tr.root)
         ps = _prof.ProfScope(sampled=self.sampler.take())
         tok_p = _prof.activate(ps)
         try:
-            yield tr
+            with _mirror(SPAN_QUERY, tr.query_id):
+                yield tr
         finally:
             _active_span.reset(tok_s)
             _active_trace.reset(tok_t)
@@ -520,6 +684,9 @@ class Tracer:
             try:
                 tr.receipt = _prof.build_receipt(doc, ps)
                 doc["receipt"] = tr.receipt
+                # the one receipt of the closed trace is what the query's
+                # QueryMetrics and result frame hold from here on
+                tr._stamp_receipt()
                 _prof.workload_profiler().observe(doc, ps)
             except Exception:  # fault-ok: attribution must not fail queries
                 log.warning("receipt build failed", exc_info=True)

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import SCOPE_PARTIAL_AGG, device_scope
+
 # One f32 VMEM tile is (8, 128); one-hot blocks are multiples of both.
 _LANE = 128
 
@@ -228,6 +230,7 @@ def resolve_strategy(strategy: str, num_groups: int) -> str:
     return "dense"
 
 
+@device_scope(SCOPE_PARTIAL_AGG)
 def partial_aggregate(
     gid,
     mask,

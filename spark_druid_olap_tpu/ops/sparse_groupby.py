@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import SCOPE_SPARSE_SORT, device_scope
 from .groupby import partial_aggregate
 
 SPARSE_SLOTS = 4096
@@ -269,8 +270,9 @@ def sparse_partial_aggregate(
     # TPU-idiomatic compaction: one argsort, then ONLY gathers — no R-sized
     # scatter (what jnp.unique's return_inverse would cost us).  The row
     # values ride the permutation instead of the slot ids riding an inverse.
-    order = jnp.argsort(g)
-    sg = g[order]
+    with device_scope(SCOPE_SPARSE_SORT):
+        order = jnp.argsort(g)
+        sg = g[order]
     firsts = jnp.concatenate(
         [jnp.ones((1,), jnp.bool_), sg[1:] != sg[:-1]]
     )

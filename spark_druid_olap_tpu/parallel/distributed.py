@@ -85,11 +85,13 @@ from ..obs import (
     SPAN_ARENA_BUILD,
     SPAN_COLLECTIVE_MERGE,
     SPAN_FINALIZE,
+    SPAN_PROGRAM_LOOKUP,
     SPAN_SEGMENT_DISPATCH,
     SPAN_SPARSE_DISPATCH,
     current_query_id,
     record_query_metrics,
     span,
+    span_around,
     span_event,
 )
 from ..ops.groupby import (
@@ -307,6 +309,7 @@ class DistributedEngine:
             ng = 1
         return ng, G // max(ng, 1)
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _spmd_fn(
         self,
         lowering: GroupByLowering,
@@ -1203,6 +1206,7 @@ class DistributedEngine:
         self._place_arena(ds, layout, lowering.columns, scratch)
         return True
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_spmd_fn(self, lowering, ds, layout, Lk, strategy, tree):
         """The cached single-dispatch unified program.  The key carries
         the window LENGTH `Lk` but never the scope itself — two disjoint
@@ -1228,6 +1232,7 @@ class DistributedEngine:
         self._spmd_cache[cache_key] = run
         return run
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_chunk_fn(self, lowering, ds, layout, strategy):
         from ..exec.lowering import _query_key
         from ..obs import prof
@@ -1247,6 +1252,7 @@ class DistributedEngine:
         self._spmd_cache[cache_key] = run
         return run
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_merge_fn(self, lowering, ds, tree):
         from ..exec.lowering import _query_key
         from ..obs import prof
@@ -1570,6 +1576,7 @@ class DistributedEngine:
             return False
         return self._arena_layout(ds) is not None
 
+    @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_spmd_fused_fn(self, members, ds, layout, Lk, strategies, tree):
         """The fused unified program: every member's fold inside ONE
         sharded scan, membership as data (one compiled program serves

@@ -22,6 +22,7 @@ from ..models import query as Q
 from . import expr as E
 from . import logical as L
 from .builder import QueryBuilder
+from ..obs import SPAN_ROUTE, span
 from ..utils.log import get_logger
 
 log = get_logger("plan.planner")
@@ -400,7 +401,8 @@ class Planner:
             )
 
         q = b.build()
-        phys = choose_physical(q, ds, G_kernel, self.cfg, self.n_devices)
+        with span(SPAN_ROUTE):
+            phys = choose_physical(q, ds, G_kernel, self.cfg, self.n_devices)
         log.debug(
             "rewrite: %s over %s -> %s strategy=%s distributed=%s groups=%d",
             type(q).__name__, table, phys, phys.strategy, phys.distributed,
@@ -644,7 +646,8 @@ class Planner:
             order_by=tuple(order_by),
             offset=offset or 0,
         )
-        phys = choose_physical(q, ds, 1, self.cfg, self.n_devices)
+        with span(SPAN_ROUTE):
+            phys = choose_physical(q, ds, 1, self.cfg, self.n_devices)
         return Rewrite(
             datasource=node.table,
             builder=b,

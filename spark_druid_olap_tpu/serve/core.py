@@ -333,18 +333,9 @@ class ServingCore:
 
             if parse_command(sql_text) is not None:
                 return LANE_INTERACTIVE
-            key = ctx._plan_cache_key(sql_text)
-            cached = ctx._plan_cache.get(key)
-            if cached is not None:
-                rw, _lp = cached
-            else:
-                from ..sql.parser import parse_sql
-
-                lp, explain, _ = parse_sql(sql_text, views=ctx.views)
-                if explain:
-                    return LANE_INTERACTIVE
-                rw = ctx._planner().plan(lp)
-                ctx._plan_cache[key] = (rw, lp)
+            rw, _lp, _explain, _err = ctx._plan_cached(sql_text)
+            if rw is None:  # EXPLAIN, or a shape bound for the fallback
+                return LANE_INTERACTIVE
             return classify_rewrite(rw, ctx.catalog, ctx.config)
         except Exception:  # fault-ok: lane routing must never fail a query
             return LANE_INTERACTIVE

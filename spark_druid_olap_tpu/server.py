@@ -45,7 +45,9 @@ from .models.filters import _ms_to_iso
 from .models.wire import WireError, query_from_druid
 from .obs import (
     SPAN_ADMISSION,
+    SPAN_HTTP_READ,
     SPAN_LANE,
+    SPAN_RESPOND,
     default_tracer,
     get_registry,
     new_query_id,
@@ -299,6 +301,16 @@ class _Handler(BaseHTTPRequestHandler):
         self.wfile.write(body)
         self._finish_response(code)
 
+    def _respond(self, shape, *result):
+        """Answer 200 with `shape(*result)` (a result frame rendered
+        into the route's payload).  Rendering, JSON encoding and the
+        buffering of the bytes are the `respond` span; the socket write
+        itself waits for the trace to close (see do_POST)."""
+        with span(SPAN_RESPOND):
+            self._send(
+                200, shape(*result), headers=self._partial_headers()
+            )
+
     def _error(
         self,
         code: int,
@@ -491,8 +503,12 @@ class _Handler(BaseHTTPRequestHandler):
         # from the previous query must never echo on this response
         self._query_id = None
         self._req_t0 = _time.perf_counter()
+        # the body holds the id the trace opens under, so it is read
+        # before there is a trace: as an early span the query's root
+        # adopts below, which makes the root start HERE
+        with self._tracer().early_span(SPAN_HTTP_READ) as read:
+            body = self._body()
         path = self.path.split("?")[0].rstrip("/")
-        body = self._body()
         if body is None:
             return self._error(
                 400, "invalid JSON body", "BadJsonQueryException"
@@ -524,6 +540,7 @@ class _Handler(BaseHTTPRequestHandler):
                 query_id=self._query_id,
                 query_type="native" if path == "/druid/v2" else "sql",
                 slow_ms=cfg.slow_query_ms if cfg else 0.0,
+                early=read,
             ):
                 return self._handle_query(path, body, qctx, res, cfg)
         finally:
@@ -531,7 +548,9 @@ class _Handler(BaseHTTPRequestHandler):
             # CAPTURED by _send_bytes during the query scope and is
             # written HERE — after the trace published to the ring — so
             # a client that reads it and immediately fetches
-            # /druid/v2/trace/{id} can never race the publish
+            # /druid/v2/trace/{id} can never race the publish.  (So the
+            # socket write is outside the span tree: the `respond` span
+            # ends where the bytes are buffered.)
             self._defer_buffered = False
             pending = self._buffered_response
             if pending is not None:
@@ -1021,10 +1040,7 @@ class _Handler(BaseHTTPRequestHandler):
             if serve is not None:
                 hit = serve.cached_native(q, ds, allow_delta=False)
                 if hit is not None:
-                    return self._send(
-                        200, druid_result_shape(q, hit),
-                        headers=self._partial_headers(),
-                    )
+                    return self._respond(druid_result_shape, q, hit)
             # the device breaker is open: degrade the wire query through
             # the native->logical fallback interpreter instead of the old
             # blanket 503 (the completed degradation-matrix cell); shapes
@@ -1148,10 +1164,7 @@ class _Handler(BaseHTTPRequestHandler):
             if res is None or classify_error(err) != "transient":
                 raise
             return self._native_degraded(q, err, "device_failed")
-        self._send(
-            200, druid_result_shape(q, df),
-            headers=self._partial_headers(),
-        )
+        self._respond(druid_result_shape, q, df)
 
     def _native_degraded(self, q, err, reason: str):
         """Degrade one wire-native query to the host fallback via the
@@ -1175,10 +1188,7 @@ class _Handler(BaseHTTPRequestHandler):
                     "the breaker's cooldown"
                 ) from e
             raise err
-        self._send(
-            200, druid_result_shape(q, df),
-            headers=self._partial_headers(),
-        )
+        self._respond(druid_result_shape, q, df)
 
     def _progressive_query(self, q, ds):
         """Chunked progressive response (ISSUE 7 tentpole (b)): one
@@ -1297,10 +1307,7 @@ class _Handler(BaseHTTPRequestHandler):
                     gen = self.ctx.sql_progressive(sql)
                     if gen is not None:
                         return self._stream_refinements(gen, _rows)
-                df = self.ctx.sql(sql)
-                self._send(
-                    200, _rows(df), headers=self._partial_headers()
-                )
+                self._respond(_rows, self.ctx.sql(sql))
             finally:
                 if res is not None:
                     res.admission.release()

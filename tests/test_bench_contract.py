@@ -32,7 +32,7 @@ def _fat_result():
             "pandas_ms": 678.9,
             "max_rel_err": 1e-12,
             "metrics": {k: 1.0 for k in ("scan_bytes", "kernel_ms",
-                                         "merge_ms", "roofline_util_pct",
+                                         "merge_ms", "scan_bytes_per_sec",
                                          "segments", "rows_scanned")},
         }
         for i in range(1, 5)

@@ -248,3 +248,39 @@ def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_compiled_programs_keep_device_scopes(one_chip, ssb_ctx, pallas_on):
+    """The scopes (`obs.SCOPE_*`) survive the TPU compiler: the compiled
+    arena program's operations carry them in their `op_name` metadata,
+    which is what a profiler trace shows for each device operation."""
+    import re
+
+    from spark_druid_olap_tpu.exec import arena
+    from spark_druid_olap_tpu.exec.engine import Engine
+
+    q, ds, lowering = _lowered_query(ssb_ctx, "q4_1")
+    program = Engine(strategy="pallas")._arena_program(
+        q, ds, lowering, "pallas"
+    )
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), one_chip
+    )
+    carry = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        (arena._member_init(lowering),),
+    )
+    text = program.lower(
+        carry, cols,
+        _spec((ARENA_BLOCKS,), jnp.bool_, one_chip),
+        _spec((ARENA_BLOCKS, 1), jnp.bool_, one_chip),
+    ).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("sdol.arena_scan", "sdol.filter", "sdol.group_keys",
+                  "sdol.partial_agg", "sdol.carry_merge"):
+        assert any(scope in n for n in op_names), scope
+    # the kernel call sits under the scan and the kernel's own scope
+    kernel = [n for n in op_names if "pallas_partial_aggregate" in n]
+    assert kernel and all(
+        "sdol.arena_scan" in n and "sdol.partial_agg" in n for n in kernel
+    )

@@ -236,11 +236,21 @@ def test_tracer_overhead_on_cached_ssb_query_counted_not_timed():
     # budget for <=5% overhead on a 10ms cached-program SSB query floor
     # is 0.05 * 10ms / 2us = 250 actions.  The deterministic count makes
     # the 5% acceptance bound wall-time-free: N_calls * 2us <= 500us.
+    # (ISSUE 25: the count is two reads for each span of the tree and
+    # nothing else — the receipt is folded once, from the closed tree,
+    # and reads no clock.  On one device that is 22: the root, plan,
+    # execute, route x2, lower, program_lookup, h2d, segment_dispatch,
+    # device_fetch, finalize; it was 17.  On the eight virtual devices
+    # of conftest.py the mesh serves the query with 14; it was 11.)
     assert 0 < clk.calls <= 250, clk.calls
     # and the instrumentation actually produced the span tree
     d = ctx.tracer.last.to_dict()
     names = {c["name"] for c in d["spans"]["children"]}
     assert {"plan", "execute"} <= names
+    n_spans = sum(
+        v["n"] for v in d["receipt"]["spans"].values()
+    )
+    assert clk.calls == 2 * n_spans, (clk.calls, d["receipt"]["spans"])
 
 
 def test_engine_publishes_into_process_registry():
@@ -590,3 +600,319 @@ def test_sys_retention_drops_aged_rollup_segments(tmp_path):
     # and the user table is untouched by the telemetry sweep
     got = ctx2.sql("SELECT count(*) AS c FROM ev")
     assert int(got["c"].iloc[0]) == 50
+
+
+# ---------------------------------------------------------------------------
+# One span tree for the whole request, self time by name, the profiler's
+# clock, device scopes (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+
+def _walk(node):
+    yield node
+    for c in node.get("children", ()):
+        yield from _walk(c)
+
+
+def test_receipt_self_times_by_span_name_add_up_to_the_root():
+    """`receipt["spans"]`: every span's duration less its children's, by
+    name; under the counting clock the sum is the root's duration exactly."""
+    clk = TickClock(step=1.0)
+    tracer = Tracer(clock=clk)
+    with tracer.query_trace(query_id="q-self") as tr:
+        with span(SPAN_PLAN):
+            with span(SPAN_PLAN):  # a name met twice, nested
+                pass
+        with span(SPAN_EXECUTE):
+            with span(SPAN_FINALIZE):
+                pass
+            with span(SPAN_FINALIZE):
+                pass
+    rc = tr.receipt
+    spans = rc["spans"]
+    assert {k: v["n"] for k, v in spans.items()} == {
+        "query": 1, "plan": 2, "execute": 1, "finalize": 2,
+    }
+    assert sum(v["self_ms"] for v in spans.values()) == rc["wall_ms"]
+    assert rc["wall_ms"] == tr.total_ms == 11_000.0
+    # execute: 5 ticks long, two children of one tick each
+    assert spans["execute"]["self_ms"] == 3_000.0
+    assert spans["finalize"]["self_ms"] == 2_000.0
+    # the root's own time is what the buckets call unattributed
+    assert spans["query"]["self_ms"] == rc["unattributed_ms"]
+
+
+def test_early_span_is_adopted_and_back_dates_the_root():
+    """Work done before the trace could open (the server's body read)
+    becomes the root's first child, and the root starts with it."""
+    from spark_druid_olap_tpu.obs import SPAN_HTTP_READ
+
+    clk = TickClock(step=1.0)
+    tracer = Tracer(clock=clk)
+    with tracer.early_span(SPAN_HTTP_READ) as read:  # ticks 0, 1
+        pass
+    clk()  # a tick between the read and the trace's opening
+    with tracer.query_trace(query_id="q-early", early=read) as tr:
+        with span(SPAN_PLAN):
+            pass
+    d = tr.to_dict()
+    root = d["spans"]
+    assert [c["name"] for c in root["children"]] == ["http_read", "plan"]
+    assert root["children"][0]["start_ms"] == 0.0
+    assert root["children"][0]["duration_ms"] == 1_000.0
+    # root: from the read's start (tick 0) to the close (tick 6)
+    assert d["total_ms"] == 6_000.0
+    spans = tr.receipt["spans"]
+    assert sum(v["self_ms"] for v in spans.values()) == 6_000.0
+    assert spans["http_read"] == {"n": 1, "self_ms": 1_000.0}
+
+
+def test_receipt_is_built_once_and_is_the_closed_traces(monkeypatch):
+    """An unsampled query folds its span tree into a receipt once, at
+    trace close; QueryMetrics, the result frame and the ring's doc hold
+    that one receipt."""
+    from spark_druid_olap_tpu.obs import prof
+
+    ctx = sd.TPUOlapContext()
+    rng = np.random.default_rng(11)
+    ctx.register_table(
+        "once_t",
+        {
+            "k": rng.choice(np.array(["x", "y"], dtype=object), 400),
+            "v": rng.random(400).astype(np.float32),
+        },
+        dimensions=["k"],
+        metrics=["v"],
+    )
+    built = []
+    real = prof.build_receipt
+    monkeypatch.setattr(
+        prof, "build_receipt",
+        lambda doc, scope=None: built.append(1) or real(doc, scope),
+    )
+    df = ctx.sql("SELECT k, sum(v) AS s FROM once_t GROUP BY k")
+    assert built == [1]
+    doc = ctx.tracer.last_trace_dict()
+    rc = doc["receipt"]
+    assert ctx.last_metrics.receipt is rc and df.attrs["receipt"] is rc
+    assert rc["wall_ms"] == doc["total_ms"]
+    assert {"plan", "sql_parse", "route", "execute", "lower",
+            "program_lookup", "device_fetch", "finalize"} <= set(rc["spans"])
+    assert sum(v["self_ms"] for v in rc["spans"].values()) == pytest.approx(
+        rc["wall_ms"], abs=0.001 * len(rc["spans"])
+    )
+
+
+def test_program_lookup_span_names_family_and_marks_a_miss():
+    cfg = SessionConfig()
+    cfg.result_cache_entries = 0  # the repeat must execute, not cache-hit
+    ctx = sd.TPUOlapContext(cfg)
+    rng = np.random.default_rng(12)
+    ctx.register_table(
+        "pl_t",
+        {
+            "k": rng.choice(np.array(["x", "y", "z"], dtype=object), 300),
+            "v": rng.random(300).astype(np.float32),
+        },
+        dimensions=["k"],
+        metrics=["v"],
+    )
+    lookups = []
+    for _ in range(2):
+        ctx.sql("SELECT k, sum(v) AS s FROM pl_t GROUP BY k")
+        lookups.append([
+            s.get("attrs", {})
+            for s in _walk(ctx.tracer.last_trace_dict()["spans"])
+            if s["name"] == "program_lookup"
+        ])
+    cold, warm = lookups
+    assert cold and all(a.get("compile") for a in cold)
+    assert warm and not any(a.get("compile") for a in warm)
+    assert {a["family"] for a in warm} == {a["family"] for a in cold}
+    # the plan span says whether the text hit the plan cache
+    plans = [
+        s for s in _walk(ctx.tracer.last_trace_dict()["spans"])
+        if s["name"] == "plan"
+    ]
+    assert plans and all(p["attrs"]["cache_hit"] for p in plans)
+
+
+def test_adaptive_spans_kept_set_and_phases():
+    """The adaptive tier in the tree: `adaptive_kept` around the kept-set
+    work with the phase-A probes under it, phase B's dispatch marked; a
+    repeat recalls the kept set and probes nothing."""
+    from spark_druid_olap_tpu.catalog.segment import (
+        DimensionDict,
+        build_datasource,
+    )
+    from spark_druid_olap_tpu.exec.engine import Engine
+    from spark_druid_olap_tpu.models.aggregations import DoubleSum
+    from spark_druid_olap_tpu.models.dimensions import DimensionSpec
+    from spark_druid_olap_tpu.models.query import GroupByQuery
+
+    rng = np.random.default_rng(4)
+    n = 30_000
+    # 400 x 400 dictionary domain, 12 x 9 codes populated: no filter pins
+    # a dimension, so the kept sets have to be MEASURED (phase A runs)
+    ds = build_datasource(
+        "adspan",
+        {
+            "a": rng.integers(0, 12, size=n),
+            "b": rng.integers(0, 9, size=n),
+            "v": rng.random(n).astype(np.float32),
+        },
+        dimension_cols=["a", "b"],
+        metric_cols=["v"],
+        rows_per_segment=n // 3,
+        dicts={
+            "a": DimensionDict(values=tuple(range(400))),
+            "b": DimensionDict(values=tuple(range(400))),
+        },
+    )
+    q = GroupByQuery(
+        datasource="adspan",
+        dimensions=(DimensionSpec("a"), DimensionSpec("b")),
+        aggregations=(DoubleSum("s", "v"),),
+    )
+    eng = Engine(strategy="adaptive")
+    tracer = Tracer()
+    docs = []
+    for i in range(2):
+        with tracer.query_trace(query_id=f"ad-{i}") as tr:
+            df = eng.execute(q, ds)
+        assert eng.last_metrics.strategy == "adaptive" and len(df) == 108
+        docs.append(tr.to_dict())
+    first, repeat = ([s for s in _walk(d["spans"])] for d in docs)
+
+    def named(spans, name):
+        return [s for s in spans if s["name"] == name]
+
+    kept = named(first, "adaptive_kept")
+    assert len(kept) == 1
+    assert kept[0]["attrs"]["source"] == "measured"
+    assert kept[0]["attrs"]["compact_groups"] == 108
+    probes = named(kept[0]["children"], "adaptive_probe")
+    assert probes and all(p["attrs"]["phase"] == "A" for p in probes)
+    phase_b = named(first, "segment_dispatch")
+    assert phase_b and all(s["attrs"]["phase"] == "B" for s in phase_b)
+    assert named(first, "device_fetch") and named(first, "finalize")
+    assert named(first, "route")
+    # the repeat: kept set from the memo, no probe, phase B again
+    kept2 = named(repeat, "adaptive_kept")
+    assert kept2[0]["attrs"]["source"] == "memo"
+    assert not named(repeat, "adaptive_probe")
+    assert all(
+        s["attrs"]["phase"] == "B" for s in named(repeat, "segment_dispatch")
+    )
+    assert docs[1]["receipt"]["spans"]["adaptive_kept"]["n"] == 1
+    assert eng.last_metrics.finalize_ms > 0
+
+
+def test_spans_mirror_into_a_profiler_session(tmp_path):
+    """With a `jax.profiler` session open, the root and every span lie
+    on the profiler's host plane as `sdol:<name>`, each child inside its
+    parent on that clock; none takes the benchmark's `request:` prefix."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from spark_druid_olap_tpu.obs import SPAN_HTTP_READ
+
+    tracer = Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("request:unit"):
+            with tracer.early_span(SPAN_HTTP_READ) as read:
+                pass
+            with tracer.query_trace(query_id="q-mirror", early=read):
+                with span(SPAN_PLAN):
+                    pass
+                with span(SPAN_EXECUTE):
+                    with span(SPAN_FINALIZE):
+                        pass
+    finally:
+        jax.profiler.stop_trace()
+    # and with no session the mirror is off: spans still work
+    with tracer.query_trace(query_id="q-off"):
+        with span(SPAN_PLAN):
+            pass
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("sdol:", "request:")):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns)
+                    )
+    assert set(events) == {
+        "request:unit", "sdol:http_read", "sdol:query", "sdol:plan",
+        "sdol:execute", "sdol:finalize",
+    }
+    assert all(len(v) == 1 for v in events.values())  # q-off left nothing
+
+    def inside(child, parent):
+        (a, b), (lo, hi) = events[child][0], events[parent][0]
+        return lo <= a and b <= hi
+
+    for name in ("sdol:http_read", "sdol:query"):
+        assert inside(name, "request:unit")
+    assert inside("sdol:plan", "sdol:query")
+    assert inside("sdol:execute", "sdol:query")
+    assert inside("sdol:finalize", "sdol:execute")
+    # the read precedes the root it is adopted into
+    assert events["sdol:http_read"][0][1] <= events["sdol:query"][0][0]
+
+
+def test_device_scopes_are_registered_and_reach_the_lowered_program():
+    """`device_scope` takes registered names only, and the traced bodies
+    carry them: the lowered arena program names its scan, filter, group
+    keys, kernel call and carry merge."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_druid_olap_tpu.exec import arena
+    from spark_druid_olap_tpu.exec.engine import Engine
+    from spark_druid_olap_tpu.exec.lowering import lower_groupby
+    from spark_druid_olap_tpu.obs import SCOPE_NAMES, device_scope
+    from spark_druid_olap_tpu.sql.parser import parse_sql
+
+    with pytest.raises(ValueError):
+        device_scope("sdol.made_up")
+    assert all(n.startswith("sdol.") for n in SCOPE_NAMES)
+
+    ctx = sd.TPUOlapContext()
+    rng = np.random.default_rng(13)
+    n = 2048
+    ctx.register_table(
+        "sc_t",
+        {
+            "k": rng.choice(np.array(["x", "y", "z"], dtype=object), n),
+            "v": rng.random(n).astype(np.float32),
+        },
+        dimensions=["k"],
+        metrics=["v"],
+    )
+    lp, _, _ = parse_sql("SELECT k, sum(v) AS s FROM sc_t WHERE v > 0.5 GROUP BY k")
+    rw = ctx._planner().plan(lp)
+    ds = ctx.catalog.get(rw.datasource)
+    lowering = lower_groupby(rw.query, ds)
+    program = Engine(strategy="dense")._arena_program(
+        rw.query, ds, lowering, "dense"
+    )
+    cols = ctx.engine._cols_for_segment(ds.segments[0], ds, lowering.columns)
+    stacked = {k: jnp.stack([v, v]) for k, v in cols.items()}
+    text = program.lower(
+        (arena._member_init(lowering),), stacked,
+        jnp.asarray([True, True]), jnp.ones((2, 1), bool),
+    ).as_text(debug_info=True)
+    for scope in ("sdol.arena_scan", "sdol.filter", "sdol.group_keys",
+                  "sdol.agg_inputs", "sdol.partial_agg", "sdol.carry_merge"):
+        assert scope in text, scope

@@ -334,5 +334,77 @@ def test_concurrent_query_span_trees_do_not_interleave(srv):
         names = [c["name"] for c in trace["spans"]["children"]]
         # exactly one of each top-level phase: no cross-query bleed
         assert names.count("admission") == 1, (qid, names)
-        assert names.count("plan") == 1, (qid, names)
+        # the SQL route looks its plan up twice, and both show: the
+        # lane classifier (before admission), then ctx.sql
+        assert names.count("plan") == 2, (qid, names)
         assert names.count("execute") == 1, (qid, names)
+
+
+# ---------------------------------------------------------------------------
+# The whole served request in one tree (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+
+def _spans(node):
+    yield node
+    for c in node.get("children", ()):
+        yield from _spans(c)
+
+
+def test_served_request_tree_covers_front_end_to_response(srv):
+    """A served SQL request from the handler's first line to the buffered
+    answer: body read, both plan lookups (the first one parses), the cost
+    model's choices, the program lookup, the answer's encoding — and the
+    self times by name add up to the root."""
+    ctx, server = srv
+    code, rows, _ = _post(
+        server.port, "/druid/v2/sql",
+        {**_SQL, "context": {"queryId": "tree-1"}},
+    )
+    assert code == 200 and len(rows) == 3
+    doc = _get_trace(server.port, "tree-1")
+    root = doc["spans"]
+    names = [s["name"] for s in _spans(root)]
+    assert {"http_read", "sql_parse", "route", "program_lookup",
+            "respond", "lane", "admission", "plan", "execute"} <= set(names)
+    top = [c["name"] for c in root["children"]]
+    # the body read comes first and starts the root; the answer is
+    # encoded last, inside the tree
+    assert top[0] == "http_read" and top[-1] == "respond"
+    assert root["children"][0]["start_ms"] == 0.0
+    plans = [c for c in root["children"] if c["name"] == "plan"]
+    assert [p["attrs"]["cache_hit"] for p in plans] == [False, True]
+    assert [c["name"] for c in plans[0]["children"]] == ["sql_parse", "route"]
+    rc = doc["receipt"]
+    assert rc["wall_ms"] == doc["total_ms"]
+    assert sum(v["self_ms"] for v in rc["spans"].values()) == pytest.approx(
+        rc["wall_ms"], abs=0.001 * len(rc["spans"])
+    )
+    # what the request's QueryMetrics holds is that receipt, not an
+    # earlier provisional one
+    assert ctx.last_metrics.receipt == rc
+    # a repeat hits the plan cache both times and parses nothing
+    _post(server.port, "/druid/v2/sql",
+          {**_SQL, "context": {"queryId": "tree-2"}})
+    again = [s["name"] for s in _spans(_get_trace(server.port, "tree-2")["spans"])]
+    assert "sql_parse" not in again and again.count("plan") == 2
+
+
+def test_native_request_tree_has_read_and_respond(srv):
+    _, server = srv
+    code, _, _ = _post(
+        server.port, "/druid/v2",
+        {
+            "queryType": "groupBy", "dataSource": "ev",
+            "dimensions": ["city"], "granularity": "all",
+            "aggregations": [
+                {"type": "doubleSum", "name": "s", "fieldName": "v"}
+            ],
+            "context": {"queryId": "tree-native"},
+        },
+    )
+    assert code == 200
+    root = _get_trace(server.port, "tree-native")["spans"]
+    top = [c["name"] for c in root["children"]]
+    assert top[0] == "http_read" and top[-1] == "respond"
+    assert "program_lookup" in [s["name"] for s in _spans(root)]

@@ -5,8 +5,9 @@ vocabulary downstream consumers — `tools/obs_dump.py`, bench artifact
 diffing, the slow-query log, dashboards scraping phase histograms —
 match on BY NAME.  Two contracts keep that vocabulary auditable:
 
-* **GL1101** — every `span(...)` call in the execution/resilience/
-  serving modules must name a registered `SPAN_*` constant from
+* **GL1101** — every `span(...)` call (and `span_around(...)`, its
+  decorator form, and `Tracer.early_span(...)`) in the execution/
+  resilience/serving modules must name a registered `SPAN_*` constant from
   `spark_druid_olap_tpu/obs/trace.py` (resolved through imports by the
   project layer, so `span(SPAN_H2D)` and a literal `span("h2d")` both
   verify).  Ad-hoc or dynamically-built names fragment the taxonomy and
@@ -84,9 +85,12 @@ class SpanDisciplinePass(LintPass):
 
     @staticmethod
     def _is_span_call(name: str, canon: str) -> bool:
-        if canon.endswith(("obs.span", "obs.trace.span")):
+        # `span(NAME)`, its decorator form `span_around(NAME)` and the
+        # tracer's `early_span(NAME)` all take the name first
+        last = name.rsplit(".", 1)[-1]
+        if last in ("span", "span_around", "early_span"):
             return True
-        return name == "span" or name.endswith(".span")
+        return canon.endswith(("obs.span", "obs.trace.span"))
 
     # -- handlers -------------------------------------------------------------
 
