@@ -17,8 +17,8 @@ normal aggregation over the compacted domain:
            small enough, build the remap code -> compact code (-1 = absent).
   phase B  the UNMODIFIED segment program machinery over a *compacted
            lowering*: same query, same aggs (sketches included), dims
-           rewritten through the remap (identity / unrolled compare-select
-           chain / LUT gather by kept-set size — see compacted_lowering) —
+           rewritten through the remap (identity / one compare-select per
+           run of kept codes / LUT gather — see compacted_lowering) —
            so the kernel runs dense/Pallas at G' instead of scatter at G.
 
 Soundness: presence is computed under exactly the row mask phase B applies,
@@ -85,16 +85,55 @@ ADAPTIVE_MAX_COMPACT_GROUPS = 1 << 17
 ADAPTIVE_MIN_SHRINK = 0.5
 
 def _compare_chain_max() -> int:
-    """Kept-sets at or under this size remap codes via an unrolled
-    compare-select chain instead of a device LUT gather (see
-    compacted_lowering): ~0.3 ms per compare over 52M rows vs ~360 ms for
-    one gather on the round-5 TPU.  On CPU the inversion is the other way
-    — a small LUT gather is one L1-resident load per row while 64 fused
-    compares are 64 ALU ops — so the chain is capped near the width XLA
-    itself would select-lower."""
+    """Most RUNS of consecutive kept codes a dim's remap spells out as
+    compare-selects (see compacted_lowering, kept_runs); past it the remap
+    is a device LUT gather.  One run costs the same few elementwise ops
+    whatever its length, so the cap only matters to a kept set of scattered codes.
+    On the v5e a step of such a chain that XLA does not keep in one fusion
+    is a pass over the segment block in the Pallas kernel operand's padded
+    `[R, 1]` layout, ~0.34 ms a segment (PERF.md section 6, PR 26), so 64
+    steps still undercut the ~360 ms a gather over 52M rows was profiled
+    at in round 5.  On the CPU a small LUT gather is one L1-resident load
+    a row, and the chain is capped near the width XLA itself would
+    select-lower."""
     import jax
 
     return 64 if jax.default_backend() == "tpu" else 4
+
+
+def _run_starts(kd: np.ndarray) -> np.ndarray:
+    """Positions in `kd` (unique codes, in compact-code order) where a
+    maximal run of consecutive codes starts."""
+    if len(kd) == 0:
+        return np.zeros(0, np.int64)
+    steps = np.diff(np.asarray(kd, dtype=np.int64))
+    return np.concatenate(([0], np.flatnonzero(steps != 1) + 1))
+
+
+def kept_runs(kd: np.ndarray) -> List[Tuple[int, int, int]]:
+    """`kd` as its maximal runs of consecutive codes: `(lo, hi, offset)`
+    per run, `offset` = the number of kept codes before it, so code `c` of
+    a run is compact `c - lo + offset`.
+
+    Kept sets are mostly runs because dictionaries are sorted: a filter on
+    a parent attribute or a range keeps neighbours (q2_1's 40 brands of
+    one category, a lexicographic Bound, every `d_year` but the null
+    slot)."""
+    starts = _run_starts(kd)
+    ends = np.concatenate((starts[1:], [len(kd)])) - 1
+    return [
+        (int(kd[s]), int(kd[e]), int(s)) for s, e in zip(starts, ends)
+    ]
+
+
+def remap_form(kd: np.ndarray, cardinality: int) -> str:
+    """Which remap compacted_lowering gives a dim with kept codes `kd`:
+    `"identity"`, `"runs:<r>"` or `"lut"` (the `adaptive_kept` span's
+    `remap` attr, one entry per dim)."""
+    if len(kd) == cardinality:
+        return "identity"
+    r = len(_run_starts(kd))
+    return f"runs:{r}" if r <= _compare_chain_max() else "lut"
 
 
 def presence_columns(q, lowering: GroupByLowering, ds=None):
@@ -252,36 +291,23 @@ def compacted_lowering(
 ) -> GroupByLowering:
     """The same lowered query over the compacted code domain.
 
-    Each dim's codes_fn remaps original -> compact codes by one of three
-    equivalent strategies: identity (every code kept), an unrolled
-    compare-select chain (small kept-sets — the common case compaction
-    exists for; a TPU LUT gather cost ~360 ms/dim over 52M rows, profiled
-    round 5), or a device LUT gather (large kept-sets).  All three emit -1
-    for absent codes, which only masked-out rows can carry; decode() maps
-    compact codes back through kept_d then the original decoder — so
-    finalize_groupby and every kernel work unchanged."""
+    Each dim's codes_fn remaps original -> compact codes in the form
+    `remap_form` names, chosen from the run structure of its kept set:
+    identity (every code kept: no rewrite at all), a compare-select per
+    RUN of consecutive kept codes (`c - lo + offset` under `lo <= c <= hi`:
+    the number of elementwise steps grows with the runs, not with |kept|),
+    or a device LUT gather (more runs than `_compare_chain_max()`).  All
+    three emit int32 compact codes in `kept` order and -1 for every absent
+    code, which only masked-out rows can carry; decode() maps compact
+    codes back through kept_d then the original decoder — so
+    finalize_groupby, combine_group_ids and every kernel work unchanged."""
     new_dims: List[ResolvedDim] = []
     G = 1
     for d, kd in zip(lowering.dims, kept):
-        if len(kd) == d.cardinality:
-            # identity remap (every code present): no rewrite at all
+        form = remap_form(kd, d.cardinality)
+        if form == "identity":
             codes_fn = d.codes_fn
-        elif len(kd) <= _compare_chain_max():
-            # Unrolled compare-select instead of a table gather.  On TPU a
-            # gather through even a 250-entry LUT runs ~0.36 s per dim over
-            # 52M rows (profiled round 5: tools/profile_adaptive_phaseb.py
-            # — it was 97% of q3_2's 1117 ms phase B), while |kept| vector
-            # compares fuse into the scan for ~free; XLA only does this
-            # lowering itself for tables of ~32 entries.  Compaction exists
-            # precisely because |kept| is small, so this is the common case.
-            def codes_fn(cols, base=d.codes_fn, kd_list=kd.tolist()):
-                c = base(cols)
-                with device_scope(SCOPE_KEPT_REMAP):
-                    acc = jnp.zeros(c.shape, jnp.int32)
-                    for i, k in enumerate(kd_list):
-                        acc = acc + jnp.where(c == k, jnp.int32(i + 1), 0)
-                    return acc - 1  # absent codes -> -1, same as the LUT
-        else:
+        elif form == "lut":
             lut = np.full(d.cardinality, -1, np.int32)
             lut[kd] = np.arange(len(kd), dtype=np.int32)
             lut_dev = jnp.asarray(lut)
@@ -290,6 +316,31 @@ def compacted_lowering(
                 c = base(cols)
                 with device_scope(SCOPE_KEPT_REMAP):
                     return lut_dev[c]
+        else:
+            # One select per run, not one per kept code.  Two things the
+            # v5e compiler does with this expression shape it (PERF.md
+            # section 6, PR 26; both read off the compiled HLO):
+            # - a chain too long to stay one fusion is materialised step
+            #   by step in the Pallas kernel operand's padded `[R, 1]`
+            #   layout, 268 MB a step a segment: q2_1's 40 + 7 per-code
+            #   selects were 27 such `%copy`, 2.05 s of its 2.26 s;
+            # - a select whose value operand is the bare code column
+            #   carries that padded layout up through the group-id
+            #   arithmetic the same way, and `c - 0` folds to the bare
+            #   column — hence `minimum(c, hi)`, which equals `c` inside
+            #   the run and is never folded away.
+            # Absent codes sum to 0 and leave as -1.
+            def codes_fn(cols, base=d.codes_fn, runs=kept_runs(kd)):
+                c = base(cols).astype(jnp.int32)
+                with device_scope(SCOPE_KEPT_REMAP):
+                    acc = jnp.zeros(c.shape, jnp.int32)
+                    for lo, hi, offset in runs:
+                        acc = acc + jnp.where(
+                            (c >= lo) & (c <= hi),
+                            jnp.minimum(c, hi) - (lo - offset - 1),
+                            0,
+                        )
+                    return acc - 1
 
         def decode(codes, base=d.decode, kd=kd):
             return base(kd[np.asarray(codes, dtype=np.int64)])
@@ -414,7 +465,8 @@ class AdaptiveDomainMixin:
         The `adaptive_kept` span's own time is the memo lookup, the
         dictionary derivation and the host `nonzero` over the presence
         counts; the presence dispatches are its `adaptive_probe` children
-        (phase A), so a repeat shows none."""
+        (phase A), so a repeat shows none.  Its `remap` attr names the form
+        of each dim's code remap in the phase-B program (`remap_form`)."""
         # The memo keys segment-set-independently (lowering.memo_key) so
         # continuous streamed ingest neither forgets query shapes nor
         # leaks one entry per published delta — but a MEASURED kept set
@@ -489,7 +541,11 @@ class AdaptiveDomainMixin:
             )
             if sp is not None:
                 sp.attrs.update(
-                    source=source, compact_groups=Gc, declined=declined
+                    source=source, compact_groups=Gc, declined=declined,
+                    remap=[
+                        remap_form(kd, d.cardinality)
+                        for d, kd in zip(lowering.dims, kept)
+                    ],
                 )
             if declined:
                 log.info(

@@ -138,6 +138,14 @@ def ssb_ctx():
     return ctx
 
 
+@pytest.fixture(scope="module")
+def ssb_dim_tables():
+    """The dimension tables `ssb_ctx` registered (same seed, same draw)."""
+    from spark_druid_olap_tpu.workloads import ssb
+
+    return ssb.gen_dim_tables(1.0, np.random.default_rng(7))
+
+
 def _lowered_query(ctx, name):
     from spark_druid_olap_tpu.exec.lowering import lower_groupby
     from spark_druid_olap_tpu.sql.parser import parse_sql
@@ -156,6 +164,25 @@ def _segment_col_specs(ctx, ds, names, lead, sharding):
     return {
         n: _spec(lead, a.dtype, sharding) for n, a in cols.items()
     }
+
+
+def _compiled_arena_text(ctx, ds, lowering, program, sharding):
+    """`program` (an arena scan over `lowering`) compiled for the described
+    chip at `[ARENA_BLOCKS, R_SEGMENT]` stacks: the compiler's HLO text."""
+    from spark_druid_olap_tpu.exec import arena
+
+    cols = _segment_col_specs(
+        ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), sharding
+    )
+    carry = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, sharding),
+        (arena._member_init(lowering),),
+    )
+    return program.lower(
+        carry, cols,
+        _spec((ARENA_BLOCKS,), jnp.bool_, sharding),
+        _spec((ARENA_BLOCKS, 1), jnp.bool_, sharding),
+    ).compile().as_text()
 
 
 @pytest.fixture
@@ -189,26 +216,14 @@ def test_engine_segment_program_compiles(one_chip, ssb_ctx, pallas_on, name, G):
 def test_engine_arena_scan_compiles(one_chip, ssb_ctx, pallas_on, name):
     """The one-dispatch arena program Engine(strategy="pallas") builds:
     the scanned fold over `[B, R]` stacks."""
-    from spark_druid_olap_tpu.exec import arena
     from spark_druid_olap_tpu.exec.engine import Engine
 
     q, ds, lowering = _lowered_query(ssb_ctx, name)
     program = Engine(strategy="pallas")._arena_program(
         q, ds, lowering, "pallas"
     )
-    cols = _segment_col_specs(
-        ssb_ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), one_chip
-    )
-    carry = jax.tree.map(
-        lambda a: _spec(a.shape, a.dtype, one_chip),
-        (arena._member_init(lowering),),
-    )
-    compiled = program.lower(
-        carry, cols,
-        _spec((ARENA_BLOCKS,), jnp.bool_, one_chip),
-        _spec((ARENA_BLOCKS, 1), jnp.bool_, one_chip),
-    ).compile()
-    _assert_kernel(compiled)
+    text = _compiled_arena_text(ssb_ctx, ds, lowering, program, one_chip)
+    assert "tpu_custom_call" in text
 
 
 def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
@@ -222,6 +237,72 @@ def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
     need = eng._presence_columns(q, lowering, ds)
     cols = _segment_col_specs(ssb_ctx, ds, need, (R_SEGMENT,), one_chip)
     _assert_kernel(seg_fn.lower([cols]).compile())
+
+
+# the kept sets these queries' presence passes measure at SF10 (uniform
+# keys: every code a dimension row can carry under the filter is present):
+# (table, filter column, accepted values) per grouping dim, None = every
+# code but the null slot
+PHASE_B_KEPT = {
+    "q2_1": {"p_brand1": ("part", "p_category", ["MFGR#12"])},
+    "q3_2": {
+        "c_city": ("customer", "c_nation", ["UNITED STATES"]),
+        "s_city": ("supplier", "s_nation", ["UNITED STATES"]),
+        "d_year": ("dwdate", "d_year", [1992, 1993, 1994, 1995, 1996, 1997]),
+    },
+    # s_nation keeps codes 1, 2, 3 (+2 more): the run whose shift is zero
+    "q4_2": {
+        "d_year": ("dwdate", "d_year", [1997, 1998]),
+        "s_nation": ("supplier", "s_region", ["AMERICA"]),
+        "p_category": ("part", "p_mfgr", ["MFGR#1", "MFGR#2"]),
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(PHASE_B_KEPT))
+def test_adaptive_phase_b_relays_out_only_the_kernel_operands(
+    one_chip, ssb_ctx, ssb_dim_tables, pallas_on, monkeypatch, name
+):
+    """The adaptive tier's phase-B arena program over the compacted
+    lowering: the kept-code remap (`sdol.kept_remap`) stays fused in the
+    group-id arithmetic.  The Pallas kernel takes its group ids and mask
+    as `[R, 1]` operands, 128 x padded in (8,128) tiles; what regressed
+    before PR 26 is the compiler carrying that layout up into the remap
+    (27 `%copy` of 268 MB a segment for q2_1).  Two relayouts a segment
+    are the kernel's own operands; none belongs to the remap."""
+    import re
+
+    from spark_druid_olap_tpu.exec import adaptive_exec
+    from spark_druid_olap_tpu.exec.engine import Engine
+
+    # the cap on runs is the live backend's (the CPU's here): take the TPU's
+    monkeypatch.setattr(adaptive_exec, "_compare_chain_max", lambda: 64)
+    q, ds, lowering = _lowered_query(ssb_ctx, name)
+    tables = ssb_dim_tables
+    kept = []
+    for d in lowering.dims:
+        rule = PHASE_B_KEPT[name].get(d.spec.dimension)
+        if rule is None:
+            kept.append(np.arange(d.cardinality - 1, dtype=np.int32))
+            continue
+        table, column, accepted = rule
+        rows = np.isin(tables[table][column], accepted)
+        present = np.unique(tables[table][d.spec.dimension][rows])
+        values = np.asarray(ds.dicts[d.spec.dimension].values)
+        kept.append(np.flatnonzero(np.isin(values, present)).astype(np.int32))
+    assert all(0 < len(k) < d.cardinality for k, d in zip(kept, lowering.dims))
+    clow = adaptive_exec.compacted_lowering(lowering, kept)
+    program = Engine(strategy="pallas")._arena_program(
+        q, ds, clow, "pallas", key_extra=("adaptive",)
+    )
+    text = _compiled_arena_text(ssb_ctx, ds, clow, program, one_chip)
+    assert "tpu_custom_call" in text
+    copies = [
+        line for line in text.splitlines()
+        if re.search(rf"= s32\[{R_SEGMENT},1\]\S* copy\(", line)
+    ]
+    assert not [c for c in copies if "sdol.kept_remap" in c]
+    assert len(copies) == 2, [c.split(" = ")[0].strip() for c in copies]
 
 
 def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
@@ -256,25 +337,13 @@ def test_compiled_programs_keep_device_scopes(one_chip, ssb_ctx, pallas_on):
     which is what a profiler trace shows for each device operation."""
     import re
 
-    from spark_druid_olap_tpu.exec import arena
     from spark_druid_olap_tpu.exec.engine import Engine
 
     q, ds, lowering = _lowered_query(ssb_ctx, "q4_1")
     program = Engine(strategy="pallas")._arena_program(
         q, ds, lowering, "pallas"
     )
-    cols = _segment_col_specs(
-        ssb_ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), one_chip
-    )
-    carry = jax.tree.map(
-        lambda a: _spec(a.shape, a.dtype, one_chip),
-        (arena._member_init(lowering),),
-    )
-    text = program.lower(
-        carry, cols,
-        _spec((ARENA_BLOCKS,), jnp.bool_, one_chip),
-        _spec((ARENA_BLOCKS, 1), jnp.bool_, one_chip),
-    ).compile().as_text()
+    text = _compiled_arena_text(ssb_ctx, ds, lowering, program, one_chip)
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("sdol.arena_scan", "sdol.filter", "sdol.group_keys",
                   "sdol.partial_agg", "sdol.carry_merge"):

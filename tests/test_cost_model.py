@@ -316,44 +316,89 @@ def test_slope_fallback_guards_inverted_measurements():
     assert _clamp_bandwidth(4.5e7) == 4.5e7
 
 
-def test_compare_chain_remap_matches_lut():
-    """compacted_lowering's three remap strategies (identity / unrolled
-    compare-select / LUT gather) must be interchangeable: same compact
-    codes, -1 for absent, on every kept-set size around the chain cap."""
-    import numpy as np
+# kept set of a dictionary of n codes, and the form compacted_lowering gives
+# it on the CPU, where _compare_chain_max() allows 4 runs
+_REMAP_CASES = {
+    "run_at_start": (lambda n: range(0, 40), "runs:1"),
+    "run_from_code_1": (lambda n: range(1, 41), "runs:1"),
+    "run_in_middle": (lambda n: range(n // 2 - 20, n // 2 + 20), "runs:1"),
+    "run_at_end": (lambda n: range(n - 40, n), "runs:1"),
+    "single_code": (lambda n: [17], "runs:1"),
+    "two_runs": (lambda n: [*range(3, 9), *range(n - 50, n - 20)], "runs:2"),
+    "four_runs": (lambda n: [0, 1, 2, 50, 51, 70, n - 2, n - 1], "runs:4"),
+    "isolated_codes": (lambda n: [5, 9, 77, n - 10], "runs:4"),
+    "unsorted_kept": (lambda n: [12, 13, 14, 3, 4, 90], "runs:3"),
+    "more_runs_than_cap": (lambda n: [1, 3, 5, 7, 9, 11], "lut"),
+    "four_of_every_five": (lambda n: [c for c in range(n) if c % 5], "lut"),
+    "identity": (lambda n: range(n), "identity"),
+}
 
+
+def _remap_lowering(card):
+    from spark_druid_olap_tpu.exec.lowering import GroupByLowering, ResolvedDim
+
+    dim = ResolvedDim(
+        spec=None, cardinality=card,
+        codes_fn=lambda cols: cols["c"], decode=lambda cs: cs,
+    )
+    return GroupByLowering(
+        query=None, dims=[dim], la=None, num_groups=card,
+        columns=["c"], filter_fn=None, vcol_fns={},
+    )
+
+
+@pytest.mark.parametrize("code_dtype", ["int8", "int16", "int32"])
+@pytest.mark.parametrize("case", sorted(_REMAP_CASES))
+def test_compare_chain_remap_matches_lut(case, code_dtype):
+    """compacted_lowering's three remap forms (identity / one select per
+    run of kept codes / LUT gather) are interchangeable: compact codes in
+    `kept` order and -1 for every absent code, int32 wherever a rewrite
+    happens, whatever the run structure of the kept set and the stored
+    width of the codes."""
     from spark_druid_olap_tpu.exec import adaptive_exec as AE
-    from spark_druid_olap_tpu.exec.lowering import ResolvedDim
 
     rng = np.random.default_rng(3)
-    card = 250
-    codes = rng.integers(0, card, 10_000).astype(np.int16)
+    card = 100 if code_dtype == "int8" else 250  # int8 holds 127 codes
+    make_kept, form = _REMAP_CASES[case]
+    kept = np.asarray(list(make_kept(card)), dtype=np.int32)
+    assert AE.remap_form(kept, card) == form
+    # every code of the domain, kept and absent alike, then a random draw
+    codes = np.concatenate(
+        [np.arange(card), rng.integers(0, card, 10_000)]
+    ).astype(code_dtype)
+    lut = np.full(card, -1, np.int32)
+    lut[kept] = np.arange(len(kept), dtype=np.int32)
+    want = lut[codes]
+    assert form == "identity" or (want == -1).any()
 
-    def make_dim():
-        return ResolvedDim(
-            spec=None,
-            cardinality=card,
-            codes_fn=lambda cols: cols["c"],
-            decode=lambda cs: cs,
+    compacted = AE.compacted_lowering(_remap_lowering(card), [kept])
+    got = np.asarray(compacted.dims[0].codes_fn({"c": codes}))
+    assert form == "identity" or got.dtype == np.int32
+    assert (got == want).all()
+
+
+def test_run_remap_steps_do_not_grow_with_the_run():
+    """The regression PR 26 removed: one run of 40 kept codes lowers to as
+    many equations as one run of 2 (the per-code chain grew by two a code),
+    and each further run adds the same few."""
+    import jax
+
+    from spark_druid_olap_tpu.exec import adaptive_exec as AE
+
+    def equations(kept):
+        low = AE.compacted_lowering(
+            _remap_lowering(250), [np.asarray(kept, np.int32)]
+        )
+        codes = np.zeros(8, np.int16)
+        return len(
+            jax.make_jaxpr(lambda c: low.dims[0].codes_fn({"c": c}))(codes).eqns
         )
 
-    from spark_druid_olap_tpu.exec.lowering import GroupByLowering
-
-    for n_kept in (2, 4, 64, 200, card):
-        kept = np.sort(
-            rng.choice(card, size=n_kept, replace=False)
-        ).astype(np.int32) if n_kept < card else np.arange(card, dtype=np.int32)
-        lut = np.full(card, -1, np.int32)
-        lut[kept] = np.arange(len(kept), dtype=np.int32)
-        want = lut[codes]
-
-        base = GroupByLowering(
-            query=None, dims=[make_dim()], la=None, num_groups=card,
-            columns=["c"], filter_fn=None, vcol_fns={},
-        )
-        compacted = AE.compacted_lowering(base, [kept])
-        got = np.asarray(compacted.dims[0].codes_fn({"c": codes}))
-        assert (got == want).all(), n_kept
+    one_run = equations(range(100, 102))
+    assert equations(range(100, 140)) == one_run
+    two_runs = equations([3, 4, 5, 200, 201])
+    assert two_runs > one_run
+    assert equations([3, 4, 5, 200, 201, 230]) - two_runs == two_runs - one_run
 
 
 def test_platform_sidecar_fallback(tmp_path):

@@ -791,6 +791,8 @@ def test_adaptive_spans_kept_set_and_phases():
     assert len(kept) == 1
     assert kept[0]["attrs"]["source"] == "measured"
     assert kept[0]["attrs"]["compact_groups"] == 108
+    # codes 0..11 and 0..8 of 400: one run of kept codes a dim
+    assert kept[0]["attrs"]["remap"] == ["runs:1", "runs:1"]
     probes = named(kept[0]["children"], "adaptive_probe")
     assert probes and all(p["attrs"]["phase"] == "A" for p in probes)
     phase_b = named(first, "segment_dispatch")
@@ -800,12 +802,58 @@ def test_adaptive_spans_kept_set_and_phases():
     # the repeat: kept set from the memo, no probe, phase B again
     kept2 = named(repeat, "adaptive_kept")
     assert kept2[0]["attrs"]["source"] == "memo"
+    assert kept2[0]["attrs"]["remap"] == ["runs:1", "runs:1"]
     assert not named(repeat, "adaptive_probe")
     assert all(
         s["attrs"]["phase"] == "B" for s in named(repeat, "segment_dispatch")
     )
     assert docs[1]["receipt"]["spans"]["adaptive_kept"]["n"] == 1
     assert eng.last_metrics.finalize_ms > 0
+
+
+def test_adaptive_kept_span_names_each_dims_remap_form():
+    """A served SQL request's `adaptive_kept` span says which remap its
+    phase-B program uses, one entry per grouping dim: a range keeps one
+    run of codes, six scattered values are more runs than the CPU's cap
+    (a LUT gather), a dim whose every code is present (null slot included)
+    is not rewritten at all."""
+    cfg = SessionConfig()
+    cfg.result_cache_entries = 0  # the repeat must execute
+    ctx = sd.TPUOlapContext(cfg)
+    rng = np.random.default_rng(5)
+    n = 40_000
+    ctx.register_table(
+        "remap_t",
+        {
+            "a": np.array(
+                [f"a{k:03d}" for k in rng.integers(0, 200, n)], dtype=object
+            ),
+            "b": np.array(
+                [f"b{k:03d}" for k in rng.integers(0, 200, n)], dtype=object
+            ),
+            "c": rng.choice(np.array(["x", "y", None], dtype=object), n),
+            "v": np.ones(n, dtype=np.float32),
+        },
+        dimensions=["a", "b", "c"],
+        metrics=["v"],
+        rows_per_segment=1 << 13,
+    )
+    sql = (
+        "SELECT a, b, c, sum(v) AS s FROM remap_t "
+        "WHERE a BETWEEN 'a010' AND 'a019' "
+        "AND b IN ('b001', 'b003', 'b005', 'b007', 'b009', 'b011') "
+        "GROUP BY a, b, c"
+    )
+    for source in ("measured", "memo"):
+        ctx.sql(sql)
+        assert ctx.last_metrics.strategy == "adaptive"
+        (kept,) = [
+            s for s in _walk(ctx.tracer.last_trace_dict()["spans"])
+            if s["name"] == "adaptive_kept"
+        ]
+        assert kept["attrs"]["source"] == source
+        assert kept["attrs"]["compact_groups"] == 10 * 6 * 3
+        assert kept["attrs"]["remap"] == ["runs:1", "lut", "identity"]
 
 
 def test_spans_mirror_into_a_profiler_session(tmp_path):

@@ -290,10 +290,13 @@ def test_status_profile_over_http():
         # the trace observation lands a hair after the response bytes
         # (same benign race as the trace ring tests).  The profiler is
         # PROCESS-global (like the registry), so other tests' queries
-        # share the window — ask for a deep top-K and find ours.
+        # share the window — ask for the whole window (the profiler
+        # keeps 1024 entries) and find ours: with a shallower top-K the
+        # test depended on which files its xdist worker had run in the
+        # five minutes before it.
         mine = []
         for _ in range(200):
-            code, doc = _get_json(srv.port, "/status/profile?k=50")
+            code, doc = _get_json(srv.port, "/status/profile?k=1024")
             assert code == 200
             mine = [
                 t for t in doc["top_device"]
