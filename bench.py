@@ -2367,9 +2367,10 @@ def bench_arena(scale: float):
 
 def _mesh_receipt_rep(ctx, dist, q, ds, name):
     """Force-sampled rep of a DistributedEngine query under the context's
-    tracer.  The mesh engine emits its spans (collective_merge, shard_h2d,
-    segment_dispatch) through obs/span like every other executor, so
-    opening the query trace HERE — outermost wins — collects them and the
+    tracer.  The mesh engine emits its spans (segment_dispatch,
+    device_fetch, the shard_h2d event) through obs/span like every other
+    executor, so opening the query trace HERE — outermost wins — collects
+    them and the
     tracer folds the cost receipt at close, without routing through
     ctx.sql (whose cost model owns backend choice).  Returns
     (result_df, receipt_or_None, wall_ms, span_tree)."""
@@ -2408,7 +2409,7 @@ def bench_mesh_unified(scale: float):
     with the arena on (ONE shard_mapped program, scope as data input,
     collective merge at the boundary) — plus a virtual multi-slice point
     whose merge tree the calibrated cost model chooses (recorded as the
-    `merge_tree` span event inside collective_merge).
+    `merge_tree` span event inside the launch's segment_dispatch).
 
     Steady-state serving comparison: programs warm and residency KEPT
     across reps in every arm (the arena's whole point is that the

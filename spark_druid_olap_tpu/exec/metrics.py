@@ -19,7 +19,10 @@ Phase semantics (wall-clock, single process):
   * `est_collective_ms` — modelled ICI merge time for distributed runs
     (state bytes x ring factor / configured bandwidth); measured split of
     kernel-vs-collective inside one fused SPMD program is profiler
-    territory: use `trace()` below.
+    territory: use `trace()` below (the collectives lie under the device
+    scope `sdol.boundary_merge`).
+  * `collective_bytes` / `shard_steps` / `shards` — counted, not modelled:
+    what the mesh's merges moved and how the scope fell on the shards.
   * `finalize_ms` — host-side result materialization.
 
 `trace(logdir)` wraps `jax.profiler.trace` for the deep-dive path
@@ -65,6 +68,19 @@ class QueryMetrics:
     compile_ms: float = 0.0
     device_ms: float = 0.0
     est_collective_ms: float = 0.0
+    # mesh requests only (0 on a single device).  `collective_bytes`: the
+    # bytes of partial state the request's collectives moved over ICI, from
+    # the shapes actually merged (each merged array's bytes x the ring
+    # factor 2(n-1)/n of an allreduce, x (n-1) of an all_gather) — the
+    # counted sibling of `est_collective_ms`.  `shard_steps` x `shards` /
+    # `segments` says how evenly the scope fell on the shards: every shard
+    # runs `shard_steps` segments' worth of rows (the arena's window `Lk`,
+    # whole blocks with the ones outside the scope dead; a fraction where
+    # the scope's rows are laid end to end and cut evenly), so 1.0 is an
+    # even deal and an arena scope of 3 segments over 4 shards reads 4/3.
+    collective_bytes: int = 0
+    shard_steps: float = 0.0
+    shards: int = 0
     finalize_ms: float = 0.0
     total_ms: float = 0.0
     bytes_resident: int = 0

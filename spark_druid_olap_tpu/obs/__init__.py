@@ -35,6 +35,7 @@ from . import prof  # noqa: F401  (performance attribution, ISSUE 9)
 from .trace import (  # noqa: F401
     SCOPE_AGG_INPUTS,
     SCOPE_ARENA_SCAN,
+    SCOPE_BOUNDARY_MERGE,
     SCOPE_CARRY_MERGE,
     SCOPE_FILTER,
     SCOPE_GROUP_KEYS,
@@ -49,7 +50,6 @@ from .trace import (  # noqa: F401
     SPAN_ARENA_BUILD,
     SPAN_CLUSTER_MERGE,
     SPAN_CLUSTER_RPC,
-    SPAN_COLLECTIVE_MERGE,
     SPAN_COMPACT,
     SPAN_DEGRADED,
     SPAN_DEVICE_FETCH,

@@ -69,7 +69,7 @@ LINK_MBPS_BUCKETS = (
 )
 
 # span-name -> receipt bucket.  Device spans either block on device work
-# (device_fetch, collective_merge) or — on a sampled query — are split
+# (device_fetch) or — on a sampled query — are split
 # honestly by the sync helpers; h2d is the transfer bucket; every OTHER
 # span's exclusive time is host work.  The root `query` span's exclusive
 # time stays unattributed (the coverage-claim denominator).
@@ -80,7 +80,6 @@ DEVICE_SPANS = frozenset(
         "sparse_dispatch",
         "adaptive_probe",
         "stream_chunk",
-        "collective_merge",
     }
 )
 TRANSFER_SPANS = frozenset({"h2d"})
@@ -116,14 +115,14 @@ ROOT_SPAN = "query"
 # device LAUNCH spans — the receipt's `dispatch_count` (ISSUE 14): how
 # many host->device program launches served this query.  The arena path's
 # whole point is driving this from O(segments) to O(1); device_fetch is a
-# read-back, not a launch, so it does not count.
+# read-back, not a launch, so it does not count.  The mesh launches under
+# the same names: one `segment_dispatch` per SPMD program.
 DISPATCH_SPANS = frozenset(
     {
         "segment_dispatch",
         "sparse_dispatch",
         "adaptive_probe",
         "stream_chunk",
-        "collective_merge",
     }
 )
 

@@ -9,9 +9,8 @@ deadline die?"; this module can:
 
   * **Span tree per query** — a `QueryTrace` rooted at a `query` span,
     with children for every lifecycle phase (`admission → plan → lower →
-    h2d → segment_dispatch → device_fetch → collective_merge →
-    finalize`, plus `fallback`/`retry`/`degraded` when a query leaves
-    the happy path).  Span names are DRAWN FROM the `SPAN_*` constant
+    h2d → segment_dispatch → device_fetch → finalize`, plus
+    `fallback`/`retry`/`degraded` when a query leaves the happy path).  Span names are DRAWN FROM the `SPAN_*` constant
     registry below — the span-discipline lint pass (GL11xx) rejects
     ad-hoc strings so the taxonomy cannot fragment.
   * **query_id end-to-end** — generated at the server boundary (honoring
@@ -71,7 +70,6 @@ SPAN_LOWER = "lower"  # query lowering + segment scoping
 SPAN_H2D = "h2d"  # host->device column placement for one batch
 SPAN_SEGMENT_DISPATCH = "segment_dispatch"  # one fused program dispatch
 SPAN_DEVICE_FETCH = "device_fetch"  # blocking host fetch of partials
-SPAN_COLLECTIVE_MERGE = "collective_merge"  # mesh dispatch + ICI-merged fetch
 SPAN_FINALIZE = "finalize"  # host-side result materialization
 SPAN_FALLBACK = "fallback"  # host interpreter run
 SPAN_FALLBACK_DECODE = "fallback_decode"  # fallback table materialization
@@ -114,7 +112,6 @@ SPAN_NAMES = frozenset(
         SPAN_H2D,
         SPAN_SEGMENT_DISPATCH,
         SPAN_DEVICE_FETCH,
-        SPAN_COLLECTIVE_MERGE,
         SPAN_FINALIZE,
         SPAN_FALLBACK,
         SPAN_FALLBACK_DECODE,
@@ -165,6 +162,7 @@ SCOPE_CARRY_MERGE = "sdol.carry_merge"  # cross-segment / cross-batch fold
 SCOPE_PRESENCE = "sdol.presence"  # adaptive phase A: per-dim presence counts
 SCOPE_KEPT_REMAP = "sdol.kept_remap"  # adaptive phase B: code -> compact code
 SCOPE_SPARSE_SORT = "sdol.sparse_sort"  # sparse tier: sort-compaction of keys
+SCOPE_BOUNDARY_MERGE = "sdol.boundary_merge"  # mesh: every collective over ICI
 
 SCOPE_NAMES = frozenset(
     {
@@ -177,6 +175,7 @@ SCOPE_NAMES = frozenset(
         SCOPE_PRESENCE,
         SCOPE_KEPT_REMAP,
         SCOPE_SPARSE_SORT,
+        SCOPE_BOUNDARY_MERGE,
     }
 )
 

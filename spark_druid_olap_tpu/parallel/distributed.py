@@ -81,14 +81,18 @@ from ..ops import hll as hll_ops
 from ..ops import quantiles as quantiles_ops
 from ..ops import theta as theta_ops
 from ..obs import (
+    SCOPE_BOUNDARY_MERGE,
+    SPAN_ADAPTIVE_KEPT,
     SPAN_ADAPTIVE_PROBE,
     SPAN_ARENA_BUILD,
-    SPAN_COLLECTIVE_MERGE,
+    SPAN_DEVICE_FETCH,
     SPAN_FINALIZE,
     SPAN_PROGRAM_LOOKUP,
     SPAN_SEGMENT_DISPATCH,
     SPAN_SPARSE_DISPATCH,
     current_query_id,
+    device_scope,
+    prof,
     record_query_metrics,
     span,
     span_around,
@@ -116,6 +120,26 @@ log = get_logger("parallel.distributed")
 
 _SPARSE_STATE_KEYS = ("gids", "sums", "mins", "maxs")
 _SPARSE_FLAG_KEYS = ("overflow", "row_overflow", "n_rows", "n_real")
+
+
+def _launch(run, *args):
+    """Call a compiled SPMD program inside the caller's launch span: the
+    call returns when the program is enqueued (a sampled query waits for
+    the device here, `obs/prof.py`)."""
+    t_call = _time.perf_counter()
+    return prof.dispatch_sync(run(*args), t_call)
+
+
+def _fetch(tree):
+    """The blocking copy back of a launched program's merged state, under
+    `device_fetch`: the wait for the device is paid here."""
+    with span(SPAN_DEVICE_FETCH):
+        prof.fetch_sync(tree)
+        return jax.device_get(tree)
+
+
+def _nbytes(tree) -> int:
+    return sum(int(np.asarray(x).nbytes) for x in jax.tree.leaves(tree))
 
 
 class DistributedEngine:
@@ -337,7 +361,6 @@ class DistributedEngine:
             "dense-state",
             strategy,
         ) + tuple(key_extra)
-        from ..obs import prof
 
         if cache_key in self._spmd_cache:
             prof.note_program_cache("dense-state", hit=True)
@@ -383,11 +406,12 @@ class DistributedEngine:
                     num_min=num_min, num_max=num_max,
                 )
             # broker-merge over the data axis (ICI collectives)
-            sums = lax.psum(sums, DATA_AXIS)
-            if num_min:
-                mins = lax.pmin(mins, DATA_AXIS)
-            if num_max:
-                maxs = lax.pmax(maxs, DATA_AXIS)
+            with device_scope(SCOPE_BOUNDARY_MERGE):
+                sums = lax.psum(sums, DATA_AXIS)
+                if num_min:
+                    mins = lax.pmin(mins, DATA_AXIS)
+                if num_max:
+                    maxs = lax.pmax(maxs, DATA_AXIS)
             sk_out = {}
             for agg in sketches:
                 # per-agg FILTER mask composes with the row mask (same
@@ -396,12 +420,14 @@ class DistributedEngine:
                 amask = mask & mfn(cols) if mfn is not None else mask
                 if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
                     st = hll_ops.partial_hll(agg, cols, gid_l, amask, Gl)
-                    sk_out[agg.name] = lax.pmax(st, DATA_AXIS)
+                    with device_scope(SCOPE_BOUNDARY_MERGE):
+                        sk_out[agg.name] = lax.pmax(st, DATA_AXIS)
                 elif isinstance(agg, A.QuantilesSketch):
                     st = quantiles_ops.partial_quantiles(
                         agg, cols, gid_l, amask, Gl
                     )
-                    gathered = lax.all_gather(st, DATA_AXIS)  # [nd,Gl,K+1,2]
+                    with device_scope(SCOPE_BOUNDARY_MERGE):
+                        gathered = lax.all_gather(st, DATA_AXIS)  # [nd,Gl,K+1,2]
                     acc = gathered[0]
                     for i in range(1, gathered.shape[0]):
                         acc = quantiles_ops.merge_states(
@@ -410,7 +436,8 @@ class DistributedEngine:
                     sk_out[agg.name] = acc
                 else:
                     st = theta_ops.partial_theta(agg, cols, gid_l, amask, Gl)
-                    gathered = lax.all_gather(st, DATA_AXIS)  # [nd, Gl, K]
+                    with device_scope(SCOPE_BOUNDARY_MERGE):
+                        gathered = lax.all_gather(st, DATA_AXIS)  # [nd, Gl, K]
                     acc = gathered[0]
                     for i in range(1, gathered.shape[0]):
                         acc = theta_ops.merge_states(acc, gathered[i], agg.size)
@@ -497,9 +524,10 @@ class DistributedEngine:
                 slots=slots, inner_strategy=inner,
                 row_capacity=row_capacity,
             )
-            gathered = jax.tree.map(
-                lambda x: lax.all_gather(x, DATA_AXIS), st
-            )
+            with device_scope(SCOPE_BOUNDARY_MERGE):
+                gathered = jax.tree.map(
+                    lambda x: lax.all_gather(x, DATA_AXIS), st
+                )
             acc = jax.tree.map(lambda x: x[0], gathered)
             for i in range(1, nd):
                 acc = merge_sparse_states(
@@ -577,7 +605,8 @@ class DistributedEngine:
                     num_groups=d.cardinality, num_min=0, num_max=0,
                     strategy=strat,
                 )
-                per.append(lax.psum(s[:, 0], DATA_AXIS))
+                with device_scope(SCOPE_BOUNDARY_MERGE):
+                    per.append(lax.psum(s[:, 0], DATA_AXIS))
             return per
 
         specs = {n: P(DATA_AXIS) for n in col_keys}
@@ -694,6 +723,7 @@ class DistributedEngine:
             rows_scanned=ds.num_rows,
             segments=len(ds.segments),
             num_groups=lowering.num_groups,
+            shards=self._row_device_count(),
         )
         # metrics scope: what pruning WOULD scan (parity with the local
         # engine's numbers); shards themselves always span the full set
@@ -760,9 +790,18 @@ class DistributedEngine:
         before_bytes = self._shard_cache.bytes_used
         segs = self._scope_for_metrics(q, ds) if q is not None else None
         cols, padded = self._global_columns(ds, columns, segs=segs)
+        # the scope's rows lie end to end, cut evenly: every shard runs
+        # its cut of them, counted in (padded) segments' worth of rows
+        nd = self.mesh.shape[DATA_AXIS]
+        seg_rows = max(
+            (
+                sg.num_rows_padded
+                for sg in (ds.segments if segs is None else segs)
+            ),
+            default=ROW_PAD,
+        )
+        m.shard_steps = padded / nd / max(1, seg_rows)
         if len(self._shard_cache) > known:  # new shards were placed
-            from ..obs import prof
-
             dt = _time.perf_counter() - t0
             new_bytes = max(0, self._shard_cache.bytes_used - before_bytes)
             m.h2d_ms += dt * 1e3
@@ -772,7 +811,6 @@ class DistributedEngine:
             # per-shard split is recorded as a span event so mesh bench
             # artifacts are attribution-honest (ISSUE 15 satellite)
             prof.record_h2d(new_bytes, dt)
-            nd = self.mesh.shape[DATA_AXIS]
             span_event(
                 "shard_h2d", datasource=ds.name, bytes=new_bytes,
                 per_shard_bytes=new_bytes // max(1, nd), shards=nd,
@@ -780,11 +818,14 @@ class DistributedEngine:
         return cols, padded
 
     def _execute_dense_state(
-        self, q, ds, lowering, m, strategy, key_extra=()
+        self, q, ds, lowering, m, strategy, key_extra=(), span_attrs=None
     ):
         """The dense-[Gl, M]-state path (dense / Pallas / scatter kernels
-        share it — only the per-shard kernel differs)."""
-        from ..plan.cost import groupby_state_bytes
+        share it — only the per-shard kernel differs).  One launch span,
+        then the blocking fetch under `device_fetch`, as on one device."""
+        from ..plan.cost import (
+            allgather_factor, allreduce_factor, groupby_state_bytes,
+        )
 
         cols, padded = self._place_shards(ds, lowering.columns, m, q=q)
         local_rows = padded // self.mesh.shape[DATA_AXIS]
@@ -797,24 +838,33 @@ class DistributedEngine:
         m.program_cache_hit = len(compiled) == key_count
         nd = self.mesh.shape[DATA_AXIS]
         m.est_collective_ms = (
-            2.0 * (nd - 1) / nd
+            allreduce_factor(nd)
             * groupby_state_bytes(q, lowering.num_groups, None)
             / self._cfg().collective_bytes_per_us
             / 1e3
         )
         t0 = _time.perf_counter()
-        # single host fetch (one round trip — see engine._execute_groupby)
-        # under the collective-merge span: the fetch blocks on the SPMD
-        # program, so this is where the ICI merge's wall time is paid
-        with span(SPAN_COLLECTIVE_MERGE):
-            from ..obs import prof
-
-            t_call = _time.perf_counter()
-            out_state = run(cols)
-            # sampled query: split the collective span into enqueue vs
-            # device-complete time before the blocking fetch (obs/prof.py)
-            out_state = prof.dispatch_sync(out_state, t_call)
-            sums, mins, maxs, sk = jax.device_get(out_state)
+        with span(SPAN_SEGMENT_DISPATCH, shards=nd, **(span_attrs or {})):
+            out_state = _launch(run, cols)
+        # single host fetch (one round trip — see engine._execute_groupby):
+        # it blocks on the SPMD program, so the ICI merge's wall time is
+        # paid here
+        sums, mins, maxs, sk = _fetch(out_state)
+        # what `_spmd_fn`'s collectives moved, a device: the fetched arrays
+        # are the merged ones (a groups axis leaves each device 1/ng of
+        # them); HLL registers are reduced like the sums, theta and
+        # quantile states gathered
+        ng, _ = self._groups_split(lowering.num_groups)
+        hll = _nbytes([
+            sk[a.name] for a in lowering.la.sketch_aggs
+            if isinstance(a, (A.HyperUnique, A.CardinalityAgg))
+        ])
+        m.collective_bytes += round(
+            (
+                allreduce_factor(nd) * (_nbytes((sums, mins, maxs)) + hll)
+                + allgather_factor(nd) * (_nbytes(sk) - hll)
+            ) / ng
+        )
         dt = (_time.perf_counter() - t0) * 1e3
         if m.program_cache_hit:
             m.device_ms = dt
@@ -871,6 +921,7 @@ class DistributedEngine:
         exhausted by an exact count — the caller falls back to the
         dense-state scatter path, and the decline is remembered."""
         from ..ops import sparse_groupby as _sg
+        from ..plan.cost import allgather_factor
 
         if lowering.la.sketch_aggs or not lowering.dims:
             # sparse states carry no sketch registers and need real dims
@@ -892,7 +943,14 @@ class DistributedEngine:
             # dispatch span: the mesh receipt's dispatch_count must count
             # sparse rungs like the single-device ladder does
             with span(SPAN_SPARSE_DISPATCH, slots=slots):
-                state, flags = jax.device_get(run(cols))
+                launched = _launch(run, cols)
+            state, flags = _fetch(launched)
+            # every rung gathers its whole slot state from every shard
+            m.collective_bytes += round(
+                allgather_factor(self.mesh.shape[DATA_AXIS])
+                * _nbytes((state, flags))
+                / self._groups_split(lowering.num_groups)[0]
+            )
             if cap is not None and bool(flags["row_overflow"].any()):
                 n = int(flags["n_rows"].max())
                 new_cap = next(
@@ -976,6 +1034,8 @@ class DistributedEngine:
             ADAPTIVE_MAX_COMPACT_GROUPS,
             ADAPTIVE_MIN_SHRINK,
             compacted_lowering,
+            filter_derived_kept,
+            remap_form,
         )
         from ..exec.lowering import empty_partials
         from ..plan.cost import choose_kernel_strategy
@@ -984,50 +1044,43 @@ class DistributedEngine:
         # scanned (a fresh delta may hold codes the scan never saw —
         # reusing a stale set would silently drop those rows); derived
         # sets are supersets by construction and survive appends.  Same
-        # entry shapes as the local AdaptiveDomainMixin.
+        # entry shapes, and the same `adaptive_kept` span (own time: memo,
+        # derivation, host `nonzero`; children: the presence pass), as the
+        # local AdaptiveDomainMixin.
         seg_sig = tuple(s.uid for s in ds.segments)
-        entry = self._adaptive_kept.get(qkey)
-        kept = None
-        if entry is not None:
-            if entry[0] == "derived":
-                kept = entry[1]
-            elif entry[1] == seg_sig:
-                kept = entry[2]
-        if kept is None:
-            # dictionary-derived shortcut (shared with the local engine):
-            # a filter that pins every grouping dim replaces the SPMD
-            # presence pass with O(cardinality) host work
-            from ..exec.adaptive_exec import filter_derived_kept
-
-            kept = filter_derived_kept(q, lowering, ds)
-            if kept is not None:
-                self._adaptive_kept[qkey] = ("derived", kept)
-        if kept is None:
-            # phase A reads only mask + dim-code columns (the shared
-            # helper keeps the physical time column when intervals need it)
-            from ..exec.adaptive_exec import presence_columns
-
-            need = presence_columns(q, lowering, ds)
-            # a failure of the pass raises (transient ones into execute()'s
-            # evict-and-retry path): it is never a reason to decline
-            cols, padded = self._place_shards(ds, need, m, q=q)
-            local_rows = padded // self.mesh.shape[DATA_AXIS]
-            run = self._presence_fn(
-                lowering, local_rows, ds, tuple(cols.keys())
+        with span(SPAN_ADAPTIVE_KEPT) as sp:
+            entry = self._adaptive_kept.get(qkey)
+            kept, source = None, "memo"
+            if entry is not None:
+                if entry[0] == "derived":
+                    kept = entry[1]
+                elif entry[1] == seg_sig:
+                    kept = entry[2]
+            if kept is None:
+                # dictionary-derived shortcut (shared with the local
+                # engine): a filter that pins every grouping dim replaces
+                # the SPMD presence pass with O(cardinality) host work
+                kept, source = filter_derived_kept(q, lowering, ds), "derived"
+                if kept is not None:
+                    self._adaptive_kept[qkey] = ("derived", kept)
+            if kept is None:
+                kept, source = self._measure_kept(q, ds, lowering, m), "measured"
+                self._adaptive_kept[qkey] = ("measured", seg_sig, kept)
+            Gc = 1
+            for kd in kept:
+                Gc *= len(kd)
+            declined = Gc > ADAPTIVE_MAX_COMPACT_GROUPS or (
+                Gc > ADAPTIVE_MIN_SHRINK * lowering.num_groups
             )
-            with span(SPAN_ADAPTIVE_PROBE):
-                counts = jax.device_get(run(cols))
-            kept = [
-                np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
-                for c in counts
-            ]
-            self._adaptive_kept[qkey] = ("measured", seg_sig, kept)
-        Gc = 1
-        for kd in kept:
-            Gc *= len(kd)
-        if Gc > ADAPTIVE_MAX_COMPACT_GROUPS or (
-            Gc > ADAPTIVE_MIN_SHRINK * lowering.num_groups
-        ):
+            if sp is not None:
+                sp.attrs.update(
+                    source=source, compact_groups=Gc, declined=declined,
+                    remap=[
+                        remap_form(kd, d.cardinality)
+                        for d, kd in zip(lowering.dims, kept)
+                    ],
+                )
+        if declined:
             log.info(
                 "mesh adaptive compaction declined: G'=%d of G=%d",
                 Gc, lowering.num_groups,
@@ -1059,8 +1112,35 @@ class DistributedEngine:
                 strat = "pallas"
         m.num_groups = clow.num_groups
         return self._execute_dense_state(
-            q, ds, clow, m, strat, key_extra=("adaptive",) + cards
+            q, ds, clow, m, strat, key_extra=("adaptive",) + cards,
+            span_attrs={"phase": "B"},
         )
+
+    def _measure_kept(self, q, ds, lowering, m) -> List[np.ndarray]:
+        """Adaptive phase A on the mesh: one presence program over the
+        scope's shards, its per-dim counts psum-merged, then the codes
+        seen.  A failure of the pass raises (transient ones into
+        execute()'s evict-and-retry path): it is never a reason to
+        decline."""
+        from ..exec.adaptive_exec import presence_columns
+        from ..plan.cost import allreduce_factor
+
+        # phase A reads only mask + dim-code columns (the shared helper
+        # keeps the physical time column when intervals need it)
+        need = presence_columns(q, lowering, ds)
+        cols, padded = self._place_shards(ds, need, m, q=q)
+        nd = self.mesh.shape[DATA_AXIS]
+        run = self._presence_fn(
+            lowering, padded // nd, ds, tuple(cols.keys())
+        )
+        with span(SPAN_ADAPTIVE_PROBE, phase="A", shards=nd):
+            launched = _launch(run, cols)
+        counts = _fetch(launched)
+        m.collective_bytes += round(allreduce_factor(nd) * _nbytes(counts))
+        return [
+            np.nonzero(np.asarray(c) > 0)[0].astype(np.int32)
+            for c in counts
+        ]
 
     # -- unified SPMD-arena core (ISSUE 15) ----------------------------------
     #
@@ -1126,7 +1206,6 @@ class DistributedEngine:
         cache hits), then cold stacks largest-first so the longest
         transfer issues earliest."""
         from ..exec.pipeline import placement_order
-        from ..obs import prof
         from ..resilience import fire
 
         fire("h2d")  # fault-injection site: shard placement
@@ -1212,7 +1291,6 @@ class DistributedEngine:
         the window LENGTH `Lk` but never the scope itself — two disjoint
         scopes of equal rounded size share one compiled program."""
         from ..exec.lowering import _query_key
-        from ..obs import prof
 
         # literal tag at the same tuple position as the legacy families
         # ("dense-state"/"sparse"/...) so no key can alias across
@@ -1235,7 +1313,6 @@ class DistributedEngine:
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_chunk_fn(self, lowering, ds, layout, strategy):
         from ..exec.lowering import _query_key
-        from ..obs import prof
 
         cache_key = _query_key(lowering.query, ds) + (
             layout.L,
@@ -1255,7 +1332,6 @@ class DistributedEngine:
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _arena_merge_fn(self, lowering, ds, tree):
         from ..exec.lowering import _query_key
-        from ..obs import prof
 
         cache_key = _query_key(lowering.query, ds) + (
             0,
@@ -1289,7 +1365,6 @@ class DistributedEngine:
             return None
         from ..exec.engine import _row_counts
         from ..exec.lowering import empty_partials
-        from ..obs import prof
         from ..resilience import current_deadline, current_partial
 
         la, G = lowering.la, lowering.num_groups
@@ -1303,6 +1378,9 @@ class DistributedEngine:
         else:
             canonical = sorted(layout.index[s.uid] for s in scope)
             j_lo, Lk = spmd_arena.scope_window(layout, canonical)
+            # every shard steps through the window, a block a step, with
+            # the blocks outside the scope dead: Lk x ndt >= len(scope)
+            m.shard_steps = float(Lk)
             memb = spmd_arena.membership_matrix(layout, [canonical])
             tree, flat_us, hier_us = self._merge_tree_for(q, lowering)
             m.est_collective_ms = min(flat_us, hier_us) / 1e3
@@ -1319,10 +1397,10 @@ class DistributedEngine:
                 )
                 m.program_cache_hit = len(compiled) == key_count
                 t0 = _time.perf_counter()
-                # single dispatch + single fetch under the collective-
-                # merge span: the receipt's dispatch_count is 1 per query
+                # single launch, single fetch: the receipt's
+                # dispatch_count is 1 per query
                 with span(
-                    SPAN_COLLECTIVE_MERGE, merge_tree=tree,
+                    SPAN_SEGMENT_DISPATCH, arena=1, merge_tree=tree,
                     shards=layout.ndt, window=Lk,
                 ):
                     span_event(
@@ -1331,10 +1409,12 @@ class DistributedEngine:
                         hier_us=round(hier_us, 3),
                         shards=layout.ndt, slices=self._slice_count(),
                     )
-                    t_call = _time.perf_counter()
-                    out_state = run(cols, np.int32(j_lo), memb)
-                    out_state = prof.dispatch_sync(out_state, t_call)
-                    sums, mins, maxs, _live = jax.device_get(out_state[0])
+                    out_state = _launch(run, cols, np.int32(j_lo), memb)
+                merged = _fetch(out_state[0])
+                sums, mins, maxs, _live = merged
+                m.collective_bytes += spmd_arena.boundary_merge_bytes(
+                    self._arena_mesh(), tree, merged
+                )
                 dt = (_time.perf_counter() - t0) * 1e3
                 if m.program_cache_hit:
                     m.device_ms = dt
@@ -1374,7 +1454,6 @@ class DistributedEngine:
         covers exactly the canonical blocks {j*ndt + d}, summed across
         shards."""
         from ..exec.engine import _row_counts
-        from ..obs import prof
         from ..resilience import checkpoint_partial, fire
 
         ndt = layout.ndt
@@ -1396,15 +1475,21 @@ class DistributedEngine:
                 SPAN_SEGMENT_DISPATCH, arena=1, chunk=j - j_lo,
                 shards=ndt,
             ):
-                t_call = _time.perf_counter()
-                carry = step_fn(carry, cols, np.int32(j), memb)
-                carry = prof.dispatch_sync(carry, t_call)
+                carry = _launch(step_fn, carry, cols, np.int32(j), memb)
             if pc is not None:
                 segs_j = by_step.get(j, [])
                 rows_j, delta_j = _row_counts(segs_j)
                 pc.add_seen(len(segs_j), rows_j, delta_j)
-        with span(SPAN_COLLECTIVE_MERGE, merge_tree=tree, shards=ndt):
-            sums, mins, maxs, _live = jax.device_get(merge_fn(carry)[0])
+        # the boundary merge is a program of its own here: one more launch
+        with span(
+            SPAN_SEGMENT_DISPATCH, arena=1, merge_tree=tree, shards=ndt,
+        ):
+            out_state = _launch(merge_fn, carry)
+        merged = _fetch(out_state[0])
+        sums, mins, maxs, _live = merged
+        m.collective_bytes += spmd_arena.boundary_merge_bytes(
+            self._arena_mesh(), tree, merged
+        )
         dt = (_time.perf_counter() - t0) * 1e3
         if m.program_cache_hit:
             m.device_ms = dt
@@ -1499,10 +1584,11 @@ class DistributedEngine:
             tree, _f, _h = self._merge_tree_for(inner, lowering)
             cols = self._place_arena(ds, layout, lowering.columns, scratch)
             run = self._arena_spmd_fn(lowering, ds, layout, Lk, strategy, tree)
-            with span(SPAN_COLLECTIVE_MERGE, merge_tree=tree, partials=1):
-                sums, mins, maxs, _live = jax.device_get(
-                    run(cols, np.int32(j_lo), memb)[0]
-                )
+            with span(
+                SPAN_SEGMENT_DISPATCH, arena=1, merge_tree=tree, partials=1,
+            ):
+                out_state = _launch(run, cols, np.int32(j_lo), memb)
+            sums, mins, maxs, _live = _fetch(out_state[0])
         state = self._pack_state(sums, mins, maxs)
         return state, sum(s.num_rows for s in segs)
 
@@ -1584,7 +1670,6 @@ class DistributedEngine:
         import json as _json
 
         from ..exec.lowering import _query_key
-        from ..obs import prof
 
         cache_key = _query_key(members[0][1], ds) + (
             layout.L,
@@ -1620,7 +1705,6 @@ class DistributedEngine:
         per-member execution (state still captured)."""
         from ..exec.lowering import empty_partials, memo_key
         from ..exec.metrics import QueryMetrics
-        from ..obs import prof
         from ..resilience import checkpoint, fire
 
         t0_all = _time.perf_counter()
@@ -1677,7 +1761,7 @@ class DistributedEngine:
             batch_m.program_cache_hit = len(compiled) == key_count
             t0 = _time.perf_counter()
             with span(
-                SPAN_COLLECTIVE_MERGE, merge_tree=tree, fused=n,
+                SPAN_SEGMENT_DISPATCH, arena=1, merge_tree=tree, fused=n,
                 shards=layout.ndt, window=Lk,
             ):
                 span_event(
@@ -1685,12 +1769,10 @@ class DistributedEngine:
                     hier_us=round(hier_us, 3), shards=layout.ndt,
                     slices=self._slice_count(), fused=n,
                 )
-                t_call = _time.perf_counter()
-                outs = fn(cols, np.int32(j_lo), memb)
-                outs = prof.dispatch_sync(outs, t_call)
-                # ONE fetch for the whole batch — the round trip the
-                # fused dispatch exists to amortize
-                states = jax.device_get(outs)
+                outs = _launch(fn, cols, np.int32(j_lo), memb)
+            # ONE fetch for the whole batch — the round trip the fused
+            # dispatch exists to amortize
+            states = _fetch(outs)
             dt = (_time.perf_counter() - t0) * 1e3
             if batch_m.program_cache_hit:
                 batch_m.device_ms = dt

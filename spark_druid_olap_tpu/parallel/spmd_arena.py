@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import SCOPE_BOUNDARY_MERGE, device_scope
 from ..utils.log import get_logger
 from .mesh import DATA_AXIS, SLICE_AXIS, row_axes
 
@@ -175,15 +176,34 @@ def _boundary_merge(mesh, tree: str, member_carry):
         mn = jnp.where(live, mn, jnp.inf)
     if mx.shape[1]:
         mx = jnp.where(live, mx, -jnp.inf)
-    groups = _merge_groups(mesh, tree)
-    for axes in groups:
-        s = lax.psum(s, axes)
-        if mn.shape[1]:
-            mn = lax.pmin(mn, axes)
-        if mx.shape[1]:
-            mx = lax.pmax(mx, axes)
-    live_n = lax.psum(live.astype(jnp.int32), tuple(row_axes(mesh)))
+    with device_scope(SCOPE_BOUNDARY_MERGE):
+        for axes in _merge_groups(mesh, tree):
+            s = lax.psum(s, axes)
+            if mn.shape[1]:
+                mn = lax.pmin(mn, axes)
+            if mx.shape[1]:
+                mx = lax.pmax(mx, axes)
+        live_n = lax.psum(live.astype(jnp.int32), tuple(row_axes(mesh)))
     return s, mn, mx, live_n
+
+
+def boundary_merge_bytes(mesh, tree: str, merged) -> int:
+    """Bytes a device sends in `_boundary_merge`, from the merged arrays
+    it returned (`(sums, mins, maxs, live_n)`, as fetched): each state
+    array is allreduced once per merge group of `tree`, the live count
+    once over all row devices; an allreduce over n devices moves
+    2(n-1)/n of the array (`plan.cost.allreduce_factor`)."""
+    from ..plan.cost import allreduce_factor
+
+    def over(axes) -> float:
+        return allreduce_factor(int(np.prod([mesh.shape[a] for a in axes])))
+
+    *state, live_n = merged
+    state_factor = sum(over(axes) for axes in _merge_groups(mesh, tree))
+    return round(
+        state_factor * sum(np.asarray(a).nbytes for a in state)
+        + over(row_axes(mesh)) * np.asarray(live_n).nbytes
+    )
 
 
 def build_spmd_arena_program(

@@ -125,6 +125,107 @@ def _clamp_bandwidth(bytes_per_s: float) -> float:
     return min(max(bytes_per_s, 1e6), 2e12)
 
 
+def measure_mesh() -> Dict[str, float]:
+    """The two constants only a mesh can give, over every device JAX
+    shows (real chips or a CPU-forced mesh; more than one):
+    `collective_bytes_per_us` from a psum of 64 MiB of f32 merge state
+    less the same program without the bytes, and `cost_dispatch_us` from
+    a tiny end-to-end SPMD aggregate.  `calibrate()` ends with it; alone
+    (`python -m spark_druid_olap_tpu.plan.calibrate mesh`) it is the
+    probe of a mesh deployment, seconds instead of the whole sweep."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..catalog.segment import ROW_PAD
+    from ..parallel.mesh import DATA_AXIS, make_mesh
+
+    rng = np.random.default_rng(0)
+    n_dev = len(jax.devices())
+    mesh = make_mesh(n_data=n_dev, n_groups=1)
+    # 64 MiB of f32 merge state a device: the allreduce then takes
+    # milliseconds.  At the 1 MiB this probe began with, the bytes' time
+    # (~40 us) lay under the host clock's noise around a 2.4 ms dispatch:
+    # five readings on four v5e chips ran from 6.5e3 to 1.6e7 bytes/us
+    state_g, state_m = 1 << 18, 64
+    local = jnp.asarray(
+        rng.random((n_dev * state_g, state_m)).astype(np.float32)
+    )
+    sharded = jax.device_put(local, NamedSharding(mesh, P(DATA_AXIS)))
+
+    # salt rides INSIDE the sharded dispatch (x + salt before the
+    # collective): a repeated byte-identical program+input pair is
+    # exactly what a remote dispatch cache would serve without
+    # executing — hazard (b) of _timeit_synced
+    @jax.jit
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS), P()),
+        out_specs=P(),
+        check_vma=False,
+    )
+    def allreduce(x, salt):
+        return jnp.sum(jax.lax.psum(x + salt, DATA_AXIS))
+
+    @jax.jit
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS), P()),
+        out_specs=P(),
+        check_vma=False,
+    )
+    def no_comm(x, salt):
+        # the baseline's tiny psum carries the SALT (not a foldable
+        # constant) so it survives compilation: it charges the
+        # collective's fixed launch latency to the baseline, leaving
+        # t_ar - t_base as pure bytes-moved time
+        return jnp.sum(jax.lax.psum(salt, DATA_AXIS)) + jnp.sum(x + salt)
+
+    t_ar = _timeit_synced(
+        lambda s: allreduce(sharded, jnp.full((1,), s, jnp.float32)), reps=5
+    )
+    t_base = _timeit_synced(
+        lambda s: no_comm(sharded, jnp.full((1,), s, jnp.float32)), reps=5
+    )
+    bytes_moved = 2.0 * (n_dev - 1) / n_dev * state_g * state_m * 4
+    t_comm = max(t_ar - t_base, 1e-7)
+    out = {"collective_bytes_per_us": bytes_moved / (t_comm * 1e6)}
+
+    # dispatch overhead: end-to-end tiny SPMD aggregate incl. host gather
+    tiny_rows = ROW_PAD * n_dev
+    tgid = jax.device_put(
+        np.zeros(tiny_rows, np.int32), NamedSharding(mesh, P(DATA_AXIS))
+    )
+    tsv = jax.device_put(
+        np.ones((tiny_rows, 1), np.float32),
+        NamedSharding(mesh, P(DATA_AXIS)),
+    )
+
+    @jax.jit
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P()),
+        out_specs=P(),
+        check_vma=False,
+    )
+    def tiny_agg(gid, v, salt):
+        return jnp.sum(
+            jax.lax.psum(
+                jax.ops.segment_sum(v + salt, gid, num_segments=8),
+                DATA_AXIS,
+            )
+        )
+
+    t_tiny = _timeit_synced(
+        lambda s: tiny_agg(tgid, tsv, jnp.full((1, 1), s, jnp.float32))
+    )
+    out["cost_dispatch_us"] = t_tiny * 1e6
+    return out
+
+
 def calibrate(
     rows: int = 1 << 23,
     groups: int = 1024,
@@ -453,89 +554,8 @@ def calibrate(
     out["partial"] = bool(over())
 
     # mesh measurements need >1 device (real chips or a CPU-forced mesh)
-    n_dev = len(jax.devices())
-    if n_dev > 1 and not over():
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from ..parallel.mesh import DATA_AXIS, make_mesh
-
-        mesh = make_mesh(n_data=n_dev, n_groups=1)
-        state_g, state_m = 4096, 64  # 1 MiB of f32 merge state
-        local = jnp.asarray(
-            rng.random((n_dev * state_g, state_m)).astype(np.float32)
-        )
-        sharded = jax.device_put(local, NamedSharding(mesh, P(DATA_AXIS)))
-
-        # salt rides INSIDE the sharded dispatch (x + salt before the
-        # collective): a repeated byte-identical program+input pair is
-        # exactly what a remote dispatch cache would serve without
-        # executing — hazard (b) of _timeit_synced
-        @jax.jit
-        @functools.partial(
-            jax.shard_map,
-            mesh=mesh,
-            in_specs=(P(DATA_AXIS), P()),
-            out_specs=P(),
-            check_vma=False,
-        )
-        def allreduce(x, salt):
-            return jnp.sum(jax.lax.psum(x + salt, DATA_AXIS))
-
-        @jax.jit
-        @functools.partial(
-            jax.shard_map,
-            mesh=mesh,
-            in_specs=(P(DATA_AXIS), P()),
-            out_specs=P(),
-            check_vma=False,
-        )
-        def no_comm(x, salt):
-            # the baseline's tiny psum carries the SALT (not a foldable
-            # constant) so it survives compilation: it charges the
-            # collective's fixed launch latency to the baseline, leaving
-            # t_ar - t_base as pure bytes-moved time
-            return jnp.sum(jax.lax.psum(salt, DATA_AXIS)) + jnp.sum(x + salt)
-
-        t_ar = _timeit_synced(
-            lambda s: allreduce(sharded, jnp.full((1,), s, jnp.float32))
-        )
-        t_base = _timeit_synced(
-            lambda s: no_comm(sharded, jnp.full((1,), s, jnp.float32))
-        )
-        bytes_moved = 2.0 * (n_dev - 1) / n_dev * state_g * state_m * 4
-        t_comm = max(t_ar - t_base, 1e-7)
-        out["collective_bytes_per_us"] = bytes_moved / (t_comm * 1e6)
-
-        # dispatch overhead: end-to-end tiny SPMD aggregate incl. host gather
-        tiny_rows = ROW_PAD * n_dev
-        tgid = jax.device_put(
-            np.zeros(tiny_rows, np.int32), NamedSharding(mesh, P(DATA_AXIS))
-        )
-        tsv = jax.device_put(
-            np.ones((tiny_rows, 1), np.float32),
-            NamedSharding(mesh, P(DATA_AXIS)),
-        )
-
-        @jax.jit
-        @functools.partial(
-            jax.shard_map,
-            mesh=mesh,
-            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P()),
-            out_specs=P(),
-            check_vma=False,
-        )
-        def tiny_agg(gid, v, salt):
-            return jnp.sum(
-                jax.lax.psum(
-                    jax.ops.segment_sum(v + salt, gid, num_segments=8),
-                    DATA_AXIS,
-                )
-            )
-
-        t_tiny = _timeit_synced(
-            lambda s: tiny_agg(tgid, tsv, jnp.full((1, 1), s, jnp.float32))
-        )
-        out["cost_dispatch_us"] = t_tiny * 1e6
+    if len(jax.devices()) > 1 and not over():
+        out.update(measure_mesh())
 
     if save_path:
         with open(save_path, "w") as f:
@@ -556,4 +576,7 @@ def calibrate(
 
 
 if __name__ == "__main__":
-    print(json.dumps(calibrate()))
+    import sys
+
+    mesh_only = sys.argv[1:] == ["mesh"]
+    print(json.dumps(measure_mesh() if mesh_only else calibrate()))

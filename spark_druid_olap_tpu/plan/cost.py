@@ -71,6 +71,18 @@ def groupby_state_bytes(q: Q.QuerySpec, num_groups: int, cfg: SessionConfig) -> 
     return (per_group + 4) * num_groups  # +4: hidden __rows counter
 
 
+def allreduce_factor(n: int) -> float:
+    """Bytes each device sends in a ring allreduce over `n` devices, as a
+    multiple of the state's bytes: 2(n-1)/n.  One owner: the planner's
+    estimate and the mesh's counted `collective_bytes` both use it."""
+    return 2.0 * (n - 1) / max(1, n)
+
+
+def allgather_factor(n: int) -> float:
+    """As `allreduce_factor`, for an all_gather: (n-1) x one device's state."""
+    return float(max(0, n - 1))
+
+
 def choose_merge_tree(
     state_bytes: int,
     n_slices: int,
@@ -96,10 +108,10 @@ def choose_merge_tree(
     flat_bw = (
         cfg.dcn_bytes_per_us if n_slices > 1 else cfg.collective_bytes_per_us
     )
-    flat_us = 2.0 * (n - 1) / n * state_bytes / max(1.0, flat_bw)
-    hier_us = 2.0 * (nd_per_slice - 1) / max(1, nd_per_slice) * (
+    flat_us = allreduce_factor(n) * state_bytes / max(1.0, flat_bw)
+    hier_us = allreduce_factor(nd_per_slice) * (
         state_bytes / max(1.0, cfg.collective_bytes_per_us)
-    ) + 2.0 * (n_slices - 1) / max(1, n_slices) * (
+    ) + allreduce_factor(n_slices) * (
         state_bytes / max(1.0, cfg.dcn_bytes_per_us)
     )
     tree = "hierarchical" if hier_us < flat_us else "flat"
@@ -441,12 +453,10 @@ def choose_physical(
                 1, min(per_device_groups, round(num_groups * sel))
             )
             state_bytes = groupby_state_bytes(q, g_eff, cfg)
-            # all_gather moves ~(nd-1) x one device's state
-            factor = float(nd - 1)
+            factor = allgather_factor(nd)
         else:
             state_bytes = groupby_state_bytes(q, per_device_groups, cfg)
-            # ring allreduce moves ~2*(nd-1)/nd of the state
-            factor = 2.0 * (nd - 1) / nd
+            factor = allreduce_factor(nd)
         collective = (
             factor * state_bytes / max(cfg.collective_bytes_per_us, 1e-9)
         )

@@ -329,6 +329,15 @@ def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    # every collective the compiler kept names its scope: a four-chip
+    # trace shows the boundary merge's `%all-reduce`s under it
+    import re
+
+    merges = [
+        ln for ln in text.splitlines()
+        if re.search(r"= \S+ all-reduce(-start)?\(", ln)
+    ]
+    assert merges and all("sdol.boundary_merge" in ln for ln in merges), merges
 
 
 def test_compiled_programs_keep_device_scopes(one_chip, ssb_ctx, pallas_on):

@@ -67,8 +67,12 @@ _OBS_TRACE_FIXTURE = """
     SPAN_H2D = "h2d"
     SPAN_FINALIZE = "finalize"
     SPAN_NAMES = frozenset({SPAN_H2D, SPAN_FINALIZE})
+    SCOPE_BOUNDARY_MERGE = "sdol.boundary_merge"
 
     def span(name, **attrs):
+        pass
+
+    def device_scope(name):
         pass
 """
 
@@ -950,6 +954,24 @@ _MATRIX = {
                 },
                 {"GL1101"},
             ),
+            # a device scope the registry does not hold: no trace reader
+            # (`tools/trace_scopes.py`) could find the collective by name
+            (
+                {
+                    "spark_druid_olap_tpu/obs/trace.py": _OBS_TRACE_FIXTURE,
+                    "spark_druid_olap_tpu/parallel/merge.py": """
+                        from jax import lax
+
+                        from ..obs.trace import device_scope
+                        from .mesh import DATA_AXIS
+
+                        def merge(state):
+                            with device_scope("sdol.my_merge"):
+                                return lax.psum(state, DATA_AXIS)
+                    """,
+                },
+                {"GL1101"},
+            ),
             # manually paired begin/end: the early `return` leaks an open
             # span — only the context manager owns the pairing
             (
@@ -990,6 +1012,20 @@ _MATRIX = {
                     def run(batches):
                         with span("finalize"):
                             return [dispatch(b) for b in batches]
+                """,
+            },
+            # the mesh's collectives under the registered scope constant
+            {
+                "spark_druid_olap_tpu/obs/trace.py": _OBS_TRACE_FIXTURE,
+                "spark_druid_olap_tpu/parallel/merge.py": """
+                    from jax import lax
+
+                    from ..obs.trace import SCOPE_BOUNDARY_MERGE, device_scope
+                    from .mesh import DATA_AXIS
+
+                    def merge(state):
+                        with device_scope(SCOPE_BOUNDARY_MERGE):
+                            return lax.psum(state, DATA_AXIS)
                 """,
             },
             # outside the instrumented surface the pass is silent (a
@@ -2163,11 +2199,11 @@ _MATRIX = {
             """},
             # parallel/ keeps its own sharded-dispatch contract
             {"spark_druid_olap_tpu/parallel/distributed.py": """
-                from ..obs import SPAN_COLLECTIVE_MERGE, span
+                from ..obs import SPAN_SEGMENT_DISPATCH, span
 
                 def merge(self, fn, shards):
                     for s in shards:
-                        with span(SPAN_COLLECTIVE_MERGE):
+                        with span(SPAN_SEGMENT_DISPATCH):
                             fn(s)
             """},
         ],
@@ -2202,11 +2238,11 @@ _MATRIX = {
             # is the per-shard round trip the sharded arena collapsed
             (
                 {"spark_druid_olap_tpu/parallel/looper.py": """
-                    from ..obs import SPAN_COLLECTIVE_MERGE, span
+                    from ..obs import SPAN_SEGMENT_DISPATCH, span
 
                     def merge_each(self, fn, shards):
                         for s in shards:
-                            with span(SPAN_COLLECTIVE_MERGE):
+                            with span(SPAN_SEGMENT_DISPATCH):
                                 fn(s)
                 """},
                 {"GL2203"},

@@ -241,7 +241,8 @@ def test_tracer_overhead_on_cached_ssb_query_counted_not_timed():
     # and reads no clock.  On one device that is 22: the root, plan,
     # execute, route x2, lower, program_lookup, h2d, segment_dispatch,
     # device_fetch, finalize; it was 17.  On the eight virtual devices
-    # of conftest.py the mesh serves the query with 14; it was 11.)
+    # of conftest.py the mesh serves the query with two more since
+    # ISSUE 27: its launch and its fetch are a span each.)
     assert 0 < clk.calls <= 250, clk.calls
     # and the instrumentation actually produced the span tree
     d = ctx.tracer.last.to_dict()

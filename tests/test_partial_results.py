@@ -567,6 +567,12 @@ def test_hammer_appends_vs_partial_queries_never_double_count():
                 },
             )
             appended["rows"] += batch
+            # leave the query thread the interpreter between batches: an
+            # appender that never yields grows the table without bound on
+            # a machine that schedules it generously, and every query then
+            # stacks all of it (the test ran 117 s in one run of the suite
+            # and over 20 minutes in another, at the same commit)
+            stop.wait(0.002)
 
     th = threading.Thread(target=appender, daemon=True)
     th.start()

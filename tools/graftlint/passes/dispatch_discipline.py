@@ -42,9 +42,9 @@ from ..core import LintPass, ModuleContext, dotted_name, is_jit_callee
 # a device dispatch — the receipt's dispatch_count buckets
 _DISPATCH_SPANS = frozenset({
     "SPAN_SEGMENT_DISPATCH", "SPAN_SPARSE_DISPATCH", "SPAN_ADAPTIVE_PROBE",
-    "SPAN_STREAM_CHUNK", "SPAN_COLLECTIVE_MERGE",
+    "SPAN_STREAM_CHUNK",
     "segment_dispatch", "sparse_dispatch", "adaptive_probe",
-    "stream_chunk", "collective_merge",
+    "stream_chunk",
 })
 
 

@@ -53,9 +53,9 @@ _COLLECTIVES = {
 
 _DISPATCH_SPANS = frozenset({
     "SPAN_SEGMENT_DISPATCH", "SPAN_SPARSE_DISPATCH", "SPAN_ADAPTIVE_PROBE",
-    "SPAN_STREAM_CHUNK", "SPAN_COLLECTIVE_MERGE",
+    "SPAN_STREAM_CHUNK",
     "segment_dispatch", "sparse_dispatch", "adaptive_probe",
-    "stream_chunk", "collective_merge",
+    "stream_chunk",
 })
 
 

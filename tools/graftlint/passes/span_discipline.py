@@ -12,6 +12,11 @@ match on BY NAME.  Two contracts keep that vocabulary auditable:
   project layer, so `span(SPAN_H2D)` and a literal `span("h2d")` both
   verify).  Ad-hoc or dynamically-built names fragment the taxonomy and
   silently break every name-matching consumer.
+  The same holds for `device_scope(...)`, which names a part of a traced
+  program in the HLO metadata a profiler trace shows: its argument must
+  be a registered `SCOPE_*` constant of the same module
+  (`tools/trace_scopes.py` and the mesh's `sdol.boundary_merge` readers
+  match on those names).
 * **GL1102** — spans are opened ONLY through the `span(...)` context
   manager: direct calls to the pairing internals
   (`QueryTrace.start_span` / `end_span`) leak an open span on every
@@ -30,7 +35,7 @@ set to verify against — while GL1102 still applies.
 from __future__ import annotations
 
 import ast
-from typing import Optional, Set
+from typing import Dict, Optional, Set, Tuple
 
 from ..core import LintPass, ModuleContext, call_name
 
@@ -48,49 +53,52 @@ class SpanDisciplinePass(LintPass):
             "spark_druid_olap_tpu/api.py",
             "spark_druid_olap_tpu/server.py",
         ),
-        # where the registered span-name constants live
+        # where the registered span-name and device-scope constants live
         "registry_module": "spark_druid_olap_tpu/obs/trace.py",
         "constant_prefix": "SPAN_",
+        "scope_prefix": "SCOPE_",
     }
 
     def __init__(self, config=None):
         super().__init__(config)
-        self._registered_cache: Optional[Set[str]] = None
-        self._registered_known = False
+        self._registered_cache: Dict[str, Optional[Set[str]]] = {}
 
     # -- registry resolution --------------------------------------------------
 
-    def _registered(self) -> Optional[Set[str]]:
-        """String values of every `SPAN_*` module constant in the registry
-        module; None when the registry module is not in the scanned tree."""
-        if self._registered_known:
-            return self._registered_cache
-        self._registered_known = True
-        if self.project is None:
-            return None
-        mod = self.project.modules.get(self.config["registry_module"])
-        if mod is None:
-            return None
-        prefix = self.config["constant_prefix"]
+    def _registered(self, prefix: str) -> Optional[Set[str]]:
+        """String values of every `<prefix>*` module constant in the
+        registry module; None when the registry module is not in the
+        scanned tree."""
+        if prefix in self._registered_cache:
+            return self._registered_cache[prefix]
         names: Set[str] = set()
-        for cname, expr in mod.constants.items():
+        mod = (
+            self.project.modules.get(self.config["registry_module"])
+            if self.project is not None else None
+        )
+        for cname, expr in (mod.constants.items() if mod else ()):
             if (
                 cname.startswith(prefix)
                 and isinstance(expr, ast.Constant)
                 and isinstance(expr.value, str)
             ):
                 names.add(expr.value)
-        self._registered_cache = names or None
-        return self._registered_cache
+        self._registered_cache[prefix] = names or None
+        return self._registered_cache[prefix]
 
-    @staticmethod
-    def _is_span_call(name: str, canon: str) -> bool:
+    def _name_kind(self, name: str, canon: str) -> Optional[Tuple[str, str]]:
+        """(what the call names, the registry prefix it is held to), or
+        None for a call this pass does not police."""
         # `span(NAME)`, its decorator form `span_around(NAME)` and the
         # tracer's `early_span(NAME)` all take the name first
         last = name.rsplit(".", 1)[-1]
-        if last in ("span", "span_around", "early_span"):
-            return True
-        return canon.endswith(("obs.span", "obs.trace.span"))
+        if last in ("span", "span_around", "early_span") or canon.endswith(
+            ("obs.span", "obs.trace.span")
+        ):
+            return "span", self.config["constant_prefix"]
+        if last == "device_scope":
+            return "device scope", self.config["scope_prefix"]
+        return None
 
     # -- handlers -------------------------------------------------------------
 
@@ -113,9 +121,11 @@ class SpanDisciplinePass(LintPass):
                 "`with span(NAME):` context manager (obs/trace.py)",
             )
             return
-        if not self._is_span_call(name, canon):
+        kind = self._name_kind(name, canon)
+        if kind is None:
             return
-        registered = self._registered()
+        what, prefix = kind
+        registered = self._registered(prefix)
         if registered is None:
             return  # registry module not in this run's scope
         arg = node.args[0] if node.args else None
@@ -127,22 +137,22 @@ class SpanDisciplinePass(LintPass):
         if arg is None:
             self.report(
                 ctx, node, "GL1101",
-                "span() call without a name argument",
+                f"{what} call without a name argument",
             )
             return
         val = self.project.resolve_string(module, arg)
         if val is None:
             self.report(
                 ctx, node, "GL1101",
-                "span name is not a statically-resolvable string — name "
-                "spans with a registered SPAN_* constant from obs/trace.py "
+                f"{what} name is not a statically-resolvable string — name "
+                f"it with a registered {prefix}* constant from obs/trace.py "
                 "(dynamic names fragment the taxonomy every trace consumer "
                 "matches on)",
             )
         elif val not in registered:
             self.report(
                 ctx, node, "GL1101",
-                f"span name {val!r} is not in the registered span-name set "
-                "(obs/trace.py SPAN_* constants) — register the constant "
-                "first, then use it",
+                f"{what} name {val!r} is not in the registered set "
+                f"(obs/trace.py {prefix}* constants) — register the "
+                "constant first, then use it",
             )

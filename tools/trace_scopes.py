@@ -10,7 +10,11 @@ line, its total time, its SELF time (less the operations it holds: a
 `%while` holds its body's) and what holds it.  With `request:<query>`
 annotations on the host plane (the benchmark's) the same table is also
 given per query, and the program's `sdol:<span>` host events are summed
-by name and checked to lie inside their request.
+by name and checked to lie inside their request.  A trace of several
+chips (the mesh) has a device plane each: the tables sum over them, and
+`scopes by chip` gives each plane's self time by innermost scope, so that
+the boundary merge (`sdol.boundary_merge`) and the wait in it for the
+slowest shard can be read per chip, and per request and chip.
 
 A by-hand tool, not the benchmark's yardstick (`benchmark/harness/
 trace_reduce.py` is): it needs the xplane schema for the per-operation
@@ -103,7 +107,7 @@ def host_events(space):
 
 def device_rows(space, requests):
     """One row per device operation event: (request name, family, scope,
-    op tail, source, duration ps, self ps, holder family)."""
+    op tail, source, duration ps, self ps, holder family, device plane)."""
     rows = []
     for plane in space.planes:
         if not plane.name.startswith(DEVICE_PLANE_PREFIX):
@@ -124,7 +128,8 @@ def device_rows(space, requests):
                 )
                 fam, scope, tail, src = meta.get(mid, ("?", "-", "", ""))
                 rows.append([req, fam, scope, tail, src, b - a, b - a,
-                             holder[1] if holder is not None else "-"])
+                             holder[1] if holder is not None else "-",
+                             plane.name])
                 stack.append((b, len(rows) - 1))
     return rows
 
@@ -132,7 +137,7 @@ def device_rows(space, requests):
 def table(rows, top):
     """Rows summed by (family, scope, holder), ranked by self time."""
     acc = defaultdict(lambda: [0, 0, 0, "", ""])
-    for _req, fam, scope, tail, src, dur, self_ps, holder in rows:
+    for _req, fam, scope, tail, src, dur, self_ps, holder, _plane in rows:
         a = acc[(fam, scope, holder)]
         a[0] += 1
         a[1] += dur
@@ -145,6 +150,18 @@ def table(rows, top):
          "op_name": tail, "source": src}
         for (fam, scope, holder), (n, dur, self_ps, tail, src) in ranked
     ]
+
+
+def scopes_by_chip(rows):
+    """Self seconds by innermost scope on each device plane:
+    {scope: {plane: seconds}}, with "-" for operations under no scope."""
+    acc = defaultdict(lambda: defaultdict(int))
+    for r in rows:
+        acc[r[2].split("/")[-1]][r[8]] += r[6]
+    return {
+        scope: {plane: ps / 1e12 for plane, ps in sorted(chips.items())}
+        for scope, chips in sorted(acc.items())
+    }
 
 
 def span_summary(requests, spans):
@@ -169,13 +186,19 @@ def summarize(path):
     requests, spans = host_events(space)
     rows = device_rows(space, requests)
     out = {"device_ops": table(rows, TOP), "by_request": {},
+           "scopes_by_chip": scopes_by_chip(rows),
            "host": span_summary(requests, spans)}
+    chips = len({r[8] for r in rows}) or 1
     for req in sorted({r[0] for r in rows} - {"-"}):
         mine = [r for r in rows if r[0] == req]
         n = sum(1 for name, _, _ in requests if name == req)
         out["by_request"][req] = {
             "requests": n,
             "device_self_s_per_request": sum(r[6] for r in mine) / 1e12 / n,
+            "scope_ms_per_request_and_chip": {
+                scope: sum(by.values()) * 1e3 / n / chips
+                for scope, by in scopes_by_chip(mine).items()
+            },
             "ops": table(mine, 6),
         }
     return out
@@ -199,7 +222,11 @@ def main(argv=None):
     for req, d in out["by_request"].items():
         print(f"{req}: {d['requests']} requests, "
               f"{d['device_self_s_per_request'] * 1e3:.2f} ms on the device each")
+        print("  ms by scope, a request and chip:",
+              json.dumps({k: round(v, 4) for k, v in
+                          d["scope_ms_per_request_and_chip"].items()}))
         show(d["ops"])
+    print("scopes by chip (self s):", json.dumps(out["scopes_by_chip"]))
     print("host spans:", json.dumps(out["host"]))
     return 0
 
