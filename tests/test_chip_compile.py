@@ -10,6 +10,8 @@ import — so every xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,6 +70,23 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_lane_dense(text):
+    """A compiled program hands the kernel lane-dense rows: no line
+    defines a per-row integer or predicate array as a column (`s32[R,1]`
+    or `pred[R,1]` with the unit dimension minor: 128 x padding in (8,
+    128) tiles, what the kernel's operands were before PR 28), by any
+    operation (`copy`, `broadcast`, `fusion`, `bitcast`) under any scope,
+    and no `%copy` relays a whole per-row array out on the way to the
+    kernel.  A per-row dimension has five digits or more (a segment is
+    2^19 rows); no group count here has."""
+    names = lambda pattern: [
+        ln.split(" = ")[0].strip() for ln in text.splitlines()
+        if re.search(pattern, ln)
+    ]
+    assert not names(r"= (s32|pred)\[\d{5,},1\]\{1,0")
+    assert not names(r"= (s32|pred|f32|bf16)\[(\d+,)?\d{5,}(,\d+)?\]\S* copy\(")
+
+
 # (G, Ms, Mn, Mx): SSB q1 (one group), TPC-H Q1, a min/max mix at the
 # 1024-group tile edge, the widest single tile, q2's 8008 (two tiles)
 KERNEL_SHAPES = [
@@ -86,6 +105,24 @@ def test_kernel_compiles_for_v5e(one_chip, G, Ms, Mn, Mx):
         num_groups=G, num_min=Mn, num_max=Mx,
     ).compile()
     _assert_kernel(compiled)
+    text = compiled.as_text()
+    _assert_lane_dense(text)
+    # the f32 values' exact split into bf16 parts survives the TPU
+    # pipeline only as `reduce-precision`: a convert to bf16 and back is
+    # elided there as excess precision, and the sums fall to bf16
+    assert text.count(" reduce-precision(") >= 2, "the bf16 split was elided"
+
+
+def test_kernel_compiles_at_the_mesh_shape_of_use(one_chip):
+    """The mesh's dense-state call (`parallel/distributed.py`): the kernel
+    once over a shard's whole rows, 29 SF10 segments at G' = 800, with no
+    column operand and no relayout in front of it."""
+    compiled = pallas_partial_aggregate.lower(
+        *_kernel_args((29 * R_SEGMENT,), 800, 3, 0, 0, one_chip),
+        num_groups=800, num_min=0, num_max=0,
+    ).compile()
+    _assert_kernel(compiled)
+    _assert_lane_dense(compiled.as_text())
 
 
 SCAN_G, SCAN_MS = 1024, 2
@@ -224,6 +261,7 @@ def test_engine_arena_scan_compiles(one_chip, ssb_ctx, pallas_on, name):
     )
     text = _compiled_arena_text(ssb_ctx, ds, lowering, program, one_chip)
     assert "tpu_custom_call" in text
+    _assert_lane_dense(text)
 
 
 def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
@@ -265,13 +303,12 @@ def test_adaptive_phase_b_relays_out_only_the_kernel_operands(
 ):
     """The adaptive tier's phase-B arena program over the compacted
     lowering: the kept-code remap (`sdol.kept_remap`) stays fused in the
-    group-id arithmetic.  The Pallas kernel takes its group ids and mask
-    as `[R, 1]` operands, 128 x padded in (8,128) tiles; what regressed
-    before PR 26 is the compiler carrying that layout up into the remap
-    (27 `%copy` of 268 MB a segment for q2_1).  Two relayouts a segment
-    are the kernel's own operands; none belongs to the remap."""
-    import re
-
+    group-id arithmetic, and the kernel takes the packed id as the dense
+    row the fusion writes.  Before PR 28 its operands were `[R, 1]`
+    columns, 128 x padded in (8, 128) tiles: two relayouts a segment in
+    front of it (and, before PR 26, 27 more carried up into q2_1's
+    remap).  Now none: no column operand is defined anywhere in the
+    program and nothing is copied on the way to the kernel."""
     from spark_druid_olap_tpu.exec import adaptive_exec
     from spark_druid_olap_tpu.exec.engine import Engine
 
@@ -297,12 +334,8 @@ def test_adaptive_phase_b_relays_out_only_the_kernel_operands(
     )
     text = _compiled_arena_text(ssb_ctx, ds, clow, program, one_chip)
     assert "tpu_custom_call" in text
-    copies = [
-        line for line in text.splitlines()
-        if re.search(rf"= s32\[{R_SEGMENT},1\]\S* copy\(", line)
-    ]
-    assert not [c for c in copies if "sdol.kept_remap" in c]
-    assert len(copies) == 2, [c.split(" = ")[0].strip() for c in copies]
+    assert "sdol.kept_remap" in text
+    _assert_lane_dense(text)
 
 
 def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
@@ -329,10 +362,9 @@ def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    _assert_lane_dense(text)
     # every collective the compiler kept names its scope: a four-chip
     # trace shows the boundary merge's `%all-reduce`s under it
-    import re
-
     merges = [
         ln for ln in text.splitlines()
         if re.search(r"= \S+ all-reduce(-start)?\(", ln)
@@ -344,8 +376,6 @@ def test_compiled_programs_keep_device_scopes(one_chip, ssb_ctx, pallas_on):
     """The scopes (`obs.SCOPE_*`) survive the TPU compiler: the compiled
     arena program's operations carry them in their `op_name` metadata,
     which is what a profiler trace shows for each device operation."""
-    import re
-
     from spark_druid_olap_tpu.exec.engine import Engine
 
     q, ds, lowering = _lowered_query(ssb_ctx, "q4_1")

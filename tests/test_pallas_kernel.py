@@ -13,51 +13,101 @@ from spark_druid_olap_tpu.ops.pallas_groupby import pallas_partial_aggregate
 INTERPRET = True
 
 
-def _mk(R, G, Ms, Mn, Mx, seed=0, mask_p=0.8):
+def _mk(R, G, Ms, Mn, Mx, seed=0, mask_p=0.8, stray_ids=False):
+    """Operands as the lowering hands them over: the last sum column is a
+    count (pre-masked ones), masked rows may carry any id."""
     rng = np.random.default_rng(seed)
-    gid = jnp.asarray(rng.integers(0, G, R).astype(np.int32))
-    mask = jnp.asarray(rng.random(R) < mask_p)
-    sv = jnp.asarray(
-        (rng.random((R, Ms)) * np.asarray(mask)[:, None]).astype(np.float32)
-    )
-    mmv = jnp.asarray(rng.random((R, Mn + Mx)).astype(np.float32))
-    mmm = jnp.asarray(rng.random((R, Mn + Mx)) < 0.9)
+    gid = rng.integers(0, G, R).astype(np.int32)
+    mask = rng.random(R) < mask_p
+    if stray_ids:
+        stray = rng.choice(
+            np.asarray([-1, -7, G, G + 5, 1 << 20, np.iinfo(np.int32).max,
+                        np.iinfo(np.int32).min], np.int32), R)
+        gid = np.where(mask, gid, stray)
+    sv = rng.random((R, Ms)).astype(np.float32)
+    sv[:, -1] = 1.0
+    sv = sv * mask[:, None]
+    mmv = rng.standard_normal((R, Mn + Mx)).astype(np.float32)
+    mmm = rng.random((R, Mn + Mx)) < 0.9
     return gid, mask, sv, mmv, mmm
 
 
-@pytest.mark.parametrize(
-    "R,G,Ms,Mn,Mx",
-    [
-        (4096, 12, 3, 0, 0),      # Q1 shape: tiny G, no extrema
-        (8192, 300, 4, 2, 1),     # mid G with min/max
-        (8192, 700, 2, 1, 1),     # G > one group tile => 2D grid
-        (1024, 1, 1, 0, 0),       # degenerate single group
-    ],
-)
-def test_pallas_matches_dense(R, G, Ms, Mn, Mx):
-    gid, mask, sv, mmv, mmm = _mk(R, G, Ms, Mn, Mx)
-    want = dense_partial_aggregate(
-        gid, mask, sv, mmv, mmm,
-        num_groups=G, block_rows=1024, num_min=Mn, num_max=Mx,
-    )
-    got = pallas_partial_aggregate(
-        gid, mask, sv, mmv, mmm,
-        num_groups=G, num_min=Mn, num_max=Mx, interpret=INTERPRET,
-    )
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]), rtol=1e-6)
+def _reference(gid, mask, sv, mmv, mmm, G, Mn, Mx):
+    """float64 numpy, one scatter a column: what every strategy must equal."""
+    sums = np.zeros((G, sv.shape[1]))
+    np.add.at(sums, gid[mask], sv[mask].astype(np.float64))
+    mins = np.full((G, Mn), np.inf, np.float32)
+    maxs = np.full((G, Mx), -np.inf, np.float32)
+    for m in range(Mn):
+        ok = mask & mmm[:, m]
+        np.minimum.at(mins[:, m], gid[ok], mmv[ok, m])
+    for m in range(Mx):
+        ok = mask & mmm[:, Mn + m]
+        np.maximum.at(maxs[:, m], gid[ok], mmv[ok, Mn + m])
+    return sums, mins, maxs
 
 
-def test_pallas_all_masked():
-    gid, mask, sv, mmv, mmm = _mk(2048, 10, 2, 1, 1, mask_p=0.0)
-    sums, mins, maxs = pallas_partial_aggregate(
-        gid, jnp.zeros_like(mask), sv * 0, mmv, mmm,
-        num_groups=10, num_min=1, num_max=1, interpret=INTERPRET,
-    )
-    assert float(np.abs(np.asarray(sums)).sum()) == 0.0
-    assert np.isinf(np.asarray(mins)).all() and (np.asarray(mins) > 0).all()
-    assert np.isinf(np.asarray(maxs)).all() and (np.asarray(maxs) < 0).all()
+# (R, G, Ms, Mn, Mx, mask_p, stray_ids)
+PARITY_CASES = {
+    "one_group": (1024, 1, 1, 0, 0, 0.8, False),
+    "q1_tiny_g": (4096, 12, 3, 0, 0, 0.8, False),
+    "g_off_the_lane_tile": (8192, 300, 4, 2, 1, 0.8, False),
+    "min_only": (2048, 130, 2, 2, 0, 0.8, False),
+    "max_only": (2048, 130, 2, 0, 2, 0.8, False),
+    "widest_single_tile": (4096, 4096, 2, 0, 0, 0.8, False),
+    "two_group_tiles": (4096, 8008, 2, 1, 1, 0.8, False),
+    "all_rows_masked": (2048, 10, 2, 1, 1, 0.0, False),
+    "all_rows_masked_stray_ids": (2048, 10, 2, 1, 1, 0.0, True),
+    "masked_rows_carry_stray_ids": (8192, 700, 3, 1, 1, 0.6, True),
+    "no_row_masked": (2048, 208, 2, 0, 0, 1.1, False),
+    # 131 is prime: the only blocks that divide R are single 128-lane tiles
+    "rows_with_small_divisors_only": (128 * 131, 37, 2, 1, 0, 0.8, True),
+    "several_grid_steps_and_tiles": (1 << 16, 260, 3, 0, 0, 0.8, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARITY_CASES))
+def test_pallas_parity(case):
+    """The kernel against the XLA dense strategy and a float64 reference:
+    counts exact, sums to f32 accumulation error, min/max exact, empty
+    groups 0 / +inf / -inf."""
+    R, G, Ms, Mn, Mx, mask_p, stray = PARITY_CASES[case]
+    ops = _mk(R, G, Ms, Mn, Mx, mask_p=mask_p, stray_ids=stray)
+    got = [
+        np.asarray(a) for a in pallas_partial_aggregate(
+            *map(jnp.asarray, ops),
+            num_groups=G, num_min=Mn, num_max=Mx, interpret=INTERPRET,
+        )
+    ]
+    want = _reference(*ops, G, Mn, Mx)
+    assert [a.shape for a in got] == [(G, Ms), (G, Mn), (G, Mx)]
+    np.testing.assert_array_equal(got[0][:, -1], want[0][:, -1])  # counts
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-6)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+    empty = want[0][:, -1] == 0
+    if mask_p == 0.0:
+        assert empty.all()
+    assert (got[0][empty] == 0).all()
+    if not stray and R % 1024 == 0:
+        # the dense strategy clamps nothing and needs in-range ids
+        dense = dense_partial_aggregate(
+            *map(jnp.asarray, ops),
+            num_groups=G, block_rows=1024, num_min=Mn, num_max=Mx,
+        )
+        for g, d in zip(got, dense):
+            np.testing.assert_allclose(g, np.asarray(d), rtol=1e-6)
+
+
+def test_pallas_rejects_rows_it_cannot_tile():
+    """An R off the 128-lane tile has no lane-dense block."""
+    R = 1000
+    ops = _mk(R, 4, 1, 0, 0)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        pallas_partial_aggregate(
+            *map(jnp.asarray, ops),
+            num_groups=4, num_min=0, num_max=0, interpret=INTERPRET,
+        )
 
 
 def test_engine_pallas_strategy_parity(lineitem_ds):
