@@ -81,7 +81,7 @@ class TPUOlapContext:
         # ~200x slower than scatter there).
         self.config = config or SessionConfig.load_calibrated()
         self.catalog = MetadataCache()
-        self.engine = Engine()
+        self.engine = Engine(config=self.config)
         # overlapped h2d transfer pipeline (exec/pipeline.py, ISSUE 10):
         # prefetch depth / speculation byte cap / on-off come from config
         self.engine.configure_pipeline(self.config)
@@ -625,7 +625,9 @@ class TPUOlapContext:
         engine = self._engine_for(rw)
 
         def refinements():
-            for df, info in engine.execute_progressive(q, ds):
+            for df, info in engine.execute_progressive(
+                q, ds, strategy=rw.physical.strategy
+            ):
                 yield self._post_process(rw, ds, df), info
             self._last_engine_metrics = getattr(
                 engine, "last_metrics", None
@@ -1181,7 +1183,9 @@ class TPUOlapContext:
             rw.query, (Q.GroupByQuery, Q.TimeseriesQuery, Q.TopNQuery)
         ):
             return False
-        return self._engine_for(rw).fusable(rw.query, ds)
+        return self._engine_for(rw).fusable(
+            rw.query, ds, strategy=rw.physical.strategy
+        )
 
     def execute_rewrite(self, rw: Rewrite, use_result_cache: bool = True):
         import pandas as pd
@@ -1221,10 +1225,16 @@ class TPUOlapContext:
 
         with span(SPAN_ROUTE):
             engine = self._engine_for(rw)
+        # the plan's kernel class and this session's cost constants
+        # travel with the query: arguments of the call, never a field
+        # written on the (shared) engine
+        route = {"strategy": rw.physical.strategy, "cfg": self.config}
         state = None
         fusable = self._fusable(rw, ds)
         fused = (
-            self.serve.fused_execute(rw.query, ds, engine=engine)
+            self.serve.fused_execute(
+                rw.query, ds, engine=engine, strategy=route["strategy"]
+            )
             if fusable else None
         )
         if fused is not None:
@@ -1232,7 +1242,7 @@ class TPUOlapContext:
             self._last_engine_metrics = m
         elif rw.grouping_sets and isinstance(rw.query, Q.GroupByQuery):
             df = execute_grouping_sets(
-                rw.query, rw.grouping_sets, ds, engine
+                rw.query, rw.grouping_sets, ds, engine, **route
             )
             self._last_engine_metrics = getattr(
                 engine, "last_metrics", None
@@ -1247,13 +1257,13 @@ class TPUOlapContext:
             # NEXT append refreshes this answer by scanning only the
             # delta (serve/result_cache.py)
             with engine.state_capture() as cap:
-                df = engine.execute(rw.query, ds)
+                df = engine.execute(rw.query, ds, **route)
             state = cap["state"]
             self._last_engine_metrics = getattr(
                 engine, "last_metrics", None
             )
         else:
-            df = engine.execute(rw.query, ds)
+            df = engine.execute(rw.query, ds, **route)
             self._last_engine_metrics = getattr(
                 engine, "last_metrics", None
             )
@@ -1328,7 +1338,9 @@ class TPUOlapContext:
         return df[cols].reset_index(drop=True)
 
     def _engine_for(self, rw: Rewrite):
-        phys = rw.physical
+        """The engine of the rewrite's backend.  Nothing of the request
+        is written on it (the breaker sync aside): engines are shared by
+        every request in flight."""
         # ONE routing decision, shared with breaker selection: branching
         # on _backend_for here is what keeps its "can never disagree"
         # docstring true — an edit to the mesh condition lands on both
@@ -1338,24 +1350,14 @@ class TPUOlapContext:
                 from .parallel.mesh import make_mesh
 
                 self._dist_engine = DistributedEngine(
-                    mesh=make_mesh(*phys.mesh_shape)
+                    mesh=make_mesh(*rw.physical.mesh_shape),
+                    config=self.config,
                 )
-            # route mesh kernels by the SESSION's cost constants, not
-            # a fresh file load — re-synced EVERY call (same as the
-            # local engine below) so a replaced ctx.config is honored
-            self._dist_engine._calibrated_cfg = self.config
             # the mesh path reports to ITS OWN breaker: a sick mesh
             # trips only itself, single-device queries stay routed
             self._sync_engine_resilience(self._dist_engine, "mesh")
             return self._dist_engine
-        # the engine's adaptive tier picks its compact-domain kernel from
-        # the session's cost constants, not a fresh file load
-        self.engine._calibrated_cfg = self.config
         self._sync_engine_resilience(self.engine)
-        if self.engine.strategy != phys.strategy:
-            self.engine.strategy = phys.strategy
-            # strategy participates in the engine's program cache key, so
-            # flipping it is safe (distinct cache entries)
         return self.engine
 
     # -- DataFrame-ish builder (the reference's "sourceDataframe" analog) ----
@@ -1382,13 +1384,17 @@ def _eval_host(e: E.Expr, df) -> np.ndarray:
     return np.asarray(fn(cols))
 
 
-def execute_grouping_sets(q: Q.GroupByQuery, grouping_sets, ds, engine):
+def execute_grouping_sets(
+    q: Q.GroupByQuery, grouping_sets, ds, engine, **route
+):
     """CUBE/ROLLUP/GROUPING SETS: one kernel pass per set, absent
     dimensions emitted as nulls, plus a __grouping_id bitmask (SQL
     GROUPING_ID semantics: bit i set => dim i aggregated away).
 
     Shared by the SQL path (rw.grouping_sets) and the serving path (a wire
-    groupBy's subtotalsSpec, server.py) — the two must not drift."""
+    groupBy's subtotalsSpec, server.py) — the two must not drift.  `route`
+    is the plan's `strategy=` / `cfg=` for the engine (none on the wire
+    path: the engine's own)."""
     import pandas as pd
 
     from .resilience import current_partial
@@ -1429,14 +1435,14 @@ def execute_grouping_sets(q: Q.GroupByQuery, grouping_sets, ds, engine):
     # overlaps them
     if hasattr(engine, "execute_groupby_batch"):
         results = engine.execute_groupby_batch(
-            subs, ds, set_labels=set_labels
+            subs, ds, set_labels=set_labels, **route
         )
     else:
         results = []
         for i, sub in enumerate(subs):
             if pc is not None and set_labels is not None:
                 pc.set_label = set_labels[i]
-            results.append(engine.execute(sub, ds))
+            results.append(engine.execute(sub, ds, **route))
     if pc is not None:
         pc.finish_sets()
     for s, f in zip(grouping_sets, results):

@@ -97,7 +97,6 @@ class SessionConfig:
     # `plan/calibrate.py` measures them on the live backend and
     # `SessionConfig.load_calibrated()` picks up the saved values; the
     # defaults below are v5e-flavoured estimates used until calibration runs.
-    cost_model_enabled: bool = True
     dense_max_groups: int = 1 << 17  # dense one-hot vs scatter cutover
     onehot_vmem_budget_mb: int = 32
     # device VMEM capacity class, MiB: the budget kernel tile sets must

@@ -62,6 +62,7 @@ from ..obs import (
     span,
     span_around,
 )
+from ..plan.cost import presence_kernels, shape_kernel
 from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
@@ -358,75 +359,22 @@ class AdaptiveDomainMixin:
     (qkey set).  Everything else reuses the engine's program machinery.
     """
 
-    def _adaptive_eligible(self, lowering: GroupByLowering) -> bool:
-        from ..ops.groupby import SCATTER_CUTOVER
-        from ..ops.pallas_groupby import pallas_available
-
-        # explicit kernel requests ('segment', 'sparse', 'dense', 'pallas')
-        # are honored as such (the ADVICE r1 rule the sparse tier follows):
-        # adaptive runs when the cost model chose it, or under 'auto' —
-        # where one probe pass is cheap insurance on any backend once G is
-        # past the scatter cutover, since the kept-set cache makes repeats
-        # a single compact-domain pass.
-        if self.strategy not in ("auto", "adaptive"):
-            return False
-        return (
-            lowering.num_groups > SCATTER_CUTOVER
-            and bool(lowering.dims)
-            # an unfiltered query keeps every present code; compaction can
-            # still win (populated << domain), so no filter requirement
-        )
-
     def _presence_columns(self, q, lowering: GroupByLowering, ds=None):
         return presence_columns(q, lowering, ds)
-
-    def _adaptive_main_strategy(self, ds: DataSource, g_compact: int) -> str:
-        from ..config import SessionConfig
-        from ..ops.groupby import SCATTER_CUTOVER
-        from ..ops.pallas_groupby import pallas_available
-        from ..plan.cost import choose_kernel_strategy
-
-        cfg = getattr(self, "_calibrated_cfg", None)
-        if cfg is None:
-            cfg = SessionConfig.load_calibrated()
-            self._calibrated_cfg = cfg
-        strat = choose_kernel_strategy(ds.num_rows, g_compact, cfg)
-        if (
-            strat == "dense"
-            and g_compact <= SCATTER_CUTOVER
-            and pallas_available()
-        ):
-            strat = "pallas"
-        return strat
 
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _presence_program(self, q, ds, lowering: GroupByLowering):
         """Fused per-segment program: presence COUNTS per grouping dim under
         the query's row mask — one data read covers every dim."""
         from ..ops.groupby import partial_aggregate
-        from ..ops.pallas_groupby import pallas_available
 
-        pallas_ok = pallas_available()
-        key = _query_key(q, ds) + ("adaptive-presence", pallas_ok)
+        strategies = presence_kernels(d.cardinality for d in lowering.dims)
+        key = _query_key(q, ds) + ("adaptive-presence", strategies)
         cached = self._query_fn_cache.get(key)
         if cached is not None:
             prof.note_program_cache("adaptive-presence", hit=True)
             return cached
         prof.note_program_cache("adaptive-presence", hit=False)
-
-        # same inner convention as the sparse tier: one-hot kernels on a
-        # TPU backend (within the one-hot domain cap), scatter everywhere
-        # else (a card-sized scatter state is cache-resident on CPU; the
-        # static auto-resolver would pick the dense one-hot there —
-        # measured 55 s for one SF10 presence pass vs ~0.5 s on scatter)
-        from ..ops.groupby import SCATTER_CUTOVER
-
-        strategies = [
-            "pallas"
-            if pallas_ok and d.cardinality <= SCATTER_CUTOVER
-            else "segment"
-            for d in lowering.dims
-        ]
 
         @jax.jit
         def seg_fn(cols_list):
@@ -558,12 +506,14 @@ class AdaptiveDomainMixin:
             return kept
 
     def _dispatch_groupby_adaptive(
-        self, q: Q.GroupByQuery, ds: DataSource, lowering: GroupByLowering
+        self, q: Q.GroupByQuery, ds: DataSource, lowering: GroupByLowering,
+        cfg,
     ):
         """Adaptive-compaction attempt.  Returns None when declining at
         dispatch time (no shrink to be had; caller falls through to the
         sparse/scatter paths in the same phase), else resolve() -> df.
-        A device error in either phase raises."""
+        A device error in either phase raises.  `cfg`: the cost
+        constants phase B's kernel is chosen by."""
         segs = self._segments_in_scope(q, ds)
         if not segs:
             return None
@@ -607,13 +557,12 @@ class AdaptiveDomainMixin:
         clow = compacted_lowering(lowering, kept)
         cards = tuple(d.cardinality for d in clow.dims)
         # the compact program's kernel comes from the CALIBRATED cost
-        # model at the compacted cardinality — the engine's static "auto"
-        # resolver picks the dense one-hot below the cutover, which on a
-        # CPU backend is the wrong side of a ~200x inversion (measured:
-        # a 60M-row phase B at G'=600 ran 49 s dense vs sub-second
-        # scatter; on TPU the same choice lands on Pallas/dense)
+        # model at the compacted cardinality, not the cutover rule: on a
+        # CPU the dense one-hot below it is the wrong side of a ~200x
+        # inversion (measured: a 60M-row phase B at G'=600 ran 49 s dense
+        # vs sub-second scatter; on TPU the same choice lands on Pallas)
         with span(SPAN_ROUTE, tier="adaptive"):
-            strat = self._adaptive_main_strategy(ds, clow.num_groups)
+            strat = shape_kernel(ds.num_rows, clow.num_groups, cfg)
         state = self._partials_for_query(
             q, ds, lowering=clow, key_extra=("adaptive",) + cards,
             strategy_override=strat, span_attrs={"phase": "B"},

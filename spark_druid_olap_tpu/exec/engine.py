@@ -34,6 +34,7 @@ from ..ops import quantiles as quantiles_ops
 from ..ops import theta as theta_ops
 from ..ops.filters import compile_filter
 from ..ops.groupby import partial_aggregate
+from ..plan.cost import concrete_kernel, tier_takes
 
 # Lowering + finalization were split out of this module (VERDICT r1 weak #8);
 # re-exported here because the distributed/streaming executors and external
@@ -379,24 +380,56 @@ def _default_device_budget() -> int:
     return max(4 << 30, int(pages * page) // 2)
 
 
+def groupby_family(q: Q.QuerySpec, ds: DataSource):
+    """Normalize a GroupBy-family query to its inner GroupBy plus the
+    per-type result shaper: the one mapping execute, execute_progressive,
+    execute_fused and the state-capture paths of both engines share.
+    (None, None) for any other query type."""
+    if isinstance(q, Q.TimeseriesQuery):
+        return (
+            timeseries_to_groupby(q),
+            lambda df: finalize_timeseries(df, q, ds),
+        )
+    if isinstance(q, Q.TopNQuery):
+        return topn_to_groupby(q), lambda df: finalize_topn(df, q)
+    if isinstance(q, Q.GroupByQuery):
+        return q, lambda df: df
+    return None, None
+
+
+def _tier_takes(tier: str, lowering, strategy: str) -> bool:
+    """`plan.cost.tier_takes` for a lowered query: may the adaptive /
+    sparse tier take it when it is routed `strategy`?"""
+    return tier_takes(
+        tier, strategy, lowering.num_groups, bool(lowering.dims),
+        bool(lowering.la.sketch_aggs),
+    )
+
+
 class Engine(AdaptiveDomainMixin, SparseExecMixin):
     """Executes query specs on the local device set.
 
-    `strategy` mirrors the reference's cost-model execution choice
-    (SURVEY.md §2 DruidQueryCostModel `[U]`): "auto" lets plan/cost.py pick
-    dense-one-hot vs scatter from the group cardinality."""
+    Which kernel runs is plan/cost.py's to say.  A planned query brings
+    its class and the session's cost constants with it, as `strategy=` /
+    `cfg=` of the call that executes it; the constructor's `strategy`
+    and `config` are what a call without a plan runs under (a directly
+    built engine: tests, streaming, the wire path).  Nothing writes
+    either after construction: concurrent requests share the engine."""
 
     def __init__(
         self,
         strategy: str = "auto",
         device_cache_bytes: Optional[int] = None,
         program_cache_entries: int = 256,
+        config=None,
     ):
+        from ..config import SessionConfig
         from ..utils.lru import ByteBudgetCache, CountBudgetCache
 
         if device_cache_bytes is None:
             device_cache_bytes = _default_device_budget()
         self.strategy = strategy
+        self.config = config or SessionConfig.load_calibrated()
         # observability (SURVEY.md §5): populated on every execution
         self.last_metrics = None
         # metrics object being filled during one execution — THREAD-LOCAL
@@ -708,15 +741,12 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
 
     # -- entry points --------------------------------------------------------
 
-    def execute(self, q: Q.QuerySpec, ds: DataSource):
-        import pandas as pd
-
-        if isinstance(q, Q.GroupByQuery):
-            return self._execute_groupby(q, ds)
-        if isinstance(q, Q.TimeseriesQuery):
-            return self._execute_timeseries(q, ds)
-        if isinstance(q, Q.TopNQuery):
-            return self._execute_topn(q, ds)
+    def execute(self, q: Q.QuerySpec, ds: DataSource, strategy=None, cfg=None):
+        """`strategy` / `cfg`: the plan's kernel class and the session's
+        cost constants (class docstring); None = the constructor's."""
+        inner, shape = groupby_family(q, ds)
+        if inner is not None:
+            return shape(self._execute_groupby(inner, ds, strategy, cfg))
         if isinstance(q, Q.ScanQuery):
             return self._execute_scan(q, ds)
         if isinstance(q, Q.SearchQuery):
@@ -818,7 +848,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             if not _arena.query_disabled():
                 plan = _arena.plan_for(self, batches, need)
         if plan is not None:
-            strategy = strategy_override or self._resolve_strategy(G)
+            strategy = strategy_override or concrete_kernel(self.strategy, G)
             run = self._pipeline.start(
                 ds, plan.remainder, need,
                 speculative=self._pipeline.speculative_candidates(
@@ -928,29 +958,6 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             prof.note_compile(m.compile_ms)
         return result
 
-    def _resolve_strategy(self, num_groups: int) -> str:
-        """Resolve 'auto' to a concrete kernel strategy (ops.groupby's shared
-        resolver).
-
-        "dense" from the cost model is a kernel *class* (one-hot vs scatter);
-        the Pallas kernel is its hand-scheduled implementation and is
-        preferred whenever a TPU backend is present."""
-        from ..ops.groupby import resolve_strategy
-        from ..ops.pallas_groupby import pallas_available
-
-        if self.strategy == "dense":
-            from ..ops.groupby import SCATTER_CUTOVER
-
-            if num_groups <= SCATTER_CUTOVER and pallas_available():
-                return "pallas"
-            return "dense"
-        if self.strategy in ("sparse", "adaptive"):
-            # execution-layer accelerators, not kernel strategies: when the
-            # sparse/adaptive path declines a query (low G, sketch aggs,
-            # overflow, no shrink) the standard path resolves as if "auto"
-            return resolve_strategy("auto", num_groups)
-        return resolve_strategy(self.strategy, num_groups)
-
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _segment_program(
         self,
@@ -966,7 +973,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         dispatch.  The analog of Druid compiling a query into one engine pass,
         with the broker's cross-segment merge folded in."""
         la, G = lowering.la, lowering.num_groups
-        strategy = strategy_override or self._resolve_strategy(G)
+        strategy = strategy_override or concrete_kernel(self.strategy, G)
         # _query_key includes schema_signature: a re-ingested datasource
         # (new dict cardinalities => new G) must not reuse a stale program.
         # The "fused" tag pins this key family apart from the tagged
@@ -1031,28 +1038,13 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
 
     # -- micro-batch fusion (serve/, ISSUE 8) --------------------------------
 
-    def _groupby_family(self, q: Q.QuerySpec, ds: DataSource):
-        """Normalize a GroupBy-family query to its inner GroupBy plus the
-        per-type result shaper — the one mapping execute_progressive,
-        execute_fused, and the state-capture paths all share."""
-        if isinstance(q, Q.TimeseriesQuery):
-            return (
-                timeseries_to_groupby(q),
-                lambda df: finalize_timeseries(df, q, ds),
-            )
-        if isinstance(q, Q.TopNQuery):
-            return topn_to_groupby(q), lambda df: finalize_topn(df, q)
-        if isinstance(q, Q.GroupByQuery):
-            return q, lambda df: df
-        return None, None
-
-    def fusable(self, q: Q.QuerySpec, ds: DataSource) -> bool:
+    def fusable(self, q: Q.QuerySpec, ds: DataSource, strategy=None) -> bool:
         """May this query join a fused micro-batch / the state-capturing
         dense path?  GroupBy-family only (mergeable partial state), no
         wire subtotals, and neither the sparse nor the adaptive
-        accelerator would engage (those tiers have their own dispatch
-        protocols a fused program cannot host)."""
-        inner, _ = self._groupby_family(q, ds)
+        accelerator would engage under `strategy` (those tiers have
+        their own dispatch protocols a fused program cannot host)."""
+        inner, _ = groupby_family(q, ds)
         if inner is None or inner.subtotals:
             return False
         try:
@@ -1061,12 +1053,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             )
         except Exception:  # fault-ok: an unlowerable query declines fusion
             return False
+        strategy = strategy or self.strategy
         return not (
-            self._sparse_eligible(lowering)
-            or self._adaptive_eligible(lowering)
+            _tier_takes("sparse", lowering, strategy)
+            or _tier_takes("adaptive", lowering, strategy)
         )
 
-    def execute_fused(self, queries, ds: DataSource, query_ids=None):
+    def execute_fused(
+        self, queries, ds: DataSource, query_ids=None, strategies=None
+    ):
         """Execute N compatible GroupBy-family queries as ONE fused device
         program per segment batch: the union of the members' in-scope
         segments moves host->device once (shared residency), every
@@ -1078,7 +1073,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         `df` is the finalized per-query result (identical to a serial
         `execute`), `state` the merged HOST partial state (the delta-aware
         result cache stores it), `metrics` the member's own QueryMetrics
-        (query_id stamped per member — serving-discipline GL1702)."""
+        (query_id stamped per member — serving-discipline GL1702).
+        `strategies`: each member's planned class (None = the
+        constructor's, for every member)."""
         import time as _time
 
         from .metrics import QueryMetrics
@@ -1089,7 +1086,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         query_ids = list(query_ids or [""] * n)
         members = []
         for q in queries:
-            inner, shape = self._groupby_family(q, ds)
+            inner, shape = groupby_family(q, ds)
             if inner is None:
                 raise ValueError(
                     f"{type(q).__name__} is not fusable (GroupBy-family "
@@ -1111,7 +1108,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             c for m in members for c in m[3].columns
         )
         strategies = tuple(
-            self._resolve_strategy(m[3].num_groups) for m in members
+            concrete_kernel(st or self.strategy, m[3].num_groups)
+            for st, m in zip(strategies or [None] * n, members)
         )
         batch_m = QueryMetrics(query_type="fused")  # h2d/compile accumulator
         self._m = batch_m
@@ -1424,7 +1422,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         full scope).  The delta-aware result cache calls this with the
         freshly-appended uids so a dashboard refresh after an append
         scans ONLY the delta.  Returns (state, rows_scanned)."""
-        inner, _ = self._groupby_family(q, ds)
+        inner, _ = groupby_family(q, ds)
         if inner is None:
             raise ValueError(f"{type(q).__name__} has no partial state")
         inner = groupby_with_time_granularity(inner)
@@ -1451,7 +1449,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 f"partial-state shape mismatch {a['sums'].shape} vs "
                 f"{b['sums'].shape} (dictionary domain changed)"
             )
-        inner, _ = self._groupby_family(q, ds)
+        inner, _ = groupby_family(q, ds)
         lowering = self._lowering_for(
             groupby_with_time_granularity(inner), ds
         )
@@ -1470,7 +1468,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
     def finalize_groupby_state(self, q: Q.QuerySpec, ds: DataSource, state):
         """Host partial state -> the query's final result frame (the same
         finalize the live execution path runs)."""
-        inner, shape = self._groupby_family(q, ds)
+        inner, shape = groupby_family(q, ds)
         inner = groupby_with_time_granularity(inner)
         lowering = self._lowering_for(inner, ds)
         with span(SPAN_FINALIZE):
@@ -1483,7 +1481,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             )
         return shape(df)
 
-    def _execute_groupby(self, q: Q.GroupByQuery, ds: DataSource):
+    def _execute_groupby(
+        self, q: Q.GroupByQuery, ds: DataSource, strategy=None, cfg=None
+    ):
         """GroupBy with idempotent re-dispatch on transient device failure
         — the analog of Spark retrying a DruidRDD partition (SURVEY.md §5
         failure-detection row: queries are read-only, so a retry is always
@@ -1500,7 +1500,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         q = groupby_with_time_granularity(q)
         return run_device_attempts(
             self,
-            lambda: self._execute_groupby_once(q, ds),
+            lambda: self._dispatch_groupby_once(q, ds, strategy, cfg)(),
             lambda: self._evict_query_state(q, ds),
         )
 
@@ -1518,10 +1518,10 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             self._device_cache.pop(k)
             self._note_resident_drop(k)
 
-    def _execute_groupby_once(self, q: Q.GroupByQuery, ds: DataSource):
-        return self._dispatch_groupby_once(q, ds)()
-
-    def execute_groupby_batch(self, queries, ds: DataSource, set_labels=None):
+    def execute_groupby_batch(
+        self, queries, ds: DataSource, set_labels=None, strategy=None,
+        cfg=None,
+    ):
         """Execute N GroupBy queries with overlapped device round trips:
         dispatch every query's program first (async), then resolve in
         order, so the fetch latency of query i hides the compute of i+1..N.
@@ -1542,7 +1542,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         for i, q in enumerate(queries):
             _label(i)
             try:
-                resolves.append(self._dispatch_groupby_once(q, ds))
+                resolves.append(
+                    self._dispatch_groupby_once(q, ds, strategy, cfg)
+                )
             except NotImplementedError:
                 raise
             except RuntimeError as err:
@@ -1559,7 +1561,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             _label(i)  # sparse/adaptive re-passes attribute to their set
             resolves[i] = None  # release the closure (and its device state)
             if resolve is None:
-                out.append(self._execute_groupby(q, ds))
+                out.append(self._execute_groupby(q, ds, strategy, cfg))
                 self.last_metrics.retries += 1  # the failed batch dispatch
                 continue
             try:
@@ -1575,16 +1577,20 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 self._evict_query_state(
                     groupby_with_time_granularity(q), ds
                 )
-                out.append(self._execute_groupby(q, ds))
+                out.append(self._execute_groupby(q, ds, strategy, cfg))
                 self.last_metrics.retries += 1  # the failed batch resolve
         return out
 
-    def _dispatch_groupby_once(self, q: Q.GroupByQuery, ds: DataSource):
+    def _dispatch_groupby_once(
+        self, q: Q.GroupByQuery, ds: DataSource, strategy=None, cfg=None
+    ):
         """Phase 1 of one GroupBy execution: build/launch the device
         programs (async dispatch, no fetch) and return `resolve() -> df`,
         which fetches, finalizes, and publishes metrics.  The synchronous
         path is `self._dispatch_groupby_once(q, ds)()`; batch callers
-        dispatch all queries before resolving any."""
+        dispatch all queries before resolving any.  `strategy` / `cfg`
+        are read once, here, and handed down: no tier looks at the
+        engine's own while the request runs."""
         import time as _time
 
         from .metrics import QueryMetrics
@@ -1602,15 +1608,16 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # aggs too, and repeats skip its presence pass via the kept-set
         # memo), then sparse, else the dense partials path at `kernel`
         with span(SPAN_ROUTE):
-            kernel = self._resolve_strategy(lowering.num_groups)
+            strategy = strategy or self.strategy
+            kernel = concrete_kernel(strategy, lowering.num_groups)
             try_adaptive = bool(
                 segs
-                and self._adaptive_eligible(lowering)
+                and _tier_takes("adaptive", lowering, strategy)
                 and qkey not in self._adaptive_declined
             )
             try_sparse = bool(
                 segs
-                and self._sparse_eligible(lowering)
+                and _tier_takes("sparse", lowering, strategy)
                 and qkey not in self._sparse_disabled
             )
         m = self._m = QueryMetrics(
@@ -1667,7 +1674,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             # dispatch time and the sparse/dense paths proceed
             if try_adaptive:
                 adaptive_resolve = self._dispatch_groupby_adaptive(
-                    q, ds, lowering
+                    q, ds, lowering, cfg or self.config
                 )
                 if adaptive_resolve is not None:
                     m.strategy = "adaptive"
@@ -1678,7 +1685,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 )
             elif adaptive_resolve is None:
                 dense_state = self._partials_for_query(
-                    q, ds, lowering=lowering
+                    q, ds, lowering=lowering, strategy_override=kernel
                 )
         except BaseException as err:
             from ..resilience import DeadlineExceeded
@@ -1731,7 +1738,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     # nothing dispatched
                     if reason == "overflow":
                         self._sparse_disabled.add(qkey)
-                    m.strategy = self._resolve_strategy(lowering.num_groups)
+                    m.strategy = kernel
                     log.warning(
                         "sparse path declined (%s); falling back to %s%s",
                         reason,
@@ -1741,7 +1748,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     # serial fallback dispatch (rare): sparse declined, so
                     # the dense program launches now
                     dense_state = self._partials_for_query(
-                        q, ds, lowering=lowering
+                        q, ds, lowering=lowering, strategy_override=kernel
                     )
                 t_fetch = _time.perf_counter()
                 dims, la, G, sums, mins, maxs, sketch_states = dense_state
@@ -1802,18 +1809,6 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 finish()
 
         return resolve
-
-    # -- timeseries: a groupby whose only dimension is the time bucket -------
-
-    def _execute_timeseries(self, q: Q.TimeseriesQuery, ds: DataSource):
-        df = self._execute_groupby(timeseries_to_groupby(q), ds)
-        return finalize_timeseries(df, q, ds)
-
-    # -- topn: single-dim groupby + rank (exact; Druid's is approximate) -----
-
-    def _execute_topn(self, q: Q.TopNQuery, ds: DataSource):
-        df = self._execute_groupby(topn_to_groupby(q), ds)
-        return finalize_topn(df, q)
 
     # -- scan / search -------------------------------------------------------
 
@@ -2096,7 +2091,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
 
     # -- progressive execution (chunked refinement, ISSUE 7 tentpole (b)) ----
 
-    def execute_progressive(self, q: Q.QuerySpec, ds: DataSource):
+    def execute_progressive(self, q: Q.QuerySpec, ds: DataSource, strategy=None):
         """Generator of progressively-refined results for one aggregate
         query: after each segment-batch dispatch the running partial
         state is fetched and finalized, yielding `(df, info)` where
@@ -2112,16 +2107,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         visibility, so this path is opt-in (`context.progressive` on the
         wire).  Non-aggregate query types have no mergeable state to
         refine: they execute normally and emit once."""
-        if isinstance(q, Q.TimeseriesQuery):
-            inner = timeseries_to_groupby(q)
-            shape = lambda df: finalize_timeseries(df, q, ds)  # noqa: E731
-        elif isinstance(q, Q.TopNQuery):
-            inner = topn_to_groupby(q)
-            shape = lambda df: finalize_topn(df, q)  # noqa: E731
-        elif isinstance(q, Q.GroupByQuery):
-            inner = q
-            shape = lambda df: df  # noqa: E731
-        else:
+        inner, shape = groupby_family(q, ds)
+        if inner is None:
             df = self.execute(q, ds)
             # no mergeable state to refine, but execute() can still have
             # drained to a deadline partial (e.g. the scan loop under an
@@ -2153,9 +2140,10 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         dims, la, G = lowering.dims, lowering.la, lowering.num_groups
         need = lowering.columns
         rows_total, delta_total = _row_counts(segs)
+        kernel = concrete_kernel(strategy or self.strategy, G)
         m = self._m = QueryMetrics(
             query_type="progressive",
-            strategy=self._resolve_strategy(G),
+            strategy=kernel,
             datasource=ds.name,
             query_id=current_query_id(),
             rows_scanned=rows_total,
@@ -2174,7 +2162,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         truncated = False
         try:
             if segs:
-                seg_fn = self._segment_program(inner, ds, lowering)
+                seg_fn = self._segment_program(
+                    inner, ds, lowering, strategy_override=kernel
+                )
                 batches = list(self._segment_batches(segs, need))
                 # prefetch-only (reorder=False): the refinement sequence
                 # is user-visible, so batches dispatch in canonical order

@@ -23,6 +23,7 @@ import numpy as np
 from ..catalog.segment import DataSource
 from ..models import query as Q
 from ..obs import SPAN_PROGRAM_LOOKUP, span_around
+from ..plan.cost import sparse_inner_kernel
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
 from .lowering import GroupByLowering, _query_key, memo_key
@@ -31,34 +32,6 @@ log = get_logger("exec.sparse")
 
 
 class SparseExecMixin:
-
-    def _sparse_eligible(self, lowering: "GroupByLowering") -> bool:
-        """Sparse applies when the scatter path would otherwise run: huge
-        combined domain, plain (non-sketch) aggregates, and real dimensions.
-        Sketch states are [G, registers] dense — compaction would have to
-        re-key them too; at high G those queries stay on scatter."""
-        from ..ops.groupby import SCATTER_CUTOVER
-
-        # explicit strategy='segment' is the raw-scatter escape hatch and is
-        # honored as such (ADVICE r1: the sparse accelerator must not hijack
-        # an explicitly requested kernel).  The cost model emits 'sparse'
-        # when compaction should run; 'auto'/'dense' only self-upgrade on a
-        # TPU backend — measured on CPU, raw scatter beats sort-compaction
-        # at every domain size, so auto-sparse there is a pure loss.
-        from ..ops.pallas_groupby import pallas_available
-
-        auto_upgrade = (
-            self.strategy in ("auto", "dense") and pallas_available()
-        )
-        return (
-            lowering.num_groups > SCATTER_CUTOVER
-            and not lowering.la.sketch_aggs
-            and bool(lowering.dims)
-            # 'adaptive' falls through here when per-dim marginals didn't
-            # shrink: jointly-sparse domains are exactly the sparse tier's
-            # case
-            and (auto_upgrade or self.strategy in ("sparse", "adaptive"))
-        )
 
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _sparse_program(
@@ -69,7 +42,6 @@ class SparseExecMixin:
         row_capacity: Optional[int] = None,
         slots: Optional[int] = None,
     ) -> Callable:
-        from ..ops.pallas_groupby import pallas_available
         from ..ops.sparse_groupby import (
             SPARSE_SLOTS,
             sparse_partial_aggregate,
@@ -77,12 +49,7 @@ class SparseExecMixin:
 
         la = lowering.la
         slots = slots or SPARSE_SLOTS
-        # inner kernel over the compacted slots: the Pallas one-hot on TPU;
-        # scatter on CPU backends (4096-slot one-hot matmuls starve a CPU,
-        # and at `slots` segments CPU scatter is cheap).  Past SPARSE_SLOTS
-        # a non-scatter inner routes to the segmented-reduce-over-ranks
-        # kernel inside sparse_partial_aggregate (the sort-agg tier).
-        inner = "pallas" if pallas_available() else "segment"
+        inner = sparse_inner_kernel()
         # structured key, NOT an f-string: interpolation collapses distinct
         # identities (None vs "None") (graftlint jit-cache/GL103)
         key = _query_key(q, ds) + ("sparse", inner, row_capacity, slots)

@@ -39,6 +39,7 @@ import numpy as np
 from ..catalog.segment import NULL_ID, ROW_PAD, DataSource
 from ..models import query as Q
 from ..obs import SPAN_PROGRAM_LOOKUP, span_around
+from ..plan.cost import concrete_kernel, shape_kernel
 from .engine import (
     Engine,
     _merge_sketch_states,
@@ -208,7 +209,7 @@ class StreamExecutor:
 
             nd = self.mesh.shape[DATA_AXIS]
             strat = self._stream_strategy(G, chunk_rows // nd)
-            dist = DistributedEngine(mesh=self.mesh)
+            dist = DistributedEngine(mesh=self.mesh, config=eng.config)
             col_keys = list(need) + ["__valid"]
             if ds.time_column and ds.time_column in need:
                 col_keys.append("__time")
@@ -288,34 +289,16 @@ class StreamExecutor:
             )
 
     def _stream_strategy(self, G: int, rows_per_dispatch: int) -> str:
-        """Per-dispatch kernel class.  An engine constructed with an
-        explicit strategy is honored through its own resolver (the local
-        and mesh paths agree); "auto" routes through the CALIBRATED model
-        at (rows_per_dispatch, G) — the shape each dispatch actually runs
-        (per-device shard rows on a mesh).  Streaming accumulates dense
-        [G, M] states across chunks, so only the dense-state classes
-        apply: dense/Pallas one-hot or segment scatter.  This is the
-        engine-level rule from the round-4 postmortems: every NEW
-        execution path routes through the calibrated constants, never the
-        static resolver (CPU and TPU invert dense-vs-scatter by ~200x)."""
+        """Per-dispatch kernel.  An engine constructed with an explicit
+        strategy is honored (the local and mesh paths agree); "auto"
+        routes through the CALIBRATED model at (rows_per_dispatch, G):
+        the shape each dispatch actually runs (per-device shard rows on
+        a mesh).  Streaming accumulates dense [G, M] states across
+        chunks, so only the dense-state kernels apply."""
         eng = self.engine
         if eng.strategy != "auto":
-            return eng._resolve_strategy(G)
-        from ..config import SessionConfig
-        from ..plan.cost import choose_kernel_strategy
-
-        cfg = getattr(eng, "_calibrated_cfg", None)
-        if cfg is None:
-            cfg = SessionConfig.load_calibrated()
-            eng._calibrated_cfg = cfg
-        strat = choose_kernel_strategy(rows_per_dispatch, G, cfg)
-        if strat == "dense":
-            from ..ops.groupby import SCATTER_CUTOVER
-            from ..ops.pallas_groupby import pallas_available
-
-            if G <= SCATTER_CUTOVER and pallas_available():
-                strat = "pallas"
-        return strat
+            return concrete_kernel(eng.strategy, G)
+        return shape_kernel(rows_per_dispatch, G, eng.config)
 
     @span_around(SPAN_PROGRAM_LOOKUP)
     def _fused_local_fn(self, q, ds, lowering, prep, strat=None):
@@ -328,7 +311,7 @@ class StreamExecutor:
         key = _query_key(q, ds) + (
             "stream-fused",
             prep,  # carries (time_col, chunk_rows) identity
-            strat or eng._resolve_strategy(lowering.num_groups),
+            strat or concrete_kernel(eng.strategy, lowering.num_groups),
         )
         from ..obs import prof
 

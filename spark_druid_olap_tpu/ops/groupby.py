@@ -217,8 +217,9 @@ def scatter_partial_aggregate(
 
 
 def resolve_strategy(strategy: str, num_groups: int) -> str:
-    """Single source of truth for 'auto' strategy resolution (shared by this
-    dispatcher and Engine's program-cache keying)."""
+    """What a bare `partial_aggregate(strategy="auto")` runs: the kernel
+    library's own default.  The engines do not call it: which kernel a
+    query runs is chosen in plan/cost.py and handed down."""
     if strategy != "auto":
         return strategy
     if num_groups > SCATTER_CUTOVER:

@@ -19,11 +19,12 @@ TP-analog): each device matches only its slice of [0, G), shrinking the
 one-hot block and sketch states by the axis size; no collective is needed on
 that axis — outputs stay group-sharded until the host gathers them.
 
-**Kernel ladder (VERDICT r4 #1).**  The per-shard kernel is routed by the
-same calibrated cost model as the single-device engine
-(`plan.cost.choose_query_kernel`) — the round-4 engine hard-coded the dense
-one-hot, which made every high-cardinality SSB query (9 of 13) inexecutable
-on the mesh.  The full ladder now runs SPMD:
+**Kernel ladder (VERDICT r4 #1).**  What runs per shard is `plan/cost.py`'s
+to say, as on one chip: a planned query brings its class with it
+(`execute(..., strategy=, cfg=)`) and `plan.cost.route_query` turns it into
+this mesh's kernel at the per-device group slice; the model runs again only
+for a query without a plan or after a decline memo.  The full ladder runs
+SPMD:
 
 * dense / Pallas one-hot  — small G (psum/pmin/pmax merge over ``data``)
 * segment scatter         — large G, dense [Gl, M] state, same collectives
@@ -69,11 +70,8 @@ from ..catalog.segment import ROW_PAD, DataSource
 from ..exec.engine import (
     GroupByLowering,
     finalize_groupby,
-    finalize_timeseries,
-    finalize_topn,
+    groupby_family,
     groupby_with_time_granularity,
-    timeseries_to_groupby,
-    topn_to_groupby,
 )
 from ..models import aggregations as A
 from ..models import query as Q
@@ -99,11 +97,16 @@ from ..obs import (
     span_event,
 )
 from ..ops.groupby import (
-    SCATTER_CUTOVER,
     choose_block_rows,
     dense_partial_aggregate,
     partial_aggregate,
     scatter_partial_aggregate,
+)
+from ..plan.cost import (
+    presence_kernels,
+    route_query,
+    shape_kernel,
+    sparse_inner_kernel,
 )
 from ..utils.log import get_logger
 from . import spmd_arena
@@ -156,7 +159,9 @@ class DistributedEngine:
         shard_cache_bytes: int = 4 << 30,
         program_cache_entries: int = 128,
         strategy: str = "auto",
+        config=None,
     ):
+        from ..config import SessionConfig
         from ..utils.lru import ByteBudgetCache, CountBudgetCache
 
         # multi-host runtime formation (parallel/multihost.py) rides the
@@ -176,9 +181,12 @@ class DistributedEngine:
         else:
             self.slice_mesh = None
             self.mesh = mesh if mesh is not None else make_mesh()
-        # "auto" routes by the calibrated cost model; an explicit kernel
-        # class is honored as such, same contract as
-        # exec.engine.Engine(strategy=...).  Validated here: an unknown
+        # `strategy` / `config`: what a call WITHOUT a plan runs under,
+        # same contract as exec.engine.Engine ("auto" routes by the
+        # calibrated cost model; an explicit kernel class is honored as
+        # such).  A planned query brings its own as arguments of
+        # execute(); nothing writes these after construction.  Validated
+        # here: an unknown
         # string would otherwise fall into the dense one-hot branch — at
         # high G that is a pathological compile, not an error message
         if strategy not in (
@@ -187,6 +195,7 @@ class DistributedEngine:
         ):
             raise ValueError(f"unknown groupby strategy {strategy!r}")
         self.strategy = strategy
+        self.config = config or SessionConfig.load_calibrated()
         self.last_metrics = None  # observability (exec/metrics.py)
         # row-shard cache: keyed by (ds, column, data-axis, full segment
         # signature) — durable across queries; LRU under a byte budget
@@ -198,8 +207,6 @@ class DistributedEngine:
         # (dictionary remaps, bucket tables) — one blocking H2D per constant
         # on every execution without it (same as exec/engine.py)
         self._lowering_cache = CountBudgetCache(program_cache_entries)
-        # calibrated cost model for kernel routing (loaded once)
-        self._calibrated_cfg = None
         # kernel-ladder memos, mirroring exec/engine.py Engine.__init__:
         # adaptive kept-code sets + decline memo, remembered sparse rungs,
         # and sparse declines (ladder exhausted -> route straight to
@@ -226,13 +233,6 @@ class DistributedEngine:
         # per-thread state-capture holder (delta-aware result cache):
         # mirrors exec.engine.Engine._m_local
         self._m_local = _threading.local()
-
-    def _cfg(self):
-        if self._calibrated_cfg is None:
-            from ..config import SessionConfig
-
-            self._calibrated_cfg = SessionConfig.load_calibrated()
-        return self._calibrated_cfg
 
     def _lowering_for(self, q: Q.GroupByQuery, ds: DataSource):
         from ..exec.lowering import cached_lowering
@@ -459,15 +459,6 @@ class DistributedEngine:
         self._spmd_cache[cache_key] = run
         return run
 
-    def _sparse_inner(self) -> str:
-        """Inner kernel over the compacted slots: Pallas one-hot on a TPU
-        backend, scatter elsewhere (same convention as exec/sparse_exec.py;
-        past SPARSE_SLOTS the segmented-reduce tier takes over inside
-        sparse_partial_aggregate regardless)."""
-        from ..ops.pallas_groupby import pallas_available
-
-        return "pallas" if pallas_available() else "segment"
-
     def _spmd_sparse_fn(
         self,
         lowering: GroupByLowering,
@@ -498,7 +489,7 @@ class DistributedEngine:
             sparse_partial_aggregate,
         )
 
-        inner = self._sparse_inner()
+        inner = sparse_inner_kernel()
         # structured key, NOT an f-string (graftlint jit-cache/GL103)
         cache_key = _query_key(lowering.query, ds) + (
             local_rows,
@@ -571,26 +562,16 @@ class DistributedEngine:
         aggregate (VERDICT r4 #1's prescription).  Output is replicated
         (cardinality-sized vectors, tiny)."""
         from ..exec.lowering import _query_key
-        from ..ops.pallas_groupby import pallas_available
 
-        pallas_ok = pallas_available()
+        strategies = presence_kernels(d.cardinality for d in lowering.dims)
         cache_key = _query_key(lowering.query, ds) + (
             local_rows,
             self._mesh_key(),
             "adaptive-presence",
-            pallas_ok,
+            strategies,
         )
         if cache_key in self._spmd_cache:
             return self._spmd_cache[cache_key]
-        # same platform convention as exec/adaptive_exec.py: one-hot
-        # kernels only on a TPU backend, scatter everywhere else (a
-        # cardinality-sized scatter state is cache-resident on CPU)
-        strategies = [
-            "pallas"
-            if pallas_ok and d.cardinality <= SCATTER_CUTOVER
-            else "segment"
-            for d in lowering.dims
-        ]
 
         def shard_fn(cols: Dict[str, jax.Array]):
             cols = lowering.add_virtual(dict(cols))
@@ -624,16 +605,13 @@ class DistributedEngine:
 
     # -- entry points --------------------------------------------------------
 
-    def execute(self, q: Q.QuerySpec, ds: DataSource):
+    def execute(self, q: Q.QuerySpec, ds: DataSource, strategy=None, cfg=None):
+        """`strategy` / `cfg`: the plan's kernel class and the session's
+        cost constants; None = the constructor's."""
         # Timeseries/TopN rewrites + finalization are shared with the local
         # engine (exec/engine.py) so distributed semantics cannot drift.
-        if isinstance(q, Q.TimeseriesQuery):
-            df = self.execute(timeseries_to_groupby(q), ds)
-            return finalize_timeseries(df, q, ds)
-        if isinstance(q, Q.TopNQuery):
-            df = self.execute(topn_to_groupby(q), ds)
-            return finalize_topn(df, q)
-        assert isinstance(q, Q.GroupByQuery), type(q)
+        q, shape = groupby_family(q, ds)
+        assert q is not None, "GroupBy-family queries only"
         # idempotent re-dispatch on transient device failure, mirroring
         # exec/engine.py: the SAME shared retry/backoff/breaker policy
         # (resilience.run_device_attempts), differing only in what a
@@ -654,40 +632,34 @@ class DistributedEngine:
             for k in [k for k in self._shard_cache if k[0] == ds.name]:
                 self._shard_cache.pop(k)
 
-        return run_device_attempts(
-            self, lambda: self._execute_groupby_once(q, ds), evict,
+        return shape(run_device_attempts(
+            self,
+            lambda: self._execute_groupby_once(q, ds, strategy, cfg),
+            evict,
             what="mesh device",
+        ))
+
+    def _route(self, q, ds, lowering, qkey, strategy=None, cfg=None) -> str:
+        """`plan.cost.route_query` for this mesh: the handed class (else
+        the constructor's) at the per-device group slice, with this
+        engine's decline memos."""
+        declined = tuple(
+            tier
+            for tier, memo in (
+                ("adaptive", self._adaptive_declined),
+                ("sparse", self._sparse_declined),
+            )
+            if qkey in memo
+        )
+        return route_query(
+            strategy or self.strategy, q, ds, lowering.num_groups,
+            self._groups_split(lowering.num_groups)[1],
+            cfg or self.config, declined,
         )
 
-    def _route_strategy(self, q, ds, lowering, qkey) -> str:
-        """Kernel-class choice for this query on the mesh — the identical
-        calibrated model the single-device engine routes by (plan/cost.py),
-        with this engine's decline memos applied."""
-        from ..plan.cost import choose_query_kernel
-
-        exclude: List[str] = []
-        if qkey in self._adaptive_declined:
-            exclude.append("adaptive")
-        if qkey in self._sparse_declined:
-            exclude.append("sparse")
-        if self.strategy != "auto" and self.strategy not in exclude:
-            return self.strategy
-        strat = choose_query_kernel(
-            q, ds, lowering.num_groups, self._cfg(), exclude=tuple(exclude)
-        )
-        if strat == "dense":
-            # the cost model's "dense" is a kernel CLASS; the Pallas kernel
-            # is its hand-scheduled TPU implementation (same upgrade rule as
-            # Engine._resolve_strategy, but per-device: the groups axis
-            # shrinks the one-hot domain to Gl)
-            from ..ops.pallas_groupby import pallas_available
-
-            _, Gl = self._groups_split(lowering.num_groups)
-            if Gl <= SCATTER_CUTOVER and pallas_available():
-                return "pallas"
-        return strat
-
-    def _execute_groupby_once(self, q: Q.GroupByQuery, ds: DataSource):
+    def _execute_groupby_once(
+        self, q: Q.GroupByQuery, ds: DataSource, strategy=None, cfg=None
+    ):
         from ..exec.lowering import memo_key
         from ..exec.metrics import QueryMetrics
 
@@ -712,7 +684,8 @@ class DistributedEngine:
         # same contract as the local engine) so continuous streamed ingest
         # neither forgets learned rungs nor leaks one memo entry per append
         qkey = memo_key(q, ds)
-        strategy = self._route_strategy(q, ds, lowering, qkey)
+        handed, cfg = strategy, cfg or self.config
+        strategy = self._route(q, ds, lowering, qkey, handed, cfg)
         m = QueryMetrics(
             query_type="groupBy",
             strategy=strategy,
@@ -737,9 +710,9 @@ class DistributedEngine:
         out = None
         try:
             if strategy == "adaptive":
-                out = self._execute_adaptive(q, ds, lowering, qkey, m)
+                out = self._execute_adaptive(q, ds, lowering, qkey, m, cfg)
                 if out is None:  # declined: re-route without adaptive
-                    strategy = self._route_strategy(q, ds, lowering, qkey)
+                    strategy = self._route(q, ds, lowering, qkey, handed, cfg)
                     m.strategy = strategy
             if out is None and strategy == "sparse":
                 out = self._execute_sparse(q, ds, lowering, qkey, m)
@@ -840,7 +813,7 @@ class DistributedEngine:
         m.est_collective_ms = (
             allreduce_factor(nd)
             * groupby_state_bytes(q, lowering.num_groups, None)
-            / self._cfg().collective_bytes_per_us
+            / self.config.collective_bytes_per_us
             / 1e3
         )
         t0 = _time.perf_counter()
@@ -1025,7 +998,7 @@ class DistributedEngine:
 
     # -- adaptive tier -------------------------------------------------------
 
-    def _execute_adaptive(self, q, ds, lowering, qkey, m):
+    def _execute_adaptive(self, q, ds, lowering, qkey, m, cfg):
         """Adaptive dictionary-domain compaction as a distributed phase A
         (presence counts psum-merged over the data axis) + the normal SPMD
         program over the compacted lowering (phase B).  Returns None when
@@ -1038,7 +1011,6 @@ class DistributedEngine:
             remap_form,
         )
         from ..exec.lowering import empty_partials
-        from ..plan.cost import choose_kernel_strategy
 
         # measured kept sets are only valid for the segment set they
         # scanned (a fresh delta may hold codes the scan never saw —
@@ -1101,15 +1073,12 @@ class DistributedEngine:
         clow = compacted_lowering(lowering, kept)
         cards = tuple(d.cardinality for d in clow.dims)
         # phase B kernel from the calibrated model at the COMPACTED
-        # cardinality (the r4 engine bug class: a static resolver's dense
-        # pick is a ~200x inversion on CPU backends)
-        strat = choose_kernel_strategy(ds.num_rows, clow.num_groups, self._cfg())
-        if strat == "dense":
-            from ..ops.pallas_groupby import pallas_available
-
-            _, Gl = self._groups_split(clow.num_groups)
-            if Gl <= SCATTER_CUTOVER and pallas_available():
-                strat = "pallas"
+        # cardinality and the shape a device runs: the function the
+        # one-chip phase B calls (exec/adaptive_exec.py)
+        strat = shape_kernel(
+            max(1, ds.num_rows // self._row_device_count()),
+            self._groups_split(clow.num_groups)[1], cfg,
+        )
         m.num_groups = clow.num_groups
         return self._execute_dense_state(
             q, ds, clow, m, strat, key_extra=("adaptive",) + cards,
@@ -1193,7 +1162,7 @@ class DistributedEngine:
             nd = self.slice_mesh.shape[DATA_AXIS]
         else:
             ns, nd = 1, self.mesh.shape[DATA_AXIS]
-        return choose_merge_tree(sbytes, ns, nd, self._cfg())
+        return choose_merge_tree(sbytes, ns, nd, self.config)
 
     def _place_arena(self, ds: DataSource, layout, names, m):
         """Place (or reuse) the permuted [B_pad, R] column stacks.
@@ -1271,7 +1240,7 @@ class DistributedEngine:
         set in residency-aware order so a following execute() pays zero
         h2d.  Returns False when the query/datasource is not
         arena-eligible (nothing to warm)."""
-        inner, _ = self._groupby_family(q, ds)
+        inner, _ = groupby_family(q, ds)
         if inner is None:
             return False
         inner = groupby_with_time_granularity(inner)
@@ -1509,20 +1478,6 @@ class DistributedEngine:
 
     # -- host partial-state surface (delta-aware result cache) ---------------
 
-    def _groupby_family(self, q: Q.QuerySpec, ds: DataSource):
-        """GroupBy-family normalization, shared shape with the local
-        engine (exec.engine.Engine._groupby_family)."""
-        if isinstance(q, Q.TimeseriesQuery):
-            return (
-                timeseries_to_groupby(q),
-                lambda df: finalize_timeseries(df, q, ds),
-            )
-        if isinstance(q, Q.TopNQuery):
-            return topn_to_groupby(q), lambda df: finalize_topn(df, q)
-        if isinstance(q, Q.GroupByQuery):
-            return q, lambda df: df
-        return None, None
-
     @contextlib.contextmanager
     def state_capture(self):
         """Capture the merged HOST partial state of the next execution on
@@ -1550,7 +1505,7 @@ class DistributedEngine:
         decline)."""
         from ..exec.lowering import empty_partials, memo_key
 
-        inner, _ = self._groupby_family(q, ds)
+        inner, _ = groupby_family(q, ds)
         if inner is None:
             raise ValueError(f"{type(q).__name__} has no partial state")
         inner = groupby_with_time_granularity(inner)
@@ -1560,9 +1515,7 @@ class DistributedEngine:
             raise ValueError(
                 "query/datasource is not SPMD-arena eligible on the mesh"
             )
-        strategy = self._route_strategy(
-            inner, ds, lowering, memo_key(inner, ds)
-        )
+        strategy = self._route(inner, ds, lowering, memo_key(inner, ds))
         if strategy in ("sparse", "adaptive"):
             raise ValueError(
                 f"{strategy} tier has no mergeable mesh partial state"
@@ -1604,7 +1557,7 @@ class DistributedEngine:
                 f"partial-state shape mismatch {a['sums'].shape} vs "
                 f"{b['sums'].shape} (dictionary domain changed)"
             )
-        inner, _ = self._groupby_family(q, ds)
+        inner, _ = groupby_family(q, ds)
         lowering = self._lowering_for(
             groupby_with_time_granularity(inner), ds
         )
@@ -1623,7 +1576,7 @@ class DistributedEngine:
     def finalize_groupby_state(self, q: Q.QuerySpec, ds: DataSource, state):
         """Host partial state -> the query's final result frame (the same
         finalize the live mesh execution runs)."""
-        inner, shape = self._groupby_family(q, ds)
+        inner, shape = groupby_family(q, ds)
         inner = groupby_with_time_granularity(inner)
         lowering = self._lowering_for(inner, ds)
         with span(SPAN_FINALIZE):
@@ -1638,12 +1591,12 @@ class DistributedEngine:
 
     # -- micro-batch fusion on the shared arena ------------------------------
 
-    def fusable(self, q: Q.QuerySpec, ds: DataSource) -> bool:
+    def fusable(self, q: Q.QuerySpec, ds: DataSource, strategy=None) -> bool:
         """May this query join a fused micro-batch on the mesh?  Same
         surface as the local engine's: GroupBy-family, no wire subtotals,
         and the unified arena program can host it (no sketches, no
-        sparse/adaptive tier, layout eligible)."""
-        inner, _ = self._groupby_family(q, ds)
+        sparse/adaptive tier under `strategy`, layout eligible)."""
+        inner, _ = groupby_family(q, ds)
         if inner is None or inner.subtotals:
             return False
         try:
@@ -1655,8 +1608,8 @@ class DistributedEngine:
             return False
         from ..exec.lowering import memo_key
 
-        strategy = self._route_strategy(
-            inner, ds, lowering, memo_key(inner, ds)
+        strategy = self._route(
+            inner, ds, lowering, memo_key(inner, ds), strategy
         )
         if strategy in ("sparse", "adaptive"):
             return False
@@ -1695,7 +1648,9 @@ class DistributedEngine:
         self._spmd_cache[cache_key] = run
         return run
 
-    def execute_fused(self, queries, ds: DataSource, query_ids=None):
+    def execute_fused(
+        self, queries, ds: DataSource, query_ids=None, strategies=None
+    ):
         """Execute N compatible GroupBy-family queries as ONE unified
         arena dispatch: members share the sharded arena via the
         membership scan input, every member's fold runs inside the same
@@ -1712,7 +1667,7 @@ class DistributedEngine:
         query_ids = list(query_ids or [""] * n)
         members = []
         for q in queries:
-            inner, shape = self._groupby_family(q, ds)
+            inner, shape = groupby_family(q, ds)
             if inner is None:
                 raise ValueError(
                     f"{type(q).__name__} is not fusable (GroupBy-family "
@@ -1723,16 +1678,17 @@ class DistributedEngine:
             segs = self._scope_for_metrics(inner, ds)
             members.append((q, inner, shape, lowering, segs))
         layout = self._arena_layout(ds)
+        handed = list(strategies or [None] * n)
         strategies = tuple(
-            self._route_strategy(mm[1], ds, mm[3], memo_key(mm[1], ds))
-            for mm in members
+            self._route(mm[1], ds, mm[3], memo_key(mm[1], ds), st)
+            for st, mm in zip(handed, members)
         )
         if (
             layout is None
             or any(mm[3].la.sketch_aggs for mm in members)
             or any(s in ("sparse", "adaptive") for s in strategies)
         ):
-            return self._execute_fused_serial(queries, ds, query_ids)
+            return self._execute_fused_serial(queries, ds, query_ids, handed)
         prof.note_fusion(n)
         checkpoint("engine.fused_loop")  # fused deadline contract
         fire("device_dispatch")
@@ -1835,14 +1791,14 @@ class DistributedEngine:
         self.last_metrics = out[-1][2] if out else None
         return out
 
-    def _execute_fused_serial(self, queries, ds, query_ids):
+    def _execute_fused_serial(self, queries, ds, query_ids, strategies):
         """Fallback for an arena-ineligible batch: serial per-member
         execution under state capture — the same (df, state, metrics)
         tuple contract, minus the shared dispatch."""
         out = []
-        for q, qid in zip(queries, query_ids):
+        for q, qid, st in zip(queries, query_ids, strategies):
             with self.state_capture() as cap:
-                df = self.execute(q, ds)
+                df = self.execute(q, ds, strategy=st)
             mm = self.last_metrics
             if mm is not None and qid:
                 mm.query_id = qid

@@ -295,12 +295,103 @@ def estimate_selectivity(filt, ds: DataSource) -> float:
 def choose_kernel_strategy(
     rows: int, num_groups: int, cfg: SessionConfig, sparse_ok: bool = False
 ) -> str:
-    """Min-cost kernel class for a (rows, groups) shape — the strategy an
-    Engine constructed outside the planner (streaming, direct execution)
-    should be pinned to when calibrated constants are available."""
+    """Min-cost kernel CLASS for a (rows, groups) shape."""
     return min(
         _kernel_costs(rows, num_groups, cfg, sparse_ok), key=lambda kv: kv[1]
     )[0]
+
+
+# -- which kernel runs: the one place that says ------------------------------
+#
+# The model above prices CLASSES ("dense" one-hot, "segment" scatter, and
+# the high-cardinality tiers "sparse" / "adaptive").  Everything below
+# turns a class into what an executor launches; the engines (exec/engine,
+# adaptive_exec, sparse_exec, streaming, parallel/distributed) call these
+# and keep no rule of their own.
+
+
+def _pallas_ok() -> bool:
+    """Is the compiled Pallas kernel there to route to (a TPU backend)?
+    The only routing call of `pallas_available()`."""
+    from ..ops.pallas_groupby import pallas_available
+
+    return pallas_available()
+
+
+def concrete_kernel(strategy: str, groups_per_device: int) -> str:
+    """Class -> the kernel of the dense-state path.  "dense" is a class:
+    the Pallas kernel is its hand-scheduled implementation and takes it
+    whenever a TPU is present and the domain fits the one-hot cap (the
+    mesh passes its per-device slice Gl, everybody else G).  "auto",
+    and a high-cardinality tier that declined, resolve by the cutover;
+    an explicit kernel ("pallas", "segment", "scatter") is honoured."""
+    from ..ops.groupby import SCATTER_CUTOVER
+
+    small = groups_per_device <= SCATTER_CUTOVER
+    if strategy in ("auto", "sparse", "adaptive"):
+        strategy = "dense" if small else "segment"
+    if strategy == "dense" and small and _pallas_ok():
+        return "pallas"
+    return strategy
+
+
+def shape_kernel(rows: int, groups_per_device: int, cfg: SessionConfig) -> str:
+    """The kernel for a bare (rows, groups per device) shape, by the
+    calibrated model: what a stream's dispatch and the adaptive tier's
+    phase B (one chip and mesh alike) launch."""
+    return concrete_kernel(
+        choose_kernel_strategy(rows, groups_per_device, cfg),
+        groups_per_device,
+    )
+
+
+def presence_kernels(cardinalities) -> Tuple[str, ...]:
+    """Per-dimension kernels of the adaptive probe (phase A): one-hot
+    kernels on a TPU within the one-hot cap, scatter everywhere else (a
+    cardinality-sized scatter state is cache-resident on a CPU, where
+    the dense one-hot took 55 s for one SF10 presence pass)."""
+    from ..ops.groupby import SCATTER_CUTOVER
+
+    pallas = _pallas_ok()
+    return tuple(
+        "pallas" if pallas and c <= SCATTER_CUTOVER else "segment"
+        for c in cardinalities
+    )
+
+
+def sparse_inner_kernel() -> str:
+    """The sparse tier's kernel over its compacted slots: the Pallas
+    one-hot on a TPU, scatter elsewhere (4096-slot one-hot matmuls
+    starve a CPU).  Past SPARSE_SLOTS a non-scatter inner routes to the
+    segmented-reduce tier inside `sparse_partial_aggregate`."""
+    return "pallas" if _pallas_ok() else "segment"
+
+
+def tier_takes(
+    tier: str, strategy: str, num_groups: int, has_dims: bool,
+    has_sketch: bool,
+) -> bool:
+    """May the high-cardinality `tier` ("adaptive" | "sparse") take a
+    query routed `strategy`?  Both need a domain past the scatter
+    cutover and real dimensions; sparse also needs plain aggregates (a
+    sketch state is [G, registers] dense and would have to be re-keyed;
+    adaptive re-keys them through its rewritten lowering).  An explicit
+    kernel is honoured as such: adaptive runs when the model chose it or
+    under "auto"; sparse when the model chose it, as adaptive's fallback
+    (marginals that did not shrink are the jointly-sparse case), and on
+    a TPU in place of a dense-state scatter ("auto" / "dense": on a CPU
+    raw scatter beats sort-compaction at every size).  Passing the
+    tier's own name asks whether it is eligible at all."""
+    from ..ops.groupby import SCATTER_CUTOVER
+
+    if num_groups <= SCATTER_CUTOVER or not has_dims:
+        return False
+    if tier == "adaptive":
+        return strategy in ("auto", "adaptive")
+    return not has_sketch and (
+        strategy in ("sparse", "adaptive")
+        or (strategy in ("auto", "dense") and _pallas_ok())
+    )
 
 
 def query_kernel_costs(
@@ -311,14 +402,9 @@ def query_kernel_costs(
     selectivity: Optional[float] = None,
 ) -> dict:
     """strategy -> modelled microseconds for a PLANNED query over `ds`: the
-    kernel half of `choose_physical`, factored out so the distributed and
-    streaming engines route by the identical calibrated model (VERDICT r4
-    #1: the mesh path hard-coding dense was the round-4 headline gap).
-    Eligibility mirrors the single-device engine: sparse requires real dims
-    and no sketch state to re-key; adaptive re-keys sketches transparently
-    so only dims are required."""
+    kernel half of `choose_physical`.  Tier eligibility is `tier_takes`,
+    the predicate the engines ask."""
     from ..models import aggregations as A
-    from ..ops.groupby import SCATTER_CUTOVER
 
     rows = ds.num_rows
     aggs = getattr(q, "aggregations", ())
@@ -330,10 +416,10 @@ def query_kernel_costs(
         for a in aggs
     )
     dims = getattr(q, "dimensions", ())
-    sparse_ok = (
-        num_groups > SCATTER_CUTOVER and not has_sketch and bool(dims)
+    sparse_ok, adaptive_ok = (
+        tier_takes(t, t, num_groups, bool(dims), has_sketch)
+        for t in ("sparse", "adaptive")
     )
-    adaptive_ok = num_groups > SCATTER_CUTOVER and bool(dims)
     segs = getattr(ds, "segments", None)
     n_segments = (
         len(segs) if segs is not None else max(1, rows // (1 << 22))
@@ -362,22 +448,39 @@ def choose_query_kernel(
     exclude: Tuple[str, ...] = (),
     costs: Optional[dict] = None,
 ) -> str:
-    """Min-cost kernel class for a planned query — `choose_physical`'s
-    strategy choice as a standalone (used by parallel/distributed.py and
-    exec/streaming.py).  `exclude` masks classes the caller cannot or will
-    not run (e.g. "adaptive" after a decline memo); `costs` accepts a
-    precomputed query_kernel_costs dict so choose_physical does not pay
-    the selectivity walk twice."""
+    """Min-cost kernel class for a planned query: `choose_physical`'s
+    strategy choice.  `exclude` masks classes the caller will not run
+    (a decline memo); `costs` accepts a precomputed query_kernel_costs
+    dict so choose_physical does not pay the selectivity walk twice."""
     if costs is None:
         costs = query_kernel_costs(q, ds, num_groups, cfg)
-    costs = {k: v for k, v in costs.items() if k not in exclude}
-    if not cfg.cost_model_enabled:
-        if num_groups <= cfg.dense_max_groups and "dense" not in exclude:
-            return "dense"
-        if costs.get("sparse", float("inf")) != float("inf"):
-            return "sparse"
-        return "segment"
-    return min(costs.items(), key=lambda kv: kv[1])[0]
+    return min(
+        (kv for kv in costs.items() if kv[0] not in exclude),
+        key=lambda kv: kv[1],
+    )[0]
+
+
+def route_query(
+    strategy: str,
+    q: Q.QuerySpec,
+    ds: DataSource,
+    num_groups: int,
+    groups_per_device: int,
+    cfg: SessionConfig,
+    declined: Tuple[str, ...] = (),
+) -> str:
+    """What the mesh runs for a query handed the class `strategy`: the
+    tier itself ("adaptive" / "sparse") or the dense-state kernel.  The
+    planner's answer is taken as handed; the model runs again only where
+    it must: no class was chosen ("auto": an engine built without a
+    plan), or a decline memo excludes the one that was."""
+    if strategy == "auto" or strategy in declined:
+        strategy = choose_query_kernel(
+            q, ds, num_groups, cfg, exclude=declined
+        )
+    if strategy in ("sparse", "adaptive"):
+        return strategy
+    return concrete_kernel(strategy, groups_per_device)
 
 
 def choose_physical(
@@ -461,9 +564,7 @@ def choose_physical(
             factor * state_bytes / max(cfg.collective_bytes_per_us, 1e-9)
         )
         dist_cost = compute + collective + cfg.cost_dispatch_us
-        distributed = cfg.prefer_distributed and (
-            not cfg.cost_model_enabled or dist_cost < local_cost
-        )
+        distributed = cfg.prefer_distributed and dist_cost < local_cost
         if distributed:
             mesh_shape = (nd, ng)
     return PhysicalPlan(

@@ -309,14 +309,19 @@ class ServingCore:
 
     # -- fusion --------------------------------------------------------------
 
-    def fused_execute(self, q, ds, engine=None) -> Optional[tuple]:
+    def fused_execute(
+        self, q, ds, engine=None, strategy=None
+    ) -> Optional[tuple]:
         """Micro-batch fusion entry: (df, state, metrics) or None.
         `engine` selects the executing backend (None = the context's
         local engine; the mesh's DistributedEngine batches through its
-        unified SPMD arena) — backends never share a batch."""
+        unified SPMD arena) — backends never share a batch.  `strategy`
+        is the member's planned kernel class (None = the engine's)."""
         if not self.fusion.enabled:
             return None
-        return self.fusion.execute(self.ctx, q, ds, engine=engine)
+        return self.fusion.execute(
+            self.ctx, q, ds, engine=engine, strategy=strategy
+        )
 
     # -- lanes ---------------------------------------------------------------
 

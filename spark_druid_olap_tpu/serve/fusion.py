@@ -112,11 +112,14 @@ _RETRY = "retry"  # re-execute individually on the member's own thread
 
 
 class _Member:
-    __slots__ = ("query", "query_id", "event", "verdict", "payload")
+    __slots__ = (
+        "query", "query_id", "strategy", "event", "verdict", "payload",
+    )
 
-    def __init__(self, query, query_id: str):
+    def __init__(self, query, query_id: str, strategy=None):
         self.query = query
         self.query_id = query_id
+        self.strategy = strategy  # the member's planned kernel class
         self.event = threading.Event()
         self.verdict: Optional[str] = None
         self.payload = None
@@ -209,7 +212,7 @@ class FusionScheduler:
         with self._lock:
             self._arrivals.append(now)
 
-    def execute(self, ctx, q, ds, engine=None):
+    def execute(self, ctx, q, ds, engine=None, strategy=None):
         """Join (or lead) the micro-batch for `q` over the `ds`
         snapshot.  Returns (df, state, metrics) or None (serial path).
         `engine` is the executing backend (None = ctx.engine); distinct
@@ -227,7 +230,7 @@ class FusionScheduler:
         window_ms, mode, n_recent = self._decide_window_ms(now)
         self._note_arrival(now)
         sig = (ds.name, backend, schema_signature(ds))
-        me = _Member(q, current_query_id())
+        me = _Member(q, current_query_id(), strategy)
         with self._lock:
             batch = self._open.get(sig)
             if (
@@ -353,6 +356,7 @@ class FusionScheduler:
                     [m.query for m in members],
                     current,
                     query_ids=[m.query_id for m in members],
+                    strategies=[m.strategy for m in members],
                 )
             with self._lock:
                 self.batches_fused += 1

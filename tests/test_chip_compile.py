@@ -232,16 +232,18 @@ def pallas_on(monkeypatch):
     monkeypatch.setattr(pallas_groupby, "pallas_available", lambda: True)
 
 
-# q1_1: G=1; q4_1: G=208 — the dense class the engine upgrades to the
-# kernel on a TPU (Engine._resolve_strategy)
+# q1_1: G=1; q4_1: G=208 — the dense class the chooser turns into the
+# kernel on a TPU (plan.cost.concrete_kernel)
 @pytest.mark.parametrize("name,G", [("q1_1", 1), ("q4_1", 208)])
 def test_engine_segment_program_compiles(one_chip, ssb_ctx, pallas_on, name, G):
     from spark_druid_olap_tpu.exec.engine import Engine
 
     q, ds, lowering = _lowered_query(ssb_ctx, name)
     assert lowering.num_groups == G
+    from spark_druid_olap_tpu.plan.cost import concrete_kernel
+
+    assert concrete_kernel("dense", G) == "pallas"
     eng = Engine(strategy="pallas")
-    assert eng._resolve_strategy(G) == "pallas"
     seg_fn = eng._segment_program(q, ds, lowering)
     cols = _segment_col_specs(
         ssb_ctx, ds, lowering.columns, (R_SEGMENT,), one_chip

@@ -10,6 +10,8 @@ overhead.
 """
 
 import dataclasses
+import json
+import os
 
 import numpy as np
 import pytest
@@ -457,3 +459,257 @@ def test_platform_sidecar_fallback(tmp_path):
     cfg = SessionConfig.load_calibrated(root=str(tmp_path))
     assert cfg.cost_per_row_dense != 9.9
     assert cfg.calibration_meta["mismatch"] is True
+
+
+# ---------------------------------------------------------------------------
+# The chooser (plan/cost.py): one table of the shapes the ledger's cells run.
+# Every row was first read off the PARENT's eight copies (PR 29: the engine's
+# `_resolve_strategy`, `_adaptive_main_strategy`, the mesh's `_route_strategy`
+# and phase B, `_stream_strategy`), so it records the routing as it was, not a
+# reading of it.  G' are the nine adaptive queries' own `compact_groups`.
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_ROWS_1CHIP = 60_000_000  # SSB SF10 lineorder; a (4, 1) mesh holds 15 M a device
+_G_Q4_3 = 2_010_008  # (250 + 1) cities x (1000 + 1) brands x (7 + 1) years
+
+
+def _file_cfg(platform):
+    """The constants `calibration.<platform>.json` gives a session on its
+    own device (load_calibrated applies them over the platform profile;
+    spelled out here because this process is not that device)."""
+    with open(os.path.join(_ROOT, f"calibration.{platform}.json")) as f:
+        data = json.load(f)
+    cfg = SessionConfig()
+    if platform == "cpu":
+        cfg = _cpu_profile_cfg()
+    for k, v in data.items():
+        if k.startswith(("cost_", "scatter_")) or k == "collective_bytes_per_us":
+            setattr(cfg, k, type(getattr(cfg, k))(v))
+    return cfg
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Routing as on a TPU: the compiled kernel is there to route to."""
+    from spark_druid_olap_tpu.plan import cost
+
+    monkeypatch.setattr(cost, "_pallas_ok", lambda: True)
+
+
+# (id, calibration file, rows a device, groups a device, kernel)
+_SHAPES = [
+    ("q1_x", "tpu", _ROWS_1CHIP, 1, "pallas"),
+    ("q4_1", "tpu", _ROWS_1CHIP, 208, "pallas"),
+    *[
+        (f"phaseB-{q}", "tpu", _ROWS_1CHIP, g, kernel)
+        for q, g, kernel in [
+            ("q2_1", 280, "pallas"), ("q2_2", 273, "pallas"),
+            ("q2_3", 7, "pallas"), ("q3_1", 150, "pallas"),
+            ("q3_2", 600, "pallas"), ("q3_3", 24, "pallas"),
+            ("q3_4", 1, "pallas"), ("q4_2", 100, "pallas"),
+            ("q4_3", 800, "segment"),  # stays the scatter (ROADMAP S2a)
+        ]
+    ],
+    ("mesh-q1_x", "tpu", _ROWS_1CHIP // 4, 1, "pallas"),
+    ("mesh-q4_1", "tpu", _ROWS_1CHIP // 4, 208, "pallas"),
+    ("mesh-phaseB-q3_1", "tpu", _ROWS_1CHIP // 4, 150, "pallas"),
+    ("mesh-phaseB-q2_1", "tpu", _ROWS_1CHIP // 4, 280, "pallas"),
+    ("mesh-phaseB-q3_2", "tpu", _ROWS_1CHIP // 4, 600, "pallas"),
+    ("mesh-phaseB-q4_3", "tpu", _ROWS_1CHIP // 4, 800, "segment"),
+    # the same q4_1 shape under the CPU's constants: the class flips
+    ("cpu-q4_1", "cpu", _ROWS_1CHIP, 208, "segment"),
+]
+
+
+@pytest.mark.parametrize(
+    "calib,rows,groups,kernel",
+    [s[1:] for s in _SHAPES], ids=[s[0] for s in _SHAPES],
+)
+def test_chooser_table(on_tpu, calib, rows, groups, kernel):
+    from spark_druid_olap_tpu.exec.engine import Engine
+    from spark_druid_olap_tpu.exec.streaming import StreamExecutor
+    from spark_druid_olap_tpu.plan.cost import (
+        choose_kernel_strategy,
+        concrete_kernel,
+        shape_kernel,
+    )
+
+    cfg = _file_cfg(calib)
+    cls = choose_kernel_strategy(rows, groups, cfg)
+    assert cls in ("dense", "segment")
+    assert shape_kernel(rows, groups, cfg) == kernel
+    # the planner hands the class, an engine turns it into the kernel
+    assert concrete_kernel(cls, groups) == kernel
+    # a stream's dispatch of that shape takes the same kernel
+    stream = StreamExecutor(engine=Engine(config=cfg))
+    assert stream._stream_strategy(groups, rows) == kernel
+
+
+def _q4_3_like():
+    """Dimensions, aggregates and selectivity of SSB q4_3 for the model."""
+    q = GroupByQuery(
+        datasource="t",
+        dimensions=tuple(DimensionSpec(d) for d in ("y", "c", "b")),
+        aggregations=(DoubleSum("s", "v"),),
+    )
+    ds = _FakeDS(_ROWS_1CHIP)
+    ds.segments = [None] * 115
+    # c_region, s_nation, d_year IN (2 of 7), p_category
+    return q, ds, (1 / 5) * (1 / 25) * (2 / 7) * (1 / 25)
+
+
+def test_chooser_high_cardinality_class(on_tpu):
+    """G = 2.0 M (q4_3): the model's class is the adaptive tier, on one chip
+    and on the mesh; the mesh runs the model again only when no class was
+    handed or a decline memo excludes the one that was."""
+    from spark_druid_olap_tpu.plan import cost
+
+    cfg = _file_cfg("tpu")
+    q, ds, sel = _q4_3_like()
+    costs = cost.query_kernel_costs(q, ds, _G_Q4_3, cfg, selectivity=sel)
+    assert cost.choose_query_kernel(q, ds, _G_Q4_3, cfg, costs=costs) == "adaptive"
+    assert cost.tier_takes("adaptive", "adaptive", _G_Q4_3, True, False)
+    assert not cost.tier_takes("adaptive", "dense", _G_Q4_3, True, False)
+    assert not cost.tier_takes("adaptive", "adaptive", 208, True, False)
+    # handed: taken as handed, the model is not asked
+    asked = []
+    orig = cost.choose_query_kernel
+
+    def spy(*a, **k):
+        asked.append(k.get("exclude"))
+        return orig(*a, **k)
+
+    cost.choose_query_kernel, keep = spy, orig
+    try:
+        route = lambda st, declined=(): cost.route_query(  # noqa: E731
+            st, q, ds, _G_Q4_3, _G_Q4_3, cfg, declined
+        )
+        assert route("adaptive") == "adaptive" and not asked
+        assert route("dense") == "dense" and not asked  # past the one-hot cap
+        assert cost.route_query("dense", q, ds, 208, 208, cfg) == "pallas"
+        assert not asked
+        # declined, or an engine built without a plan: the model runs
+        assert route("adaptive", ("adaptive",)) in ("sparse", "segment")
+        assert asked == [("adaptive",)]
+        route("auto")
+        assert asked == [("adaptive",), ()]
+    finally:
+        cost.choose_query_kernel = keep
+
+
+def _wide_ds(name, n=16_384, card=80):
+    from spark_druid_olap_tpu.catalog.segment import (
+        DimensionDict,
+        build_datasource,
+    )
+
+    rng = np.random.default_rng(11)
+    cols = {
+        "a": rng.integers(0, card, size=n),
+        "b": rng.integers(0, card, size=n),
+        "v": (rng.random(n) * 100).astype(np.float32),
+    }
+    return build_datasource(
+        name, cols, dimension_cols=["a", "b"], metric_cols=["v"],
+        rows_per_segment=n // 4,
+        dicts={
+            d: DimensionDict(values=tuple(range(card))) for d in ("a", "b")
+        },
+    )
+
+
+def _phase_b_kernel(cache, family_tags):
+    """The kernel component of the adaptive tier's phase-B program key:
+    the element after the family tag (`("fused", strategy)`, `("arena",
+    strategy)`, the mesh's `"dense-state", strategy`)."""
+    found = {
+        k[k.index(t) + 1]
+        for k in cache
+        if "adaptive" in k
+        for t in family_tags
+        if t in k
+    }
+    assert len(found) == 1, found
+    return found.pop()
+
+
+@pytest.mark.parametrize("kept_b,kernel", [(30, "dense"), (40, "segment")])
+def test_engines_agree_with_the_chooser(kept_b, kernel):
+    """One shape, three executors, one kernel: the single-device engine and
+    the mesh, handed the same class and constants, launch phase B (G' = 20 x
+    30 = 600 and 20 x 40 = 800, the two sides of the TPU constants' crossover)
+    with the kernel the chooser names for the shape each device runs, and
+    report the same tier.  The kernel library runs for real (no Pallas on
+    this backend: the dense class stays the XLA one-hot)."""
+    import jax
+
+    from spark_druid_olap_tpu.exec.engine import Engine
+    from spark_druid_olap_tpu.models.filters import And, InFilter
+    from spark_druid_olap_tpu.parallel.distributed import DistributedEngine
+    from spark_druid_olap_tpu.parallel.mesh import make_mesh
+    from spark_druid_olap_tpu.plan.cost import shape_kernel
+
+    assert len(jax.devices()) >= 4, "conftest must provide CPU devices"
+    cfg = _file_cfg("tpu")
+    ds = _wide_ds(f"agree{kept_b}")
+    q = GroupByQuery(
+        datasource=ds.name,
+        dimensions=(DimensionSpec("a"), DimensionSpec("b")),
+        aggregations=(DoubleSum("s", "v"), Count("n")),
+        filter=And((
+            InFilter("a", tuple(range(20))),
+            InFilter("b", tuple(range(kept_b))),
+        )),
+    )
+    g_compact = 20 * kept_b
+    assert shape_kernel(ds.num_rows, g_compact, cfg) == kernel
+    assert shape_kernel(ds.num_rows // 4, g_compact, cfg) == kernel
+
+    eng = Engine(config=SessionConfig())  # its own constants are not asked
+    want = eng.execute(q, ds, strategy="adaptive", cfg=cfg)
+    assert eng.last_metrics.strategy == "adaptive"
+    assert _phase_b_kernel(eng._query_fn_cache, ("fused", "arena")) == kernel
+
+    dist = DistributedEngine(mesh=make_mesh(n_data=4), config=SessionConfig())
+    got = dist.execute(q, ds, strategy="adaptive", cfg=cfg)
+    assert dist.last_metrics.strategy == "adaptive"
+    assert _phase_b_kernel(dist._spmd_cache, ("dense-state",)) == kernel
+
+    key = ["a", "b"]
+    got = got.sort_values(key).reset_index(drop=True)
+    want = want.sort_values(key).reset_index(drop=True)
+    np.testing.assert_array_equal(got["n"], want["n"])
+    np.testing.assert_allclose(got["s"], want["s"], rtol=2e-5)
+    # nothing of the request stayed on either engine
+    assert (eng.strategy, dist.strategy) == ("auto", "auto")
+
+
+def test_plan_travels_with_the_query_not_on_the_engine():
+    """Rule 2 (PR 29): two SQL queries whose physical plans differ in
+    `strategy`, through ONE context.  Each runs under its own plan's class,
+    and the shared engine is what it was at construction after both: no
+    request leaves its decision where the next (or a concurrent) one would
+    read it.  At the parent `api._engine_for` wrote `engine.strategy`."""
+    cfg = SessionConfig.load_calibrated()
+    cfg.result_cache_entries = 0
+    cfg.prefer_distributed = False  # the single-device engine, as the one-chip cells
+    ctx = sd.TPUOlapContext(cfg)
+    ctx.register_datasource(_wide_ds("travel", card=300))
+    eng = ctx.engine
+    assert eng.strategy == "auto"
+    low = "SELECT sum(v) AS s FROM travel"
+    high = (
+        "SELECT a, b, sum(v) AS s FROM travel "
+        "WHERE a IN (1, 2, 3) AND b IN (4, 5, 6) GROUP BY a, b"
+    )
+    plans = {
+        sql: ctx._plan_cached(sql)[0].physical.strategy for sql in (low, high)
+    }
+    assert plans[high] == "adaptive" and plans[low] != "adaptive", plans
+    for sql in (high, low, high):
+        ctx.sql(sql)
+        # its own plan's class (on a TPU the dense class runs as the kernel)
+        assert ctx.last_metrics.strategy in (plans[sql], "pallas")
+        assert eng.strategy == "auto" and eng.config is cfg
+    assert ctx.engine is eng and not hasattr(eng, "_calibrated_cfg")

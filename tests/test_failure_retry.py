@@ -15,6 +15,7 @@ from spark_druid_olap_tpu.exec.lowering import groupby_with_time_granularity
 from spark_druid_olap_tpu.models.aggregations import Count, DoubleSum
 from spark_druid_olap_tpu.models.dimensions import DimensionSpec
 from spark_druid_olap_tpu.models.query import GroupByQuery
+from spark_druid_olap_tpu.plan.cost import concrete_kernel
 
 
 @pytest.fixture(scope="module")
@@ -57,7 +58,7 @@ def test_transient_failure_retries_once(ds):
     eng = Engine()
     q = groupby_with_time_granularity(_q())
     lowering = eng._lowering_for(q, ds)
-    strategy = eng._resolve_strategy(lowering.num_groups)
+    strategy = concrete_kernel(eng.strategy, lowering.num_groups)
     calls = {"n": 0}
 
     def poisoned(cols_list):
@@ -104,7 +105,7 @@ def test_retry_evicts_transformed_query_identity(ds):
     qt = groupby_with_time_granularity(raw)
     assert qt is not raw  # the transform actually rewrote it
     lowering = eng._lowering_for(qt, tds)
-    strategy = eng._resolve_strategy(lowering.num_groups)
+    strategy = concrete_kernel(eng.strategy, lowering.num_groups)
 
     def poisoned(cols_list):
         raise RuntimeError("injected transient device failure")
@@ -132,13 +133,12 @@ def test_persistent_failure_surfaces(ds):
 def test_static_errors_do_not_retry(ds):
     eng = Engine()
     calls = {"n": 0}
-    orig = Engine._execute_groupby_once
 
-    def counting(self, q, ds):
+    def counting(self, q, ds, *route):
         calls["n"] += 1
         raise ValueError("static planning error")
 
-    eng._execute_groupby_once = counting.__get__(eng)
+    eng._dispatch_groupby_once = counting.__get__(eng)
     with pytest.raises(ValueError):
         eng.execute(_q(), ds)
     assert calls["n"] == 1  # no second dispatch for non-transient errors

@@ -3731,10 +3731,16 @@ def test_stats_counts_findings_per_pass(tmp_path):
     assert doc["stats"]["findings_new"] == 1
 
 
-def test_whole_tree_stats_meets_time_budget_acceptance():
-    """The ISSUE 17 acceptance criterion, measured the way it is
-    specified — the full project run reports < 10 s via --stats — held
-    across every pass generation since (ISSUE 20 lands the 29th)."""
+def test_whole_tree_stats_meets_work_budget_acceptance():
+    """The ISSUE 17 acceptance criterion (the full project run under 10 s
+    via --stats), held across every pass generation since (ISSUE 20 lands
+    the 29th), as a budget of WORK and not of wall clock: tier-1 runs this
+    beside five other busy xdist workers, and a 10 s wall limit failed
+    there on a tree that had not changed (ledger, PRs 22 and 23).  The
+    yardstick is the run's own parse of the same files, in the same
+    process under the same load: when the criterion was written the parse
+    took 1.16 s of the 10, so the 29 passes together may cost 7.6 parses.
+    The parse's own work is a count, bounded beside it."""
     out = _cli(["--stats", *_TARGETS], cwd=_ROOT)
     assert out.returncode == 0, out.stdout + out.stderr
     line = [
@@ -3744,7 +3750,10 @@ def test_whole_tree_stats_meets_time_budget_acceptance():
     doc = json.loads(line[len("graftlint --stats "):])
     assert doc["passes"] == len(ALL_PASSES) == 29
     assert doc["findings_new"] == 0
-    assert doc["total_seconds"] < 10.0, doc["per_pass_seconds"]
+    assert 150 <= doc["files_scanned"] <= 300
+    per = dict(doc["per_pass_seconds"])
+    parse = per.pop("core:parse+project")
+    assert len(per) == 29 and sum(per.values()) < 7.6 * parse, per
 
 
 def test_baseline_has_no_superseded_lock_entries():

@@ -264,8 +264,7 @@ def test_eviction_counter_under_byte_pressure():
     )
     before = ctr.snapshot().get("ev", 0)
     # a budget far below the table's footprint forces LRU eviction
-    eng = Engine(device_cache_bytes=4 << 10)
-    eng._calibrated_cfg = ctx.config
+    eng = Engine(device_cache_bytes=4 << 10, config=ctx.config)
     ds = ctx.catalog.get("ev")
     for seg in ds.segments[:8]:
         eng._device_cols(seg, ["v"], ds_name="ev")
