@@ -560,9 +560,13 @@ class AdaptiveDomainMixin:
         # model at the compacted cardinality, not the cutover rule: on a
         # CPU the dense one-hot below it is the wrong side of a ~200x
         # inversion (measured: a 60M-row phase B at G'=600 ran 49 s dense
-        # vs sub-second scatter; on TPU the same choice lands on Pallas)
-        with span(SPAN_ROUTE, tier="adaptive"):
+        # vs sub-second scatter).  On a TPU the dense class is priced on
+        # the Pallas kernel it runs as (plan/calibrate.py) and takes every
+        # compacted domain the kernel does; the span says which ran
+        with span(SPAN_ROUTE, tier="adaptive") as sp:
             strat = shape_kernel(ds.num_rows, clow.num_groups, cfg)
+            if sp is not None:
+                sp.attrs.update(kernel=strat, groups=clow.num_groups)
         state = self._partials_for_query(
             q, ds, lowering=clow, key_extra=("adaptive",) + cards,
             strategy_override=strat, span_attrs={"phase": "B"},

@@ -50,11 +50,13 @@ _LANE = 128
 # this constant bounds G for the dense strategy overall.
 DENSE_MAX_GROUPS = 1 << 17
 
-# ESTIMATED dense-vs-scatter crossover for a v5e-class chip.  The estimate follows the cost-model formula
-# (G/128 <= 4 * scatter_cost_per_row); `plan/calibrate.py` replaces it with
-# a measured value the first time it runs on the real backend, and the
-# calibrated crossover is what the planner actually uses
-# (SessionConfig.load_calibrated).
+# The one-hot cap and the high-cardinality tiers' eligibility line; nothing
+# measures or replaces it.  Up to it a TPU's dense class runs as the Pallas
+# kernel (plan/cost.concrete_kernel), the model offers that class no further
+# (cost.dense_class_cap), and "auto" resolves dense; past it a query with
+# dimensions may take the adaptive or the sparse tier (cost.tier_takes).
+# Below it, dense against scatter is the calibrated model's choice
+# (plan/cost.shape_kernel), not this constant's.
 SCATTER_CUTOVER = 4096
 
 

@@ -86,6 +86,7 @@ from ..obs import (
     SPAN_DEVICE_FETCH,
     SPAN_FINALIZE,
     SPAN_PROGRAM_LOOKUP,
+    SPAN_ROUTE,
     SPAN_SEGMENT_DISPATCH,
     SPAN_SPARSE_DISPATCH,
     current_query_id,
@@ -1075,10 +1076,13 @@ class DistributedEngine:
         # phase B kernel from the calibrated model at the COMPACTED
         # cardinality and the shape a device runs: the function the
         # one-chip phase B calls (exec/adaptive_exec.py)
-        strat = shape_kernel(
-            max(1, ds.num_rows // self._row_device_count()),
-            self._groups_split(clow.num_groups)[1], cfg,
-        )
+        groups = self._groups_split(clow.num_groups)[1]
+        with span(SPAN_ROUTE, tier="adaptive") as sp:
+            strat = shape_kernel(
+                max(1, ds.num_rows // self._row_device_count()), groups, cfg
+            )
+            if sp is not None:
+                sp.attrs.update(kernel=strat, groups=groups)
         m.num_groups = clow.num_groups
         return self._execute_dense_state(
             q, ds, clow, m, strat, key_extra=("adaptive",) + cards,

@@ -3,17 +3,32 @@
 Reference parity: the reference's `DruidQueryCostModel` ships tunable cost
 constants via SQLConf with documented defaults the operator is expected to
 re-tune per deployment (SURVEY.md §2 cost-model row `[U]`).  Round 1 shipped
-guessed constants; this module replaces guessing with measurement: it times
-the actual kernels the engine dispatches —
+guessed constants; this module replaces guessing with measurement.  What is
+timed is what the chooser (`plan/cost.py`) names, called the way the engines
+call it, `ops.groupby.partial_aggregate(gid[R], mask[R], values[R, 2],
+strategy=<kernel>)` over whole segments' worth of rows with a filter mask:
 
-* dense one-hot partial aggregation (`ops/groupby.dense_partial_aggregate`)
-  -> `cost_per_row_dense` (us per row per 128-wide group tile),
-* scatter segment-sum                    -> `cost_per_row_scatter` (us/row),
+* `cost_per_row_dense` (us per row per 128-wide group tile): the kernel
+  `concrete_kernel("dense", g)` names on THIS backend — the compiled Pallas
+  kernel on a TPU, the XLA one-hot scan elsewhere — at G = 128 and G = 896
+  (1 and 7 tiles), the model's `rows x c x tiles` fitted through both.  A
+  price of the dense class is a price of that kernel and of no other: the
+  model offers the class only where `concrete_kernel` launches it
+  (`cost.dense_class_cap`);
+* `cost_per_row_scatter` / `_hi` (us/row at 1,024 and 2^20 groups) and
+  `cost_per_group_state`: `scatter_partial_aggregate` (strategy
+  "segment"), masked rows written to its trash slot as phase B's are;
+* `cost_per_row_sparse`, `cost_per_row_compact`: the sort-compaction tier's
+  two passes;
+* `stream_bytes_per_s`, `h2d_bytes_per_s`: a streamed reduction, a put;
 * psum of a [G, M] state over the mesh   -> `collective_bytes_per_us`,
 * a tiny end-to-end SPMD dispatch        -> `cost_dispatch_us`
 
-— and writes `calibration.json` at the repo root, which
-`SessionConfig.load_calibrated()` reads.  Run on the TPU to get real-chip
+— and writes `calibration.json` at the repo root and its per-platform
+sidecar, which `SessionConfig.load_calibrated()` reads.  A key the sweep did
+not write (the hand-kept `vmem_budget_bytes`, a mesh reading taken on a
+four-chip host, a constant a clipped sweep never reached) keeps the value
+the file held for the same device.  Run on the TPU to get real-chip
 constants; on CPU the constants are CPU-honest (the planner's choices then
 match the backend that will actually execute).
 """
@@ -32,6 +47,10 @@ _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 DEFAULT_PATH = os.path.join(_REPO_ROOT, "calibration.json")
+
+# group domains the dense class's kernel is timed at: 1 and 7 lane tiles
+# (7: SSB q4_3's compacted domain of 800, the widest phase B the cells run)
+DENSE_PROBE_GROUPS = (128, 896)
 
 
 def sidecar_path(platform: str, root: Optional[str] = None) -> str:
@@ -223,7 +242,26 @@ def measure_mesh() -> Dict[str, float]:
         lambda s: tiny_agg(tgid, tsv, jnp.full((1, 1), s, jnp.float32))
     )
     out["cost_dispatch_us"] = t_tiny * 1e6
+    out["collective_measured_on"] = "%d x %s, plan/calibrate.py measure_mesh" % (
+        n_dev, jax.devices()[0].device_kind,
+    )
     return out
+
+
+def _kept_keys(paths, out: Dict) -> Dict:
+    """What the file being replaced holds beyond this sweep's `out`: the
+    keys of the first of `paths` that reads as a calibration of the same
+    device.  A re-run then keeps what no single sweep measures (PR 22 had
+    to put `vmem_budget_bytes` back by hand after one)."""
+    for path in paths:
+        try:
+            with open(path) as f:
+                old = json.load(f)
+        except (OSError, ValueError):
+            continue
+        if isinstance(old, dict) and old.get("device") == out["device"]:
+            return {k: v for k, v in old.items() if k not in out}
+    return {}
 
 
 def calibrate(
@@ -233,10 +271,10 @@ def calibrate(
     budget_s: Optional[float] = None,
 ) -> Dict[str, float]:
     """`budget_s` caps wall time (every step pays a compile).  When the
-    deadline passes, remaining steps are
-    skipped, the file is marked `"partial": true`, and unmeasured constants
-    stay at their platform-profile defaults (cost_per_row_compact falls
-    back to the scatter floor so the schema check still sees it)."""
+    deadline passes, remaining steps are skipped, the file is marked
+    `"partial": true`, and an unmeasured constant keeps what the file held
+    for this device, else its platform-profile default (cost_per_row_compact
+    falls back to the scatter floor so the schema check still sees it)."""
     import jax
     import jax.numpy as jnp
 
@@ -247,13 +285,16 @@ def calibrate(
     def over() -> bool:
         return deadline is not None and time.perf_counter() > deadline
 
-    from ..catalog.segment import ROW_PAD
-    from ..ops.groupby import dense_partial_aggregate
+    from ..ops.groupby import partial_aggregate
+    from .cost import _g_tiles, concrete_kernel
 
     rng = np.random.default_rng(0)
     half = rows // 2
     gid = jnp.asarray(rng.integers(0, groups, size=rows).astype(np.int32))
     mask = jnp.ones(rows, jnp.bool_)
+    # the filter mask the dense and scatter kernels are timed under: half
+    # the rows are the scatter's trash-slot writes, the kernel's -1 ids
+    keep = jnp.asarray(rng.random(rows) < 0.5)
     sv = jnp.asarray(rng.random((rows, 2)).astype(np.float32))
     mmv = jnp.zeros((rows, 0), jnp.float32)
     mmm = jnp.zeros((rows, 0), jnp.bool_)
@@ -287,55 +328,55 @@ def calibrate(
     # bandwidth loop: a 400 s CPU compile measuring nothing) and (b)
     # embeds megabytes of data in every program a remote-compile backend
     # must ship.  Slices for the low size are taken ONCE, outside timing.
-    gid_lo, mask_lo, sv_lo = gid[:half], mask[:half], sv[:half]
+    mask_lo, keep_lo, sv_lo = mask[:half], keep[:half], sv[:half]
     mmv_lo, mmm_lo = mmv[:half], mmm[:half]
 
-    # dense one-hot kernel: us / row / 128-tile, from the two-size slope.
-    # G=256 (2 tiles) keeps the measurement cheap on backends where dense
-    # is slow (CPU: ~1 us/row/tile); the constant is per-tile, so the
-    # planner scales it to any G.
-    g_dense = 256
-    gid_d = jnp.asarray(rng.integers(0, g_dense, size=rows).astype(np.int32))
-    gid_d_lo = gid_d[:half]
-    dense_fn = functools.partial(
-        dense_partial_aggregate,
-        num_groups=g_dense,
-        block_rows=min(rows, 1 << 15),
-        num_min=0,
-        num_max=0,
-    )
+    @functools.partial(jax.jit, static_argnames=("kernel", "n_groups"))
+    def agg_k(g, mk, v, mv, mm, salt, kernel, n_groups):
+        # the engines' call (exec/engine._segment_partials, the mesh's
+        # dense-state shard_fn): the dispatcher, handed the kernel's name
+        return _scalar(partial_aggregate(
+            g, mk, v + salt, mv, mm, num_groups=n_groups,
+            num_min=0, num_max=0, strategy=kernel,
+        ))
 
-    @jax.jit
-    def dense_k(g, mk, v, mv, mm, salt):
-        return _scalar(dense_fn(g, mk, v + salt, mv, mm))
-
-    def dense_at(n, salt):
-        if n == rows:
-            return dense_k(gid_d, mask, sv, mmv, mmm, jnp.float32(salt))
-        return dense_k(
-            gid_d_lo, mask_lo, sv_lo, mmv_lo, mmm_lo, jnp.float32(salt)
+    def agg_times(kernel, n_groups, g_hi):
+        """Median seconds of `kernel` over `rows` and over `half` rows."""
+        g_lo = g_hi[:half]
+        return (
+            _timeit_synced(lambda s: agg_k(
+                g_hi, keep, sv, mmv, mmm, jnp.float32(s),
+                kernel=kernel, n_groups=n_groups,
+            )),
+            _timeit_synced(lambda s: agg_k(
+                g_lo, keep_lo, sv_lo, mmv_lo, mmm_lo, jnp.float32(s),
+                kernel=kernel, n_groups=n_groups,
+            )),
         )
 
-    tiles = max(1, -(-g_dense // 128))
-    cost_per_row_dense = _slope_us_per_row(
-        dense_at, rows, half, t_rtt=t_rtt
-    ) / tiles
+    # dense class: us / row / 128-tile of the kernel the class RUNS as on
+    # this backend, each domain's cost from the two-size slope.  Two tile
+    # counts, so the per-tile constant is a fit of the model's own form
+    # (rows x c x tiles, least squares through the origin: the wide domain
+    # weighs 49 x the narrow one, as it does in every decision the constant
+    # takes part in) and the file shows how far from linear the kernel is
+    dense_us_per_row = {}
+    for g_dense in DENSE_PROBE_GROUPS:
+        if dense_us_per_row and over():
+            break
+        dense_kernel = concrete_kernel("dense", g_dense)
+        t_hi, t_lo = agg_times(dense_kernel, g_dense, gid % g_dense)
+        dense_us_per_row[g_dense] = _slope_or_fallback(
+            t_hi, t_lo, rows, half, t_rtt
+        )
+    cost_per_row_dense = sum(
+        c * _g_tiles(g) for g, c in dense_us_per_row.items()
+    ) / sum(_g_tiles(g) ** 2 for g in dense_us_per_row)
 
     # scatter kernel: us/row at the base domain, plus the per-group state
     # cost separated from the wide domain's INTERCEPT difference (fixed
     # overheads cancel between the two domains; per-row cost is the slope)
-    @functools.partial(jax.jit, static_argnames=("n_seg",))
-    def scatter_k(g, v, salt, n_seg):
-        return _scalar(
-            jax.ops.segment_sum(v + salt, g, num_segments=n_seg)
-        )
-
-    t_sc_hi = _timeit_synced(
-        lambda s: scatter_k(gid, sv, jnp.float32(s), n_seg=groups)
-    )
-    t_sc_lo = _timeit_synced(
-        lambda s: scatter_k(gid_lo, sv_lo, jnp.float32(s), n_seg=groups)
-    )
+    t_sc_hi, t_sc_lo = agg_times("segment", groups, gid)
     cost_per_row_scatter = _slope_or_fallback(
         t_sc_hi, t_sc_lo, rows, half, t_rtt
     )
@@ -347,12 +388,7 @@ def calibrate(
     cost_per_group_state = None
     cost_per_row_scatter_hi = None
     if not over():
-        t_w_hi = _timeit_synced(
-            lambda s: scatter_k(gid_w, sv, jnp.float32(s), n_seg=wide)
-        )
-        t_w_lo = _timeit_synced(
-            lambda s: scatter_k(gid_w_lo, sv_lo, jnp.float32(s), n_seg=wide)
-        )
+        t_w_hi, t_w_lo = agg_times("segment", wide, gid_w)
         # floor: scatter at a WIDER domain can never be cheaper per row
         cost_per_row_scatter_hi = _slope_or_fallback(
             t_w_hi, t_w_lo, rows, half, t_rtt, floor=cost_per_row_scatter
@@ -511,6 +547,9 @@ def calibrate(
 
     out = {
         "cost_per_row_dense": cost_per_row_dense,
+        # what was timed for it, and each probed domain's own us/row
+        "dense_kernel": dense_kernel,
+        "dense_us_per_row": {str(g): c for g, c in dense_us_per_row.items()},
         "cost_per_row_scatter": cost_per_row_scatter,
         "stream_bytes_per_s": stream_bytes_per_s,
         "h2d_bytes_per_s": h2d_bytes_per_s,
@@ -558,16 +597,17 @@ def calibrate(
         out.update(measure_mesh())
 
     if save_path:
-        with open(save_path, "w") as f:
-            json.dump(out, f, indent=1)
         # per-platform sidecar: CPU and TPU runs alternate on this host and
         # each overwrites the primary file; SessionConfig.load_calibrated
         # falls back to calibration.<platform>.json on a device mismatch so
         # measured constants survive runs on the other backend
+        plat_path = sidecar_path(
+            out["platform"], root=os.path.dirname(save_path)
+        )
+        out = {**_kept_keys((plat_path, save_path), out), **out}
+        with open(save_path, "w") as f:
+            json.dump(out, f, indent=1)
         try:
-            plat_path = sidecar_path(
-                out["platform"], root=os.path.dirname(save_path)
-            )
             with open(plat_path, "w") as f:
                 json.dump(out, f, indent=1)
         except OSError:

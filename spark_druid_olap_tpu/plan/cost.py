@@ -123,6 +123,21 @@ def _g_tiles(num_groups: int) -> int:
     return max(1, -(-num_groups // 128))
 
 
+def dense_class_cap(cfg: SessionConfig) -> int:
+    """The widest domain the model offers the dense class at: as far as
+    `cost_per_row_dense` prices what would run.  The constant is measured
+    (plan/calibrate.py) on the kernel `concrete_kernel("dense", g)` names
+    on the backend: on a TPU the Pallas kernel, which takes the class only
+    up to the one-hot cap — past it the class would launch the XLA one-hot
+    at a price taken from another kernel, 12 x apart on the v5e (PR 30).
+    Anywhere else the XLA one-hot is what runs and what was timed."""
+    from ..ops.groupby import SCATTER_CUTOVER
+
+    if _pallas_ok():
+        return min(cfg.dense_max_groups, SCATTER_CUTOVER)
+    return cfg.dense_max_groups
+
+
 def scatter_row_cost(num_groups: int, cfg: SessionConfig) -> float:
     """Per-row scatter cost at this group-domain size: log-linear
     interpolation between the calibrated low-G and high-G anchor points,
@@ -168,11 +183,14 @@ def _kernel_costs(
     G' ~ G * selectivity (per-dim admitted fractions multiply the same way
     row selectivities do)."""
     n_segments = max(1, n_segments)
-    dense = (
-        rows * cfg.cost_per_row_dense * _g_tiles(num_groups)
-        if num_groups <= cfg.dense_max_groups
-        else float("inf")
-    )
+    dense_cap = dense_class_cap(cfg)
+
+    def dense_at(g: int) -> float:
+        if g > dense_cap:
+            return float("inf")
+        return rows * cfg.cost_per_row_dense * _g_tiles(g)
+
+    dense = dense_at(num_groups)
 
     def scatter_at(g: int) -> float:
         return (
@@ -210,12 +228,7 @@ def _kernel_costs(
         probe = rows * ndims * min(
             cfg.cost_per_row_dense, cfg.cost_per_row_scatter
         )
-        main = min(
-            scatter_at(g_c),
-            rows * cfg.cost_per_row_dense * _g_tiles(g_c)
-            if g_c <= cfg.dense_max_groups
-            else float("inf"),
-        )
+        main = min(scatter_at(g_c), dense_at(g_c))
         # probe amortized over repeats: the kept-set cache (the engine's
         # analog of Druid's bitmap indexes) makes every later execution of
         # the query a single compact-domain pass, and the OLAP workload

@@ -103,9 +103,14 @@ def _geo(n: int, rng) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     return reg.astype(object), nation, city.astype(object)
 
 
-def gen_tables(scale: float = 0.01, seed: int = 7) -> Dict[str, Dict[str, np.ndarray]]:
+def gen_tables(
+    scale: float = 0.01, seed: int = 7, fact_rows: int | None = None
+) -> Dict[str, Dict[str, np.ndarray]]:
     """Normalized SSB star at ~SF `scale` (SF1: 6M lineorder rows).  Keys are
-    dense 0..n-1 so the pre-join is a direct gather.
+    dense 0..n-1 so the pre-join is a direct gather.  `fact_rows` cuts the
+    fact alone: dimension tables at SF1 already hold every attribute value
+    SF10 has, so a small fact under them gives tests SF10's dictionaries,
+    lowerings and G.
 
     Materializes the WHOLE fact host-side — use at test scales.  Large
     scale factors go through `register_streamed`, which
@@ -116,7 +121,8 @@ def gen_tables(scale: float = 0.01, seed: int = 7) -> Dict[str, Dict[str, np.nda
     n_s = len(out["supplier"]["s_suppkey"])
     n_p = len(out["part"]["p_partkey"])
     out["lineorder"] = _gen_fact(
-        int(6_000_000 * scale), rng, out["dwdate"]["d_datekey"], n_c, n_s, n_p
+        int(6_000_000 * scale) if fact_rows is None else fact_rows,
+        rng, out["dwdate"]["d_datekey"], n_c, n_s, n_p,
     )
     return out
 

@@ -162,16 +162,8 @@ def ssb_ctx():
     import spark_druid_olap_tpu as sd
     from spark_druid_olap_tpu.workloads import ssb
 
-    rng = np.random.default_rng(7)
-    tables = ssb.gen_dim_tables(1.0, rng)
-    tables["lineorder"] = ssb._gen_fact(
-        4096, rng, tables["dwdate"]["d_datekey"],
-        len(tables["customer"]["c_custkey"]),
-        len(tables["supplier"]["s_suppkey"]),
-        len(tables["part"]["p_partkey"]),
-    )
     ctx = sd.TPUOlapContext()
-    ssb.register(ctx, tables=tables)
+    ssb.register(ctx, tables=ssb.gen_tables(1.0, seed=7, fact_rows=4096))
     return ctx
 
 
@@ -296,7 +288,16 @@ PHASE_B_KEPT = {
         "s_nation": ("supplier", "s_region", ["AMERICA"]),
         "p_category": ("part", "p_mfgr", ["MFGR#1", "MFGR#2"]),
     },
+    # seven lane tiles: the widest phase B the cells run, the kernel's
+    # since the dense class is priced on it (PR 30; the scatter's before)
+    "q4_3": {
+        "d_year": ("dwdate", "d_year", [1997, 1998]),
+        "s_city": ("supplier", "s_nation", ["UNITED STATES"]),
+        "p_brand1": ("part", "p_category", ["MFGR#14"]),
+    },
 }
+# G' of each: the `compact_groups` its `adaptive_kept` span reads at SF10
+PHASE_B_GROUPS = {"q2_1": 280, "q3_2": 600, "q4_2": 100, "q4_3": 800}
 
 
 @pytest.mark.parametrize("name", sorted(PHASE_B_KEPT))
@@ -331,6 +332,7 @@ def test_adaptive_phase_b_relays_out_only_the_kernel_operands(
         kept.append(np.flatnonzero(np.isin(values, present)).astype(np.int32))
     assert all(0 < len(k) < d.cardinality for k, d in zip(kept, lowering.dims))
     clow = adaptive_exec.compacted_lowering(lowering, kept)
+    assert clow.num_groups == PHASE_B_GROUPS[name]
     program = Engine(strategy="pallas")._arena_program(
         q, ds, clow, "pallas", key_extra=("adaptive",)
     )

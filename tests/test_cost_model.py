@@ -508,7 +508,9 @@ _SHAPES = [
             ("q2_3", 7, "pallas"), ("q3_1", 150, "pallas"),
             ("q3_2", 600, "pallas"), ("q3_3", 24, "pallas"),
             ("q3_4", 1, "pallas"), ("q4_2", 100, "pallas"),
-            ("q4_3", 800, "segment"),  # stays the scatter (ROADMAP S2a)
+            # 7 tiles: the scatter's until the dense class was priced on
+            # the kernel it runs as (PR 30; 153 ms of scatter a request)
+            ("q4_3", 800, "pallas"),
         ]
     ],
     ("mesh-q1_x", "tpu", _ROWS_1CHIP // 4, 1, "pallas"),
@@ -516,7 +518,18 @@ _SHAPES = [
     ("mesh-phaseB-q3_1", "tpu", _ROWS_1CHIP // 4, 150, "pallas"),
     ("mesh-phaseB-q2_1", "tpu", _ROWS_1CHIP // 4, 280, "pallas"),
     ("mesh-phaseB-q3_2", "tpu", _ROWS_1CHIP // 4, 600, "pallas"),
-    ("mesh-phaseB-q4_3", "tpu", _ROWS_1CHIP // 4, 800, "segment"),
+    ("mesh-phaseB-q4_3", "tpu", _ROWS_1CHIP // 4, 800, "pallas"),
+    # up to the one-hot cap the kernel's price wins; past it the class is
+    # not offered on a TPU (`dense_class_cap`), whatever its constant would
+    # say of 33, 63 or 75 tiles: no shape reaches the XLA one-hot there
+    *[
+        (f"{where}-G{g}", "tpu", rows, g, kernel)
+        for where, rows in (("1chip", _ROWS_1CHIP), ("mesh", _ROWS_1CHIP // 4))
+        for g, kernel in [
+            (1024, "pallas"), (2048, "pallas"), (4096, "pallas"),
+            (4097, "segment"), (8008, "segment"), (9600, "segment"),
+        ]
+    ],
     # the same q4_1 shape under the CPU's constants: the class flips
     ("cpu-q4_1", "cpu", _ROWS_1CHIP, 208, "segment"),
 ]
@@ -598,6 +611,75 @@ def test_chooser_high_cardinality_class(on_tpu):
         cost.choose_query_kernel = keep
 
 
+@pytest.fixture(scope="module")
+def ssb_sf10_shapes():
+    """The 13 SSB queries planned against SF10's dictionaries: dimension
+    tables at SF1 already hold every attribute value SF10 has (the fact is
+    4,096 rows), and the model is then asked about SF10's own 60 M rows in
+    115 segments.  name -> (query, datasource stand-in, G)."""
+    import types
+
+    from spark_druid_olap_tpu.exec.lowering import lower_groupby
+    from spark_druid_olap_tpu.sql.parser import parse_sql
+    from spark_druid_olap_tpu.workloads import ssb
+
+    ctx = sd.TPUOlapContext()
+    ssb.register(ctx, tables=ssb.gen_tables(1.0, seed=7, fact_rows=4096))
+    out = {}
+    for name in ssb.QUERIES:
+        lp, _, _ = parse_sql(ssb.QUERIES[name])
+        rw = ctx._planner().plan(lp)
+        ds = ctx.catalog.get(rw.datasource)
+        sf10 = types.SimpleNamespace(
+            num_rows=_ROWS_1CHIP, segments=[None] * 115, dicts=ds.dicts
+        )
+        out[name] = (rw.query, sf10, lower_groupby(rw.query, ds).num_groups)
+    return out
+
+
+def _cell_expectations():
+    """(cell, query, the cell file's `expect_strategy`) for the three cells."""
+    import glob
+
+    rows = []
+    for path in sorted(glob.glob(
+        os.path.join(_ROOT, "benchmark", "workloads", "*.json")
+    )):
+        with open(path) as f:
+            spec = json.load(f)
+        cell = os.path.basename(path)[: -len(".json")]
+        rows += [(cell, q, k) for q, k in sorted(spec["expect_strategy"].items())]
+    return rows
+
+
+@pytest.mark.parametrize("cell,name,expected", _cell_expectations())
+def test_ssb_classes_are_the_cell_files(
+    on_tpu, ssb_sf10_shapes, cell, name, expected
+):
+    """Under the committed `calibration.tpu.json` each SSB query's class is
+    what its cell's file expects (`QueryMetrics.strategy`: the tier, or the
+    kernel the dense class runs as), on one chip and on the (4, 1) mesh: a
+    re-measured constant that moved one would print "routing differs from
+    the cell's file" in every benchmark run."""
+    from spark_druid_olap_tpu.plan.cost import concrete_kernel, route_query
+
+    cfg = _file_cfg("tpu")
+    q, ds, G = ssb_sf10_shapes[name]
+    if "mesh4" in cell:
+        cfg.prefer_distributed = True
+        plan = choose_physical(q, ds, G, cfg, n_devices=4)
+        assert plan.distributed and plan.mesh_shape == (4, 1)
+        assert route_query(plan.strategy, q, ds, G, G, cfg) == expected
+    else:
+        cfg.prefer_distributed = False
+        plan = choose_physical(q, ds, G, cfg, n_devices=1)
+        assert not plan.distributed
+        routed = plan.strategy
+        if routed not in ("adaptive", "sparse"):
+            routed = concrete_kernel(routed, G)
+        assert routed == expected
+
+
 def _wide_ds(name, n=16_384, card=80):
     from spark_druid_olap_tpu.catalog.segment import (
         DimensionDict,
@@ -634,14 +716,15 @@ def _phase_b_kernel(cache, family_tags):
     return found.pop()
 
 
-@pytest.mark.parametrize("kept_b,kernel", [(30, "dense"), (40, "segment")])
-def test_engines_agree_with_the_chooser(kept_b, kernel):
+@pytest.mark.parametrize("kept_b,kernel", [(64, "pallas"), (65, "segment")])
+def test_engines_agree_with_the_chooser(on_tpu, kept_b, kernel):
     """One shape, three executors, one kernel: the single-device engine and
-    the mesh, handed the same class and constants, launch phase B (G' = 20 x
-    30 = 600 and 20 x 40 = 800, the two sides of the TPU constants' crossover)
-    with the kernel the chooser names for the shape each device runs, and
-    report the same tier.  The kernel library runs for real (no Pallas on
-    this backend: the dense class stays the XLA one-hot)."""
+    the mesh, handed the same class and constants, launch phase B (G' = 64 x
+    64 = 4,096 and 64 x 65 = 4,160: the two sides of the TPU's crossover,
+    which since PR 30 is the one-hot cap, the kernel's price winning all the
+    way up to it) with the kernel the chooser names for the shape each
+    device runs, and report the same tier.  Routing as on a TPU; the kernel
+    library runs for real (the Pallas kernel in interpret mode here)."""
     import jax
 
     from spark_druid_olap_tpu.exec.engine import Engine
@@ -652,17 +735,17 @@ def test_engines_agree_with_the_chooser(kept_b, kernel):
 
     assert len(jax.devices()) >= 4, "conftest must provide CPU devices"
     cfg = _file_cfg("tpu")
-    ds = _wide_ds(f"agree{kept_b}")
+    ds = _wide_ds(f"agree{kept_b}", card=100)
     q = GroupByQuery(
         datasource=ds.name,
         dimensions=(DimensionSpec("a"), DimensionSpec("b")),
         aggregations=(DoubleSum("s", "v"), Count("n")),
         filter=And((
-            InFilter("a", tuple(range(20))),
+            InFilter("a", tuple(range(64))),
             InFilter("b", tuple(range(kept_b))),
         )),
     )
-    g_compact = 20 * kept_b
+    g_compact = 64 * kept_b
     assert shape_kernel(ds.num_rows, g_compact, cfg) == kernel
     assert shape_kernel(ds.num_rows // 4, g_compact, cfg) == kernel
 

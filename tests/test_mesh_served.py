@@ -30,7 +30,7 @@ from spark_druid_olap_tpu.models.query import GroupByQuery
 from spark_druid_olap_tpu.obs import prof
 from spark_druid_olap_tpu.parallel.distributed import DistributedEngine
 from spark_druid_olap_tpu.parallel.mesh import make_mesh
-from spark_druid_olap_tpu.plan.cost import allreduce_factor
+from spark_druid_olap_tpu.plan.cost import allreduce_factor, shape_kernel
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -108,20 +108,18 @@ def _launches(node):
     return own + sum(_launches(c) for c in node.get("children", ()))
 
 
-def _names(node):
-    yield node["name"]
+def _nodes(node):
+    yield node
     for c in node.get("children", ()):
-        yield from _names(c)
+        yield from _nodes(c)
+
+
+def _names(node):
+    return (n["name"] for n in _nodes(node))
 
 
 def _find(node, name):
-    if node["name"] == name:
-        return node
-    for c in node.get("children", ()):
-        hit = _find(c, name)
-        if hit is not None:
-            return hit
-    return None
+    return next((n for n in _nodes(node) if n["name"] == name), None)
 
 
 @pytest.mark.parametrize("name", ["q1_1", "q2_1", "q4_1", "q4_3"])
@@ -175,6 +173,19 @@ def test_mesh_adaptive_kept_span_has_the_engines_attributes(served):
         assert (probe is not None) == (attrs["source"] == "measured")
         launch = _find(tree, "segment_dispatch")
         assert launch["attrs"]["phase"] == "B"
+        # which kernel phase B ran, and at what G' a device: the `route`
+        # span around the mesh's call of the chooser (PR 30)
+        routed = [
+            n["attrs"] for n in _nodes(tree)
+            if n["name"] == "route" and (n.get("attrs") or {}).get("tier")
+        ]
+        assert routed == [{
+            "tier": "adaptive", "groups": m.num_groups,
+            "kernel": shape_kernel(
+                system.datasource.num_rows // 4, m.num_groups,
+                system.ctx.config,
+            ),
+        }]
     assert sources[1] == "memo"
 
 

@@ -799,7 +799,19 @@ def test_adaptive_spans_kept_set_and_phases():
     phase_b = named(first, "segment_dispatch")
     assert phase_b and all(s["attrs"]["phase"] == "B" for s in phase_b)
     assert named(first, "device_fetch") and named(first, "finalize")
-    assert named(first, "route")
+    # phase B's `route` span says which kernel the chooser named for the
+    # compacted shape, and G' (PR 30)
+    from spark_druid_olap_tpu.plan.cost import shape_kernel
+
+    for spans in (first, repeat):
+        routed = [
+            s["attrs"] for s in named(spans, "route")
+            if (s.get("attrs") or {}).get("tier") == "adaptive"
+        ]
+        assert routed == [{
+            "tier": "adaptive", "groups": 108,
+            "kernel": shape_kernel(ds.num_rows, 108, eng.config),
+        }]
     # the repeat: kept set from the memo, no probe, phase B again
     kept2 = named(repeat, "adaptive_kept")
     assert kept2[0]["attrs"]["source"] == "memo"
