@@ -20,9 +20,17 @@ is that program.
   fold in canonical batch order) via boundary flags and live-flag
   selects, so results are BYTE-identical to the loop path: f32 partial
   sums are not reassociation-safe, and `jnp.where` is an exact bitwise
-  select.  Fold-state carries are donated on backends that support
-  aliasing (TPU/GPU), so the chunked scan never holds two copies of the
-  `[G, M]` state.
+  select.
+* **One device call** (ISSUE 33) — the zero carry is made and the last
+  batch flushed INSIDE the trace (`_member_init` before the scan,
+  `finish_member` after it), the batch-start flags are resident beside
+  the stack and an all-true membership is a trace constant: a warm
+  request that covers its plan in one chunk enqueues the scanned program
+  and nothing else between `arena_build` and the caller's fetch, as the
+  mesh's program always has.  The chunked form threads the carry through
+  per-batch calls (made by the first, flushed by the last) and donates
+  it on backends that support aliasing (TPU/GPU), so it never holds two
+  copies of the `[G, M]` state.
 * **Shape discipline** — `partial_aggregate`'s row-block partitioning
   depends on the segment's padded row count, so stacking UNEQUAL shapes
   to a common max would change the fold tree and break byte identity.
@@ -186,15 +194,18 @@ def plan_for(engine, batches, names) -> Optional[ArenaPlan]:
     return ArenaPlan(covered, batches[len(covered):], nbytes)
 
 
-def stacked_cols(engine, ds, plan: ArenaPlan, names) -> Dict[str, Any]:
-    """Fetch (or build and place) the plan's stacked `[B, R]` columns.
+def stacked_cols(engine, ds, plan: ArenaPlan, names):
+    """Fetch (or build and place) the plan's stacked `[B, R]` columns and
+    its `[B]` batch-start flags: `(cols, start)`.
 
     Every placement goes through `Engine._put_device_col` (transfer-
     discipline GL19xx): residency accounting, the byte-budget LRU, the
     h2d fault site, and link attribution all see the stack exactly like
     any segment column.  Retired-uid poisoning is handled upstream —
     `Engine.evict_segments` drops intersecting arena slices, and a plan
-    is built from a consistent datasource snapshot."""
+    is built from a consistent datasource snapshot.  The flags are a
+    function of the plan's uids and batch lengths, so they are placed
+    once beside the stack and a warm request only looks them up."""
     cols: Dict[str, Any] = {}
 
     def lookup(key, host_fn):
@@ -221,7 +232,15 @@ def stacked_cols(engine, ds, plan: ArenaPlan, names) -> Dict[str, Any]:
     )
     if ds.time_column and ds.time_column in cols:
         cols["__time"] = cols[ds.time_column]
-    return cols
+    # the batch lengths are in the key: the byte budget can cut the same
+    # uids into other batches for a wider column set
+    start = lookup(
+        arena_key(
+            plan.uids, "start", tuple(len(b) for b in plan.batches)
+        ),
+        lambda: plan.start,
+    )
+    return cols, start
 
 
 # ---------------------------------------------------------------------------
@@ -303,24 +322,30 @@ def build_arena_program(lowerings, strategies, share=None):
     """The ONE traced scan over stacked segment blocks, computing every
     member's partial fold in a single dispatch.  Signature:
 
-        fn(carry, cols, start, memb) -> carry
+        fn(carry, cols, start, memb, init=False, finish=False)
 
     `cols` maps column name -> [Bc, R]; `start` is the [Bc] batch-start
-    flag vector; `memb` is [Bc, n_members] block membership.  Flags are
-    DATA, not trace constants: one compiled program (per chunk shape)
-    serves any membership pattern.  Chunking threads the carry through
-    repeated calls — the op sequence (hence byte identity) is invariant
-    to where the chunk boundaries fall."""
-    import functools
-
+    flag vector; `memb` is [Bc, n_members] block membership, or None for
+    all-true (written in the trace).  Flags are DATA, not trace
+    constants: one compiled program (per chunk shape) serves any
+    membership pattern.  `init` and `finish` are STATIC: with `init` the
+    trace makes the zero carry itself (`carry` is None), with `finish`
+    it flushes the carry and returns `(sums, mins, maxs, live)` a member
+    — so a call that covers the whole plan, `fn(None, cols, start, memb,
+    init=True, finish=True)`, is the request's one device computation.
+    Chunking threads the carry through repeated calls, `init` on the
+    first and `finish` on the last — the op sequence (hence byte
+    identity) is invariant to where the chunk boundaries fall.  Each
+    form compiles on its first use."""
     import jax
+    import jax.numpy as jnp
     from jax import lax
 
     from .engine import _segment_partials
 
     n = len(lowerings)
 
-    def fn(carry, cols, start, memb):
+    def fn(carry, cols, start, memb, init=False, finish=False):
         def body(c, xs):
             cols_b, start_b, memb_b = xs
             memo: Dict[Any, Any] = {}
@@ -341,16 +366,25 @@ def build_arena_program(lowerings, strategies, share=None):
                     )
             return tuple(out), None
 
+        if init:
+            carry = tuple(_member_init(lw) for lw in lowerings)
+        if memb is None:
+            memb = jnp.ones((start.shape[0], n), dtype=bool)
         with device_scope(SCOPE_ARENA_SCAN):
             c2, _ = lax.scan(body, carry, (cols, start, memb))
+        if finish:
+            with device_scope(SCOPE_CARRY_MERGE):
+                return tuple(finish_member(c) for c in c2)
         return c2
 
     # pure builder: every caller (Engine._arena_program /
     # _arena_fused_program) stores the result in the engine program
     # cache under a structured query key
+    # (a flushing chunk returns four of a member's eight donated buffers:
+    # JAX warns once a compile that the batch half "were not usable")
     donate = {"donate_argnums": (0,)} if _donate_carry() else {}
     # graftlint: disable=jit-cache -- caller caches under a query key
-    return jax.jit(fn, **donate)
+    return jax.jit(fn, static_argnames=("init", "finish"), **donate)
 
 
 def finish_member(carry_i):
@@ -403,22 +437,27 @@ def _chunk_bounds(plan: ArenaPlan, site: str = "") -> List[Tuple[int, int, int]]
 
 
 def run_plan(
-    engine, ds, plan: ArenaPlan, names, program, lowerings,
+    engine, ds, plan: ArenaPlan, names, program,
     memb: Optional[np.ndarray] = None, pc=None, checkpoint_site="",
     single_chunk: bool = False, span_attrs: Optional[dict] = None,
 ):
     """Build/fetch the stacked columns, then dispatch the scan program
-    over the plan's chunks.  Returns (carries, batches_folded) — the
-    final per-member carry tuple plus how many covered batches actually
-    folded (fewer than planned on a deadline/partial truncation).
+    over the plan's chunks.  Returns (states, batches_folded): the
+    finished `(sums, mins, maxs, live)` of every member, flushed inside
+    the program, plus how many covered batches actually folded (fewer
+    than planned on a deadline/partial truncation; `states` is None
+    when none did).
 
-    The stack build lives under the `arena_build` receipt bucket; each
-    chunk dispatch is a `segment_dispatch` span (with `span_attrs`, the
-    caller's word on what the dispatch is), so `dispatch_count` and the
-    device/transfer attribution stay honest."""
+    One chunk is the WHOLE form: the request's single device call makes
+    its own zero carry and flushes it, and nothing else is enqueued
+    between `arena_build` and the caller's fetch.  Several chunks (a
+    deadline armed, `_chunk_bounds`) thread the carry through the CHUNK
+    form's calls.  The stack build lives under the `arena_build` receipt
+    bucket; each chunk dispatch is a `segment_dispatch` span (attr
+    `form`, and `span_attrs`, the caller's word on what the dispatch
+    is), so `dispatch_count` and the device/transfer attribution stay
+    honest."""
     import time as _time
-
-    import jax.numpy as jnp
 
     from .engine import _row_counts
 
@@ -429,12 +468,11 @@ def run_plan(
     # call count identical to the loop path's one-per-batch cadence, so
     # skip=K fault injection truncates both paths at the same boundary.
     if checkpoint_site and checkpoint_partial(checkpoint_site):
-        return tuple(_member_init(lw) for lw in lowerings), 0
+        return None, 0
     with span(
         SPAN_ARENA_BUILD, blocks=len(plan.segs), batches=len(plan.batches),
     ):
-        cols = stacked_cols(engine, ds, plan, names)
-    start = memb_arr = carries = None
+        cols, start = stacked_cols(engine, ds, plan, names)
     # the fused path forces one chunk: its deadline contract is checked
     # once up front by the caller and an expiry re-routes members to
     # their serial partial-capable paths — no mid-scan truncation
@@ -443,6 +481,8 @@ def run_plan(
         if single_chunk
         else _chunk_bounds(plan, checkpoint_site)
     )
+    whole = len(chunks) == 1
+    carries = None
     done = 0
     for ci, (lo, hi, last_bi) in enumerate(chunks):
         # ci == 0 was checkpointed above, before the build
@@ -457,19 +497,19 @@ def run_plan(
             SPAN_SEGMENT_DISPATCH,
             arena=hi - lo,
             chunk=f"{ci + 1}/{len(chunks)}",
+            form="whole" if whole else "chunk",
             **(span_attrs or {}),
         ):
-            # the program's other arguments (flags, the zero carry) and
-            # the chunk's slice of every stacked column are part of the
-            # launch: small eager device arrays, a launch each
-            if carries is None:
-                start = jnp.asarray(plan.start)
-                if memb is None:
-                    memb_arr = jnp.ones((len(plan.segs), 1), dtype=bool)
-                else:
-                    memb_arr = jnp.asarray(memb)
-                carries = tuple(_member_init(lw) for lw in lowerings)
-            xs_cols = {n: a[lo:hi] for n, a in cols.items()}
+            if whole:
+                xs = (cols, start, memb)
+            else:
+                # a chunk's slice of every stacked column and flag is an
+                # eager device operation each: part of its launch
+                xs = (
+                    {n: a[lo:hi] for n, a in cols.items()},
+                    start[lo:hi],
+                    None if memb is None else memb[lo:hi],
+                )
             # first call of a newly-built program = trace+compile:
             # attribute it exactly like _call_segment_program does
             t0 = (
@@ -482,7 +522,7 @@ def run_plan(
             )
             t_call = _time.perf_counter()
             carries = program(
-                carries, xs_cols, start[lo:hi], memb_arr[lo:hi]
+                carries, *xs, init=ci == 0, finish=ci == len(chunks) - 1
             )
             carries = prof.dispatch_sync(carries, t_call)
             if t0 is not None:
@@ -494,4 +534,7 @@ def run_plan(
                 pc.add_seen(len(b), *_row_counts(b))
         done = last_bi + 1
         plan.folded = done
+    if done < len(plan.batches):
+        # truncated before the chunk that flushes: flush what folded
+        carries = tuple(finish_member(c) for c in carries)
     return carries, done

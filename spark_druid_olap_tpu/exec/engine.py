@@ -865,14 +865,13 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             # under the same site name, so deadline tests and armed
             # injections drive its chunked truncation exactly like
             # the dispatch loop's
-            carries, _done = _arena.run_plan(
-                self, ds, plan, need, program, [lowering], pc=pc,
+            states, _done = _arena.run_plan(
+                self, ds, plan, need, program, pc=pc,
                 checkpoint_site="engine.segment_loop", span_attrs=phase,
             )
             batches = plan.remainder
             if plan.folded:
-                s, mn, mx, _live = _arena.finish_member(carries[0])
-                sums, mins, maxs = s, mn, mx
+                sums, mins, maxs, _live = states[0]
             if plan.folded < len(plan.batches):
                 # truncated mid-arena: the remainder must not run
                 # (and its pending prefetch cancels with it)
@@ -1168,9 +1167,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 try:
                     # run_plan stamps batch_m's compile attribution on
                     # the first (trace+compile) dispatch
-                    carries, _done = _arena.run_plan(
-                        self, ds, plan, list(names), fn,
-                        [m[3] for m in members], memb=memb,
+                    states, _done = _arena.run_plan(
+                        self, ds, plan, list(names), fn, memb=memb,
                         single_chunk=True,
                     )
                 except BaseException:
@@ -1180,10 +1178,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     # membership is host-known: a member with no covered
                     # block keeps acc[i] = None (the loop's None-skip)
                     if len(plan.segs) and memb[:, i].any():
-                        s, mn, mx, _live = _arena.finish_member(
-                            carries[i]
-                        )
-                        acc[i] = (s, mn, mx)
+                        acc[i] = states[i][:3]
                 batches = plan.remainder
             # transfer pipeline: resident batches dispatch first, cold
             # batches' columns stream behind the fused compute; the

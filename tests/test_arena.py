@@ -17,6 +17,10 @@ Layers under test:
    contains it; the per-query opt-out and the session flag both route
    back to the loop path; donated fold-state buffers are requested
    exactly when the backend supports them.
+5. One device call (ISSUE 33): a warm single-chunk request makes its zero
+   carry and its final flush inside the scanned program, looks its batch
+   -start flags up beside the stack, and is byte-identical to the chunked
+   form that threads the carry.
 """
 
 import numpy as np
@@ -106,6 +110,28 @@ def _exact_equal(a, b):
 
 def _arena_keys(eng):
     return [k for k in eng._device_cache if arena.is_arena_key(k)]
+
+
+def _recording_programs(monkeypatch):
+    """Record `(carry, static flags)` of every arena program call."""
+    calls = []
+    real_build = arena.build_arena_program
+
+    def build(*a, **kw):
+        program = real_build(*a, **kw)
+
+        def call(carry, *xs, **flags):
+            calls.append((carry, flags))
+            return program(carry, *xs, **flags)
+
+        return call
+
+    monkeypatch.setattr(arena, "build_arena_program", build)
+    return calls
+
+
+def _forms(calls):
+    return [(carry is None, flags) for carry, flags in calls]
 
 
 # ---------------------------------------------------------------------------
@@ -501,14 +527,16 @@ def test_donation_requested_exactly_off_cpu(monkeypatch):
     ds, _ = _flat_ds(name="ar")
     q = _gb("ar")
     on = Engine()
-    got = on.execute(q, ds)
+    # an armed deadline chunks the scan: the form that threads a carry
+    with deadline_scope(60_000):
+        got = on.execute(q, ds)
     assert any(kw.get("donate_argnums") == (0,) for kw in calls)
     off = Engine()
     with arena.arena_disabled():
         _exact_equal(got, off.execute(q, ds))
 
 
-def test_donated_carry_holds_no_buffer_twice():
+def test_donated_carry_holds_no_buffer_twice(monkeypatch):
     """A TPU refuses a donated argument in which one buffer appears twice
     (`Attempt to donate the same buffer twice`, met on the first chip run
     of the arena); the CPU backend never donates, so only this sees it."""
@@ -519,6 +547,18 @@ def test_donated_carry_holds_no_buffer_twice():
     )
     carry = arena._member_init(types.SimpleNamespace(la=la, num_groups=8))
     assert len({id(leaf) for leaf in carry}) == len(carry) == 8
+    # the chunked form's carry is a program's output from the second
+    # chunk on (the first makes its own): eight buffers a member there too
+    calls = _recording_programs(monkeypatch)
+    ds, _ = _flat_ds(name="ar")
+    with deadline_scope(60_000):
+        Engine().execute(_gb("ar"), ds)
+    threaded = [carry for carry, _flags in calls]
+    assert threaded[0] is None and len(threaded) > 1
+    for carries in threaded[1:]:
+        for member in carries:
+            ptrs = {leaf.unsafe_buffer_pointer() for leaf in member}
+            assert len(ptrs) == len(member) == 8
 
 
 def test_no_donation_on_cpu_backend(monkeypatch):
@@ -533,7 +573,8 @@ def test_no_donation_on_cpu_backend(monkeypatch):
 
     monkeypatch.setattr(jax, "jit", recording_jit)
     ds, _ = _flat_ds(name="ar")
-    Engine().execute(_gb("ar"), ds)
+    with deadline_scope(60_000):  # the chunked form: a carry is threaded
+        Engine().execute(_gb("ar"), ds)
     if jax.default_backend() == "cpu":
         assert all("donate_argnums" not in kw for kw in calls)
     else:
@@ -662,3 +703,131 @@ def test_deadline_expired_before_build_skips_stack_and_falls_back():
     _exact_equal(got, want)
     # the stack build never ran: no arena slices entered the cache
     assert not _arena_keys(eng_on)
+
+
+# ---------------------------------------------------------------------------
+# 5. one device call: carry and flush inside the trace (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+
+def _counting(monkeypatch, name):
+    """Count calls of `arena.<name>` (trace-time calls included)."""
+    calls = []
+    real = getattr(arena, name)
+
+    def counted(*a, **kw):
+        calls.append(name)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(arena, name, counted)
+    return calls
+
+
+def test_warm_request_is_one_program_call_and_nothing_eager(monkeypatch):
+    """The zero carry and the final flush are the program's own: on a
+    warm repeat neither is built on the host, and the one program call
+    is the request's only device computation before the fetch."""
+    inits = _counting(monkeypatch, "_member_init")
+    flushes = _counting(monkeypatch, "finish_member")
+    programs = _recording_programs(monkeypatch)
+    ds, _ = _flat_ds(name="ar")
+    q = _gb("ar")
+    eng = Engine()
+    first = eng.execute(q, ds)
+    # the cold request traced the whole form: one init, one flush
+    assert (len(inits), len(flushes), len(programs)) == (1, 1, 1)
+    del inits[:], flushes[:], programs[:]
+    _exact_equal(eng.execute(q, ds), first)
+    assert not inits and not flushes
+    assert _forms(programs) == [(True, {"init": True, "finish": True})]
+    assert eng.last_metrics.h2d_bytes == 0
+
+
+def _members(case):
+    if case == "one_member":
+        return [_gb("ar", aggs=(Count("n"), DoubleSum("s", "v")))]
+    if case == "min_max":
+        return [_gb("ar", aggs=(DoubleMin("mn", "v"), DoubleMax("mx", "v")))]
+    return [  # fused members over different scopes
+        _gb("ar"),
+        _gb("ar", intervals=[(0, 4_096_000)]),
+        _gb("ar", filt=Selector("d", "k1")),
+    ]
+
+
+@pytest.mark.parametrize("case", ["one_member", "fused_members", "min_max"])
+def test_whole_form_equals_chunked_form(case, monkeypatch):
+    """One call that makes and flushes its own carry against per-batch
+    calls that thread it (a deadline that never fires arms chunking):
+    the same fold, byte for byte, in every member's state."""
+    from spark_druid_olap_tpu.plan.cost import concrete_kernel
+
+    programs = _recording_programs(monkeypatch)
+    ds, _ = _flat_ds(name="ar")
+    eng = Engine()
+    queries = _members(case)
+    lowerings = [eng._lowering_for(q, ds) for q in queries]
+    scopes = [
+        frozenset(s.uid for s in eng._segments_in_scope(q, ds))
+        for q in queries
+    ]
+    names = list(dict.fromkeys(c for lw in lowerings for c in lw.columns))
+    segs = [s for s in ds.segments if any(s.uid in u for u in scopes)]
+    plan = arena.plan_for(eng, eng._segment_batches(segs, names), names)
+    assert plan is not None and len(plan.batches) > 2
+    memb = None
+    if len(queries) > 1:
+        memb = np.array(
+            [[s.uid in u for u in scopes] for s in plan.segs], dtype=bool
+        )
+    program = arena.build_arena_program(
+        lowerings,
+        [concrete_kernel(eng.strategy, lw.num_groups) for lw in lowerings],
+    )
+
+    def run():
+        return arena.run_plan(
+            eng, ds, plan, names, program, memb=memb,
+            checkpoint_site="engine.segment_loop",
+        )
+
+    whole, done = run()
+    assert done == len(plan.batches)
+    assert _forms(programs) == [(True, {"init": True, "finish": True})]
+    del programs[:]
+    with deadline_scope(60_000):
+        chunked, done = run()
+    assert done == len(plan.batches)
+    nb = len(plan.batches)
+    assert _forms(programs) == [
+        (ci == 0, {"init": ci == 0, "finish": ci == nb - 1})
+        for ci in range(nb)
+    ]
+    assert len(whole) == len(chunked) == len(queries)
+    for w, c in zip(whole, chunked):
+        assert len(w) == len(c) == 4
+        for a, b in zip(w, c):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert a.tobytes() == b.tobytes()
+        assert bool(w[3])
+
+
+def test_start_flags_are_resident_beside_the_stack():
+    """The batch-start flags are placed once under an arena key: the
+    repeat looks them up (no host->device copy) and a retired uid takes
+    them along with the stacked columns."""
+    ds, _ = _flat_ds(name="ar")
+    q = _gb("ar")
+    eng = Engine()
+    eng.execute(q, ds)
+    assert eng.last_metrics.h2d_bytes > 0
+    flags = [k for k in _arena_keys(eng) if k[1] == "start"]
+    assert len(flags) == 1
+    placed = eng._device_cache.get(flags[0])
+    assert placed.dtype == bool and placed.shape == (len(flags[0][0]) - 1,)
+    eng.execute(q, ds)
+    assert eng.last_metrics.h2d_bytes == 0
+    assert eng._device_cache.get(flags[0]) is placed
+    eng.evict_segments({flags[0][0][1]})
+    assert not [k for k in _arena_keys(eng) if k[1] == "start"]

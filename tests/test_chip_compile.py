@@ -258,6 +258,41 @@ def test_engine_arena_scan_compiles(one_chip, ssb_ctx, pallas_on, name):
     _assert_lane_dense(text)
 
 
+# q4_2's scope at SF10: 34 segments, two dispatch batches (32 + 2)
+TWO_BATCH_BLOCKS = 34
+
+
+@pytest.mark.parametrize("name", ["q1_1", "q4_1"])
+def test_engine_arena_whole_form_compiles(one_chip, ssb_ctx, pallas_on, name):
+    """The served default's one device call (ISSUE 33): the scan over a
+    two-batch scope with its zero carry made and its last batch flushed
+    inside the program — no carry argument, one `while`, the kernel in
+    its body, and still no operand relaid out in front of it."""
+    from spark_druid_olap_tpu.exec.engine import Engine
+
+    q, ds, lowering = _lowered_query(ssb_ctx, name)
+    program = Engine(strategy="pallas")._arena_program(
+        q, ds, lowering, "pallas"
+    )
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (TWO_BATCH_BLOCKS, R_SEGMENT),
+        one_chip,
+    )
+    compiled = program.lower(
+        None, cols, _spec((TWO_BATCH_BLOCKS,), jnp.bool_, one_chip), None,
+        init=True, finish=True,
+    ).compile()
+    # the finished state of the one member: sums, mins, maxs, live
+    (out,) = compiled.out_info
+    assert [o.shape for o in out][:1] == [
+        (lowering.num_groups, len(lowering.la.sum_names))
+    ] and len(out) == 4
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(re.findall(r" while\(", text)) == 1
+    _assert_lane_dense(text)
+
+
 def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
     """q2_1 (G=8008, past the one-hot cutover) routes to the adaptive
     tier; its phase-A presence pass counts each grouping dim's codes

@@ -738,6 +738,56 @@ def test_program_lookup_span_names_family_and_marks_a_miss():
     assert plans and all(p["attrs"]["cache_hit"] for p in plans)
 
 
+def test_arena_dispatch_span_names_its_form():
+    """The arena's `segment_dispatch` says which form ran: one call that
+    makes and flushes its own carry on the served default ("whole"), a
+    threaded carry over per-batch calls under a deadline ("chunk"); a
+    one-batch scope never takes the arena and carries no `form`."""
+    from spark_druid_olap_tpu.resilience import deadline_scope
+
+    cfg = SessionConfig()
+    cfg.result_cache_entries = 0
+    cfg.prefer_distributed = False
+    ctx = sd.TPUOlapContext(cfg)
+    rng = np.random.default_rng(33)
+    n = 8_192
+    ctx.register_table(
+        "fm_t",
+        {
+            "k": rng.choice(np.array(["x", "y", "z"], dtype=object), n),
+            "v": rng.random(n).astype(np.float32),
+            "t": (np.arange(n) * 1_000).astype(np.int64),
+        },
+        dimensions=["k"],
+        metrics=["v"],
+        time_column="t",
+        rows_per_segment=512,
+    )
+
+    def dispatches(sqlq):
+        ctx.sql(sqlq)
+        return [
+            s["attrs"]
+            for s in _walk(ctx.tracer.last_trace_dict()["spans"])
+            if s["name"] == "segment_dispatch"
+        ]
+
+    sqlq = "SELECT k, sum(v) AS s FROM fm_t GROUP BY k"
+    whole = dispatches(sqlq)
+    assert [a["form"] for a in whole] == ["whole"]
+    assert whole[0]["chunk"] == "1/1" and whole[0]["arena"] == 16
+    with deadline_scope(60_000):
+        chunked = dispatches(sqlq)
+    assert len(chunked) > 1
+    assert {a["form"] for a in chunked} == {"chunk"}
+    assert sum(a["arena"] for a in chunked) == 16
+    one_batch = dispatches(
+        "SELECT k, sum(v) AS s FROM fm_t "
+        "WHERE __time < TIMESTAMP '1970-01-01 00:00:01' GROUP BY k"
+    )
+    assert one_batch and not any("form" in a for a in one_batch)
+
+
 def test_adaptive_spans_kept_set_and_phases():
     """The adaptive tier in the tree: `adaptive_kept` around the kept-set
     work with the phase-A probes under it, phase B's dispatch marked; a
