@@ -389,7 +389,10 @@ class AdaptiveDomainMixin:
                 for d, strat in zip(lowering.dims, strategies):
                     with device_scope(SCOPE_PRESENCE):
                         s, _, _ = partial_aggregate(
-                            d.codes_fn(cols), mask, ones, zero_mm, zero_mmm,
+                            d.codes_fn(cols), mask,
+                            # the kernel counts from its match tile
+                            (None,) if strat == "pallas" else ones,
+                            zero_mm, zero_mmm,
                             num_groups=d.cardinality, num_min=0, num_max=0,
                             strategy=strat,
                         )

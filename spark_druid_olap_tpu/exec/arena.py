@@ -8,9 +8,15 @@ so the entire in-scope fold can run as ONE traced program — this module
 is that program.
 
 * **Stacked layout** — in-scope segments of EQUAL padded row count stack
-  into one device-resident `[B, R]` array per column (plus the stacked
-  `[B, R]` validity masks: each segment's existing ROW_PAD tail is the
-  padding, so the stack adds zero pad waste).  The stack is placed
+  into one device-resident array per column (plus the stacked validity
+  masks: each segment's existing ROW_PAD tail is the padding, so the
+  stack adds zero pad waste), `[B, R / 128, 128]`: a TPU tiles an
+  array's two minor dimensions in (8, 128), so a `[B, R]` stack lays a
+  block's row as one sublane of every tile and the scan's slice of it is
+  a strided gather (on a v5e 23 us to copy a 2 MB value row out and 30
+  for the group-id fusion to read its keys, against 3.5 and 4.2 from
+  this layout, where a block is one contiguous run that the scan body
+  flattens back to `[R]` for nothing; PERF.md, PR 36).  The stack is placed
   through `Engine._put_device_col` under an `(("arena", *uids), ...)`
   key, so the residency byte budget, LRU eviction, h2d fault site, link
   accounting, and prefetch poisoning all hold unchanged.
@@ -195,8 +201,8 @@ def plan_for(engine, batches, names) -> Optional[ArenaPlan]:
 
 
 def stacked_cols(engine, ds, plan: ArenaPlan, names):
-    """Fetch (or build and place) the plan's stacked `[B, R]` columns and
-    its `[B]` batch-start flags: `(cols, start)`.
+    """Fetch (or build and place) the plan's stacked `[B, R / 128, 128]`
+    columns and its `[B]` batch-start flags: `(cols, start)`.
 
     Every placement goes through `Engine._put_device_col` (transfer-
     discipline GL19xx): residency accounting, the byte-budget LRU, the
@@ -207,6 +213,10 @@ def stacked_cols(engine, ds, plan: ArenaPlan, names):
     function of the plan's uids and batch lengths, so they are placed
     once beside the stack and a warm request only looks them up."""
     cols: Dict[str, Any] = {}
+
+    def stack(rows):
+        # block-contiguous (module docstring); R is a ROW_PAD multiple
+        return np.stack(rows).reshape(len(rows), -1, 128)
 
     def lookup(key, host_fn):
         arr = engine._device_cache.get(key)
@@ -222,13 +232,13 @@ def stacked_cols(engine, ds, plan: ArenaPlan, names):
     for n in names:
         cols[n] = lookup(
             arena_key(plan.uids, "col", n),
-            lambda n=n: np.stack(
+            lambda n=n: stack(
                 [np.asarray(s.column(n)) for s in plan.segs]
             ),
         )
     cols["__valid"] = lookup(
         arena_key(plan.uids, "valid"),
-        lambda: np.stack([np.asarray(s.valid) for s in plan.segs]),
+        lambda: stack([np.asarray(s.valid) for s in plan.segs]),
     )
     if ds.time_column and ds.time_column in cols:
         cols["__time"] = cols[ds.time_column]
@@ -324,7 +334,8 @@ def build_arena_program(lowerings, strategies, share=None):
 
         fn(carry, cols, start, memb, init=False, finish=False)
 
-    `cols` maps column name -> [Bc, R]; `start` is the [Bc] batch-start
+    `cols` maps column name -> [Bc, R / 128, 128] (`stacked_cols`; a
+    [Bc, R] stack is taken as well); `start` is the [Bc] batch-start
     flag vector; `memb` is [Bc, n_members] block membership, or None for
     all-true (written in the trace).  Flags are DATA, not trace
     constants: one compiled program (per chunk shape) serves any
@@ -348,6 +359,8 @@ def build_arena_program(lowerings, strategies, share=None):
     def fn(carry, cols, start, memb, init=False, finish=False):
         def body(c, xs):
             cols_b, start_b, memb_b = xs
+            # a block back to its rows: the same words in the same order
+            cols_b = {k: a.reshape(-1) for k, a in cols_b.items()}
             memo: Dict[Any, Any] = {}
             out = []
             for i in range(n):

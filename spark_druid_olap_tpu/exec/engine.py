@@ -326,7 +326,9 @@ def _segment_partials(
         mg, gg, j = share
         mask0 = memo.get(("mask", mg, j))
         gid0 = memo.get(("gid", gg, j))
-    gid, mask, sv, mmv, mmm = lowering.row_arrays(cols, mask=mask0, gid=gid0)
+    gid, mask, sv, mmv, mmm = lowering.row_arrays(
+        cols, mask=mask0, gid=gid0, strategy=strategy
+    )
     if memo is not None and share is not None:
         memo.setdefault(("mask", mg, j), mask)
         memo.setdefault(("gid", gg, j), gid)

@@ -224,6 +224,9 @@ class LoweredAggs:
     # and a duplicate all-ones scatter column is pure waste (the scatter
     # cost scales with the column count)
     aliased: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # sum name -> the stored metric column it sums as it is (no expression,
+    # no dictionary decode): the Pallas kernel reads that column itself
+    stored: Dict[str, str] = dataclasses.field(default_factory=dict)
 
 
 def _lower_aggs(
@@ -265,6 +268,8 @@ def _lower_aggs(
             la.long_valued[name] = isinstance(agg, A.LongSum)
             la.value_fns[name] = _field_value_fn(field, ds)
             _add_null_skip(la, name, field, ds)
+            if field not in ds.dicts:
+                la.stored[name] = field
         elif isinstance(agg, (A.LongMin, A.DoubleMin)):
             field = agg.field_name
             la.min_names.append(name)
@@ -417,6 +422,7 @@ class GroupByLowering:
         cols: Dict[str, jnp.ndarray],
         mask: Optional[jnp.ndarray] = None,
         gid: Optional[jnp.ndarray] = None,
+        strategy: Optional[str] = None,
     ):
         """cols: name -> row-aligned device array (must include "__valid",
         and "__time" when the query touches time).  Returns the kernel ABI
@@ -427,7 +433,18 @@ class GroupByLowering:
         the filter mask / group-id pipeline once per segment for members
         whose (virtualColumns, filter, intervals) / (virtualColumns,
         dimensions) sub-lowerings are identical, instead of re-tracing
-        them per member inside the fused program."""
+        them per member inside the fused program.
+
+        `strategy` is the kernel `plan/cost.py` chose for the call the
+        tuple goes to.  The XLA one-hot scan and the scatter (and a call
+        that names none) get `sum_values` as `f32[R, Ms]`, every column
+        multiplied by the row mask: their one-hot and their trash slot
+        need it.  "pallas" masks by the group id alone
+        (`ops/pallas_groupby.py`) and gets one entry a sum column instead:
+        the value's `[R]` row NOT multiplied by the row mask (a stored
+        float32 / int32 metric column as it lies resident, no fusion in
+        front of the kernel), or `None` where the column counts the kept
+        rows.  An aggregator's own mask is multiplied in for either."""
         cols = dict(cols)
         self.add_virtual(cols)
         if mask is None:
@@ -443,19 +460,22 @@ class GroupByLowering:
                     gid = jnp.zeros(mask.shape, jnp.int32)
         with device_scope(SCOPE_AGG_INPUTS):
             R = mask.shape[0]
-            maskf = mask.astype(jnp.float32)
-            sum_cols = []
-            for n in la.sum_names:
-                base = (
-                    la.value_fns[n](cols)
-                    if la.value_fns[n] is not None else None
-                )
-                v = maskf if base is None else base * maskf
-                mfn = la.mask_fns.get(n)
-                if mfn is not None:
-                    v = v * mfn(cols).astype(jnp.float32)
-                sum_cols.append(v)
-            sum_values = jnp.stack(sum_cols, axis=1)
+            if strategy == "pallas":
+                sum_values = [self._unmasked_sum_row(n, cols) for n in la.sum_names]
+            else:
+                maskf = mask.astype(jnp.float32)
+                sum_cols = []
+                for n in la.sum_names:
+                    base = (
+                        la.value_fns[n](cols)
+                        if la.value_fns[n] is not None else None
+                    )
+                    v = maskf if base is None else base * maskf
+                    mfn = la.mask_fns.get(n)
+                    if mfn is not None:
+                        v = v * mfn(cols).astype(jnp.float32)
+                    sum_cols.append(v)
+                sum_values = jnp.stack(sum_cols, axis=1)
             mm_names = la.min_names + la.max_names
             if mm_names:
                 mm_vals, mm_masks = [], []
@@ -472,6 +492,20 @@ class GroupByLowering:
                 minmax_values = jnp.zeros((R, 0), jnp.float32)
                 minmax_masks = jnp.zeros((R, 0), jnp.bool_)
         return gid, mask, sum_values, minmax_values, minmax_masks
+
+    def _unmasked_sum_row(self, name: str, cols) -> Optional[jnp.ndarray]:
+        """Sum column `name` as the Pallas kernel takes it (`row_arrays`)."""
+        la = self.la
+        stored = cols.get(la.stored.get(name))
+        if stored is not None and stored.dtype in (jnp.float32, jnp.int32):
+            base = stored
+        else:
+            base = la.value_fns[name](cols)  # None: a count
+        mfn = la.mask_fns.get(name)
+        if mfn is None:
+            return base
+        own = mfn(cols).astype(jnp.float32)
+        return own if base is None else base.astype(jnp.float32) * own
 
 
 def _query_key(q: Q.QuerySpec, ds: DataSource) -> Tuple:

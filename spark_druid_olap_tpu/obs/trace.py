@@ -156,7 +156,7 @@ SPAN_NAMES = frozenset(
 SCOPE_ARENA_SCAN = "sdol.arena_scan"  # body of the arena's scan over blocks
 SCOPE_FILTER = "sdol.filter"  # intervals + the query's filter -> row mask
 SCOPE_GROUP_KEYS = "sdol.group_keys"  # per-dim codes packed into one group id
-SCOPE_AGG_INPUTS = "sdol.agg_inputs"  # virtual columns; metrics stacked for the kernel
+SCOPE_AGG_INPUTS = "sdol.agg_inputs"  # virtual columns; the kernels' value rows
 SCOPE_PARTIAL_AGG = "sdol.partial_agg"  # the partial-aggregate kernel call
 SCOPE_CARRY_MERGE = "sdol.carry_merge"  # cross-segment / cross-batch fold
 SCOPE_PRESENCE = "sdol.presence"  # adaptive phase A: per-dim presence counts

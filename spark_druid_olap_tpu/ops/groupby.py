@@ -250,6 +250,16 @@ def partial_aggregate(
     one-hot in VMEM) up to SCATTER_CUTOVER groups (the XLA dense scan on
     non-TPU backends), and the scatter/segment path above it.
 
+    Who masks the sums.  "dense" and "segment" take `sum_values` as
+    `f32[R, Ms]` with every column already multiplied by `mask` (a count
+    is a column of the mask's 0/1): the one-hot scan contracts it as it
+    is, the scatter sends masked rows to a trash slot but adds them there.
+    "pallas" masks by the group id inside the kernel and makes a count
+    from its match tile: it takes one entry a sum column, an unmasked
+    `[R]` row or `None` for a count
+    (`exec/lowering.py row_arrays(strategy="pallas")`), and the array
+    form as well, masked or not (`ops/pallas_groupby.py`).
+
     Every current producer (combine_group_ids, the lowering codes_fns)
     already yields int32 gids; the astype below is a free no-op guard so a
     FUTURE narrow-width producer cannot wrap in trash-slot writes like

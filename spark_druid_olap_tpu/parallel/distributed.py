@@ -379,7 +379,9 @@ class DistributedEngine:
 
         def shard_fn(cols: Dict[str, jax.Array]):
             cols = lowering.add_virtual(dict(cols))  # sketches read virtuals
-            gid, mask, sv, mmv, mmm = lowering.row_arrays(cols)
+            gid, mask, sv, mmv, mmm = lowering.row_arrays(
+                cols, strategy=strategy
+            )
             if ng > 1:
                 off = lax.axis_index(GROUPS_AXIS).astype(jnp.int32) * Gl
                 gid_l = gid - off  # ids outside [0, Gl) never match the iota
@@ -583,7 +585,10 @@ class DistributedEngine:
             per = []
             for d, strat in zip(lowering.dims, strategies):
                 s, _, _ = partial_aggregate(
-                    d.codes_fn(cols), mask, ones, zero_mm, zero_mmm,
+                    d.codes_fn(cols), mask,
+                    # the kernel counts from its match tile
+                    (None,) if strat == "pallas" else ones,
+                    zero_mm, zero_mmm,
                     num_groups=d.cardinality, num_min=0, num_max=0,
                     strategy=strat,
                 )

@@ -6,7 +6,9 @@ re-tune per deployment (SURVEY.md §2 cost-model row `[U]`).  Round 1 shipped
 guessed constants; this module replaces guessing with measurement.  What is
 timed is what the chooser (`plan/cost.py`) names, called the way the engines
 call it, `ops.groupby.partial_aggregate(gid[R], mask[R], values[R, 2],
-strategy=<kernel>)` over whole segments' worth of rows with a filter mask:
+strategy=<kernel>)` over whole segments' worth of rows with a filter mask
+(the Pallas kernel in the form the lowering hands it: a count and one
+unmasked value row):
 
 * `cost_per_row_dense` (us per row per 128-wide group tile): the kernel
   `concrete_kernel("dense", g)` names on THIS backend — the compiled Pallas
@@ -335,8 +337,13 @@ def calibrate(
     def agg_k(g, mk, v, mv, mm, salt, kernel, n_groups):
         # the engines' call (exec/engine._segment_partials, the mesh's
         # dense-state shard_fn): the dispatcher, handed the kernel's name
+        v = v + salt
+        if kernel == "pallas":
+            # the form row_arrays hands that kernel: a count and one
+            # unmasked value row, as every cell's queries are
+            v = (None, v[:, 0])
         return _scalar(partial_aggregate(
-            g, mk, v + salt, mv, mm, num_groups=n_groups,
+            g, mk, v, mv, mm, num_groups=n_groups,
             num_min=0, num_max=0, strategy=kernel,
         ))
 

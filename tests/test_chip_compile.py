@@ -26,6 +26,11 @@ R_SEGMENT = 1 << 19  # rows of one SSB segment (ssb.register_streamed)
 ARENA_BLOCKS = 8
 
 
+def _arena_stack(blocks):
+    """Shape of a one-chip arena stack (`exec/arena.py stacked_cols`)."""
+    return (blocks, R_SEGMENT // 128, 128)
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -87,6 +92,57 @@ def _assert_lane_dense(text):
     assert not names(r"= (s32|pred|f32|bf16)\[(\d+,)?\d{5,}(,\d+)?\]\S* copy\(")
 
 
+def _kernel_calls(text):
+    """(operand names, operand shapes) of every `tpu_custom_call`."""
+    calls = []
+    for ln in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in ln:
+            continue
+        names = re.search(r" custom-call\(([^)]*)\)", ln).group(1)
+        shapes = re.search(r"operand_layout_constraints=\{(.*?\})\}", ln)
+        calls.append((
+            names.split(", "),
+            re.findall(r"(\w+\[[\d,]*\])\{", shapes.group(1)),
+        ))
+    return calls
+
+
+def _assert_operands_made_in_vmem(text, values, rows=R_SEGMENT, minmax=0):
+    """What ISSUE 36 took out of the programs: the kernel's value parts,
+    its count column and its mask multiply are made in VMEM, so in front
+    of every `tpu_custom_call` there is no `reduce-precision` fusion, no
+    bfloat16 per-row array, no fusion, concatenate or pad that writes a
+    stack of per-row rows, and the call's operands are the id row and
+    one dense `[rows / 128, 128]` view a sum VALUE: none for a count
+    (`__rows`), none for an aggregation class the query has not."""
+    assert " reduce-precision(" not in text
+    assert not re.search(r"bf16\[(\d+,)?\d{5,}", text)
+    stacked = [
+        ln.split(" = ")[0].strip() for ln in text.splitlines()
+        if re.search(
+            r"= (f32|bf16)\[([2-9]|\d\d+),\d{5,}\]\S* "
+            r"(fusion|concatenate|pad)\(", ln
+        )
+    ]
+    assert not stacked, stacked
+    calls = _kernel_calls(text)
+    assert calls
+    for names, shapes in calls:
+        assert len(names) == len(shapes) == 1 + values + minmax, (names, shapes)
+        assert shapes[0] == f"s32[1,{rows}]", shapes
+        assert all(
+            re.fullmatch(rf"(f32|s32)\[{rows // 128},128\]", sh)
+            for sh in shapes[1:1 + values]
+        ), shapes
+
+
+def _sum_values(lowering):
+    """Sum columns of a lowered query that bring the kernel an operand:
+    all but the hidden `__rows` count (an unfiltered COUNT(*) aliases it
+    and has no column of its own)."""
+    return len(lowering.la.sum_names) - 1
+
+
 # (G, Ms, Mn, Mx): SSB q1 (one group), TPC-H Q1, a min/max mix at the
 # 1024-group tile edge, the widest single tile, q2's 8008 (two tiles)
 KERNEL_SHAPES = [
@@ -107,10 +163,47 @@ def test_kernel_compiles_for_v5e(one_chip, G, Ms, Mn, Mx):
     _assert_kernel(compiled)
     text = compiled.as_text()
     _assert_lane_dense(text)
-    # the f32 values' exact split into bf16 parts survives the TPU
-    # pipeline only as `reduce-precision`: a convert to bf16 and back is
-    # elided there as excess precision, and the sums fall to bf16
-    assert text.count(" reduce-precision(") >= 2, "the bf16 split was elided"
+    # the other kernels' `[R, Ms]` array: every column a value operand.
+    # Mosaic legalized the split made in VMEM (bitcasts and a mask of the
+    # word: nothing XLA's TPU pipeline can elide as excess precision, as
+    # it did a convert to bf16 and back, PR 28)
+    _assert_operands_made_in_vmem(
+        text, values=Ms, rows=R_KERNEL, minmax=(Mn > 0) + (Mx > 0)
+    )
+
+
+# the lowering's form under strategy="pallas": (value dtypes, counts)
+ROWS_FORMS = {
+    "count_only": ((), 1),
+    "bare_f32_and_count": ((jnp.float32,), 1),
+    "bare_int32_and_count": ((jnp.int32,), 1),
+    "two_values_two_counts": ((jnp.float32, jnp.int32), 2),
+}
+
+
+@pytest.mark.parametrize("form", sorted(ROWS_FORMS))
+@pytest.mark.parametrize("G", [1, 800])
+def test_kernel_takes_unmasked_rows_for_v5e(one_chip, G, form):
+    """`row_arrays(strategy="pallas")`'s form: one `[R]` row a value,
+    float32 or int32 as the column is stored, `None` for a count.  The
+    dense view of a row is a bitcast: no fusion stands between the
+    parameter and the kernel."""
+    dtypes, counts = ROWS_FORMS[form]
+    gid, mask, _, mmv, mmm = _kernel_args((R_KERNEL,), G, 1, 0, 0, one_chip)
+    rows = (None,) * counts + tuple(
+        _spec((R_KERNEL,), dt, one_chip) for dt in dtypes
+    )
+    text = pallas_partial_aggregate.lower(
+        gid, mask, rows, mmv, mmm, num_groups=G, num_min=0, num_max=0,
+    ).compile().as_text()
+    _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=len(dtypes), rows=R_KERNEL)
+    fusions = [
+        ln for ln in text.splitlines() if re.search(r"= \S+ fusion\(", ln)
+        and re.search(r"= (f32|s32)\[\d{5,}", ln)
+    ]
+    # the one fusion a segment: the id row, -1 where masked
+    assert len(fusions) == 1 and "s32[" in fusions[0], fusions
 
 
 def test_kernel_compiles_at_the_mesh_shape_of_use(one_chip):
@@ -123,6 +216,9 @@ def test_kernel_compiles_at_the_mesh_shape_of_use(one_chip):
     ).compile()
     _assert_kernel(compiled)
     _assert_lane_dense(compiled.as_text())
+    _assert_operands_made_in_vmem(
+        compiled.as_text(), values=3, rows=29 * R_SEGMENT
+    )
 
 
 SCAN_G, SCAN_MS = 1024, 2
@@ -197,11 +293,11 @@ def _segment_col_specs(ctx, ds, names, lead, sharding):
 
 def _compiled_arena_text(ctx, ds, lowering, program, sharding):
     """`program` (an arena scan over `lowering`) compiled for the described
-    chip at `[ARENA_BLOCKS, R_SEGMENT]` stacks: the compiler's HLO text."""
+    chip at stacks of `ARENA_BLOCKS` segments: the compiler's HLO text."""
     from spark_druid_olap_tpu.exec import arena
 
     cols = _segment_col_specs(
-        ctx, ds, lowering.columns, (ARENA_BLOCKS, R_SEGMENT), sharding
+        ctx, ds, lowering.columns, _arena_stack(ARENA_BLOCKS), sharding
     )
     carry = jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, sharding),
@@ -240,7 +336,11 @@ def test_engine_segment_program_compiles(one_chip, ssb_ctx, pallas_on, name, G):
     cols = _segment_col_specs(
         ssb_ctx, ds, lowering.columns, (R_SEGMENT,), one_chip
     )
-    _assert_kernel(seg_fn.lower([cols, cols]).compile())
+    text = seg_fn.lower([cols, cols]).compile().as_text()
+    # flight1's request (q1_1) is this program: one kernel call a segment
+    assert len(_kernel_calls(text)) == 2
+    _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=_sum_values(lowering))
 
 
 @pytest.mark.parametrize("name", ["q1_1", "q4_1"])
@@ -256,6 +356,7 @@ def test_engine_arena_scan_compiles(one_chip, ssb_ctx, pallas_on, name):
     text = _compiled_arena_text(ssb_ctx, ds, lowering, program, one_chip)
     assert "tpu_custom_call" in text
     _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=_sum_values(lowering))
 
 
 # q4_2's scope at SF10: 34 segments, two dispatch batches (32 + 2)
@@ -275,7 +376,7 @@ def test_engine_arena_whole_form_compiles(one_chip, ssb_ctx, pallas_on, name):
         q, ds, lowering, "pallas"
     )
     cols = _segment_col_specs(
-        ssb_ctx, ds, lowering.columns, (TWO_BATCH_BLOCKS, R_SEGMENT),
+        ssb_ctx, ds, lowering.columns, _arena_stack(TWO_BATCH_BLOCKS),
         one_chip,
     )
     compiled = program.lower(
@@ -291,6 +392,7 @@ def test_engine_arena_whole_form_compiles(one_chip, ssb_ctx, pallas_on, name):
     assert "tpu_custom_call" in text
     assert len(re.findall(r" while\(", text)) == 1
     _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=_sum_values(lowering))
 
 
 def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
@@ -303,7 +405,10 @@ def test_adaptive_presence_program_compiles(one_chip, ssb_ctx, pallas_on):
     seg_fn = eng._presence_program(q, ds, lowering)
     need = eng._presence_columns(q, lowering, ds)
     cols = _segment_col_specs(ssb_ctx, ds, need, (R_SEGMENT,), one_chip)
-    _assert_kernel(seg_fn.lower([cols]).compile())
+    text = seg_fn.lower([cols]).compile().as_text()
+    # a presence count is the match tile's row sum: the id row alone
+    assert len(_kernel_calls(text)) == len(lowering.dims)
+    _assert_operands_made_in_vmem(text, values=0)
 
 
 # the kept sets these queries' presence passes measure at SF10 (uniform
@@ -375,6 +480,7 @@ def test_adaptive_phase_b_relays_out_only_the_kernel_operands(
     assert "tpu_custom_call" in text
     assert "sdol.kept_remap" in text
     _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=_sum_values(clow))
 
 
 def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
@@ -402,6 +508,7 @@ def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
     _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(text, values=_sum_values(lowering))
     # every collective the compiler kept names its scope: a four-chip
     # trace shows the boundary merge's `%all-reduce`s under it
     merges = [
@@ -409,6 +516,34 @@ def test_spmd_arena_program_compiles_for_four_chips(topo, ssb_ctx, pallas_on):
         if re.search(r"= \S+ all-reduce(-start)?\(", ln)
     ]
     assert merges and all("sdol.boundary_merge" in ln for ln in merges), merges
+
+
+def test_mesh_dense_state_program_compiles_for_four_chips(
+    topo, ssb_ctx, pallas_on
+):
+    """The mesh's phase B and its small-G queries
+    (`DistributedEngine._spmd_fn`): the kernel once over a shard's whole
+    rows under `shard_map`, q4_1 over 29 SF10 segments a chip, handed
+    the lowering's unmasked rows like the one-chip programs."""
+    from spark_druid_olap_tpu.parallel.distributed import DistributedEngine
+    from spark_druid_olap_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    mesh = make_mesh(n_data=4, devices=topo.devices)
+    local_rows = 29 * R_SEGMENT
+    q, ds, lowering = _lowered_query(ssb_ctx, "q4_1")
+    cols = _segment_col_specs(
+        ssb_ctx, ds, lowering.columns, (4 * local_rows,),
+        NamedSharding(mesh, P(DATA_AXIS)),
+    )
+    run = DistributedEngine(mesh=mesh, strategy="pallas")._spmd_fn(
+        lowering, local_rows, ds, tuple(sorted(cols)), strategy="pallas"
+    )
+    text = run.lower(cols).compile().as_text()
+    assert "all-reduce" in text
+    _assert_lane_dense(text)
+    _assert_operands_made_in_vmem(
+        text, values=_sum_values(lowering), rows=local_rows
+    )
 
 
 def test_compiled_programs_keep_device_scopes(one_chip, ssb_ctx, pallas_on):

@@ -473,6 +473,37 @@ _MATRIX = {
                 },
                 {"GL703"},
             ),
+            # a scratch ref the kernel does not take (GL703)
+            (
+                {"pkg/kern.py": """
+                    import jax
+                    import jax.numpy as jnp
+                    from jax.experimental import pallas as pl
+                    from jax.experimental.pallas import tpu as pltpu
+
+                    def _copy_kernel(x_ref, o_ref):
+                        o_ref[:] = x_ref[:]
+
+                    def run(x):
+                        return pl.pallas_call(
+                            _copy_kernel,
+                            grid=(4,),
+                            in_specs=[
+                                pl.BlockSpec((8, 128), lambda i: (0, i)),
+                            ],
+                            out_specs=pl.BlockSpec(
+                                (8, 128), lambda i: (0, i)
+                            ),
+                            out_shape=jax.ShapeDtypeStruct(
+                                (8, 512), jnp.float32
+                            ),
+                            scratch_shapes=[
+                                pltpu.VMEM((8, 128), jnp.float32),
+                            ],
+                        )(x)
+                """},
+                {"GL703"},
+            ),
             # over-indexed ref + weak fill constant resolved through an
             # import (GL704, GL705)
             (
@@ -543,6 +574,49 @@ _MATRIX = {
                         in_specs=in_specs,
                         out_specs=out_specs,
                         out_shape=jax.ShapeDtypeStruct((8, 8), jnp.float32),
+                    )(x)
+            """},
+            # the kernel since ISSUE 36: one operand a value column, so
+            # `*refs` and a starred run of specs (no static count: silent),
+            # and scratch refs after the outputs
+            {"pkg/var.py": """
+                import functools
+
+                import jax
+                import jax.numpy as jnp
+                from jax.experimental import pallas as pl
+                from jax.experimental.pallas import tpu as pltpu
+
+                def _rows_kernel(gid_ref, *refs, num_vals):
+                    val_refs = refs[:num_vals]
+                    out_ref, stack_ref = refs[num_vals:]
+                    for k, ref in enumerate(val_refs):
+                        stack_ref[k:k + 1, :] = ref[:]
+                    out_ref[:] = stack_ref[:] * gid_ref[:]
+
+                def _fixed_kernel(x_ref, o_ref, acc_ref):
+                    acc_ref[:] = x_ref[:]
+                    o_ref[:] = acc_ref[:]
+
+                def run(gid, vals):
+                    row = pl.BlockSpec((1, 128), lambda i: (0, i))
+                    return pl.pallas_call(
+                        functools.partial(_rows_kernel, num_vals=len(vals)),
+                        grid=(4,),
+                        in_specs=[row, *[row] * len(vals)],
+                        out_specs=pl.BlockSpec((8, 128), lambda i: (0, i)),
+                        out_shape=jax.ShapeDtypeStruct((8, 512), jnp.float32),
+                        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+                    )(gid, *vals)
+
+                def run_fixed(x):
+                    return pl.pallas_call(
+                        _fixed_kernel,
+                        grid=(4,),
+                        in_specs=[pl.BlockSpec((8, 128), lambda i: (0, i))],
+                        out_specs=pl.BlockSpec((8, 128), lambda i: (0, i)),
+                        out_shape=jax.ShapeDtypeStruct((8, 512), jnp.float32),
+                        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
                     )(x)
             """},
             # dynamic everything: statically unresolvable is SILENT, not

@@ -15,9 +15,11 @@ it through the project symbol table and checks:
   its `index_map` returns: `pl.BlockSpec((br, 1), lambda j, i: (i,))`
   addresses a 2-D block with a 1-D coordinate.
 * **GL703** — kernel positional ref count != len(in_specs) +
-  len(out_specs) (after subtracting `functools.partial`-bound
-  parameters): refs and specs pair positionally, so a mismatch shifts
-  EVERY operand one slot over.
+  len(out_specs) + len(scratch_shapes) (after subtracting
+  `functools.partial`-bound parameters): refs and specs pair
+  positionally, so a mismatch shifts EVERY operand one slot over.  A
+  `*refs` kernel and a `*[...]` element of a spec list have no static
+  count and are not findings.
 * **GL704** — a `ref[...]` subscript / `pl.load` / `pl.store` inside
   the kernel indexing with more dimensions than the ref's BlockSpec
   block rank.
@@ -135,7 +137,18 @@ class PallasShapePass(LintPass):
         ][bound_pos:]
         pos_params = [p for p in pos_params if p not in bound_kw]
 
-        if in_ranks is not None and out_ranks is not None:
+        # a `*refs` kernel pairs with any number of specs, and a
+        # `*[...]` element stands for a number of specs only the run
+        # knows: the count is out of static reach, not a finding
+        if kfunc.args.vararg is not None:
+            in_ranks = None
+        scratch = self._scratch_count(kw.get("scratch_shapes"), ctx)
+        if (
+            in_ranks is not None and out_ranks is not None
+            and scratch is not None
+        ):
+            # scratch refs follow the outputs; no BlockSpec, no known rank
+            out_ranks = out_ranks + [None] * scratch
             expected = len(in_ranks) + len(out_ranks)
             if len(pos_params) != expected:
                 self.report(
@@ -156,9 +169,20 @@ class PallasShapePass(LintPass):
 
     # -- specs ----------------------------------------------------------------
 
+    def _scratch_count(self, scratch, ctx) -> Optional[int]:
+        """Number of scratch refs the kernel receives after its outputs
+        (0 without `scratch_shapes`), or None when unresolvable."""
+        if scratch is None:
+            return 0
+        elts = self._seq_elts(self._resolve_local(scratch, ctx))
+        if elts is None or any(isinstance(e, ast.Starred) for e in elts):
+            return None
+        return len(elts)
+
     def _check_specs(self, specs, grid_rank, ctx, module):
         """Returns the list of block ranks (None entries = unknown), or
-        None when the spec list itself is unresolvable."""
+        None when the spec list itself is unresolvable (a `*[...]`
+        element: its plain elements are still checked one by one)."""
         if specs is None:
             return None
         specs = self._resolve_local(specs, ctx)
@@ -169,8 +193,12 @@ class PallasShapePass(LintPass):
             else:
                 return None
         ranks: List[Optional[int]] = []
+        starred = False
         for e in elts:
             rank = None
+            if isinstance(e, ast.Starred):
+                starred = True
+                continue
             if isinstance(e, ast.Call) and _is_blockspec(
                 self.project.canonical(module, call_name(e))
             ):
@@ -210,7 +238,7 @@ class PallasShapePass(LintPass):
                             "coordinate per block dimension",
                         )
             ranks.append(rank)
-        return ranks
+        return None if starred else ranks
 
     # -- kernel resolution ----------------------------------------------------
 
