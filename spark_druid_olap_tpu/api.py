@@ -35,10 +35,12 @@ from .exec.engine import Engine
 from .models import query as Q
 from .obs import (
     SPAN_DEGRADED,
+    SPAN_ENGINE,
     SPAN_EXECUTE,
     SPAN_FALLBACK,
     SPAN_PARTIAL,
     SPAN_PLAN,
+    SPAN_POST_PROCESS,
     SPAN_ROUTE,
     SPAN_SQL_PARSE,
     Tracer,
@@ -628,7 +630,9 @@ class TPUOlapContext:
             for df, info in engine.execute_progressive(
                 q, ds, strategy=rw.physical.strategy
             ):
-                yield self._post_process(rw, ds, df), info
+                with span(SPAN_POST_PROCESS):
+                    df = self._post_process(rw, ds, df)
+                yield df, info
             self._last_engine_metrics = getattr(
                 engine, "last_metrics", None
             )
@@ -1214,7 +1218,8 @@ class TPUOlapContext:
             if not rw.grouping_sets and rw.exact_distinct is None:
                 df = self.cluster.execute(rw.query, ds)
                 self._last_engine_metrics = self.cluster.last_metrics
-                df = self._post_process(rw, ds, df)
+                with span(SPAN_POST_PROCESS):
+                    df = self._post_process(rw, ds, df)
                 if rkey is not None:
                     from .resilience import current_partial
 
@@ -1231,44 +1236,50 @@ class TPUOlapContext:
         route = {"strategy": rw.physical.strategy, "cfg": self.config}
         state = None
         fusable = self._fusable(rw, ds)
-        fused = (
-            self.serve.fused_execute(
-                rw.query, ds, engine=engine, strategy=route["strategy"]
+        # one span around whichever call into the engine serves the
+        # request: its self time is the engine's own code between the
+        # engine's spans (memo key, QueryMetrics, batching, retries)
+        backend = "mesh" if engine is self._dist_engine else "device"
+        with span(SPAN_ENGINE, backend=backend):
+            fused = (
+                self.serve.fused_execute(
+                    rw.query, ds, engine=engine, strategy=route["strategy"]
+                )
+                if fusable else None
             )
-            if fusable else None
-        )
-        if fused is not None:
-            df, state, m = fused
-            self._last_engine_metrics = m
-        elif rw.grouping_sets and isinstance(rw.query, Q.GroupByQuery):
-            df = execute_grouping_sets(
-                rw.query, rw.grouping_sets, ds, engine, **route
-            )
-            self._last_engine_metrics = getattr(
-                engine, "last_metrics", None
-            )
-        elif (
-            fusable
-            and rkey is not None
-            and self.config.result_cache_delta_reuse
-        ):
-            # capture the merged host partial state alongside the normal
-            # execution: the delta-aware result cache stores it so the
-            # NEXT append refreshes this answer by scanning only the
-            # delta (serve/result_cache.py)
-            with engine.state_capture() as cap:
+            if fused is not None:
+                df, state, m = fused
+                self._last_engine_metrics = m
+            elif rw.grouping_sets and isinstance(rw.query, Q.GroupByQuery):
+                df = execute_grouping_sets(
+                    rw.query, rw.grouping_sets, ds, engine, **route
+                )
+                self._last_engine_metrics = getattr(
+                    engine, "last_metrics", None
+                )
+            elif (
+                fusable
+                and rkey is not None
+                and self.config.result_cache_delta_reuse
+            ):
+                # capture the merged host partial state alongside the
+                # normal execution: the delta-aware result cache stores
+                # it so the NEXT append refreshes this answer by
+                # scanning only the delta (serve/result_cache.py)
+                with engine.state_capture() as cap:
+                    df = engine.execute(rw.query, ds, **route)
+                state = cap["state"]
+                self._last_engine_metrics = getattr(
+                    engine, "last_metrics", None
+                )
+            else:
                 df = engine.execute(rw.query, ds, **route)
-            state = cap["state"]
-            self._last_engine_metrics = getattr(
-                engine, "last_metrics", None
-            )
-        else:
-            df = engine.execute(rw.query, ds, **route)
-            self._last_engine_metrics = getattr(
-                engine, "last_metrics", None
-            )
+                self._last_engine_metrics = getattr(
+                    engine, "last_metrics", None
+                )
 
-        df = self._post_process(rw, ds, df)
+        with span(SPAN_POST_PROCESS):
+            df = self._post_process(rw, ds, df)
         if rkey is not None:
             from .resilience import current_partial
 

@@ -72,6 +72,7 @@ from ..obs import (
     SPAN_LOWER,
     SPAN_PROGRAM_LOOKUP,
     SPAN_ROUTE,
+    SPAN_SCOPE,
     SPAN_SEGMENT_DISPATCH,
     current_query_id,
     prof,
@@ -254,26 +255,31 @@ def segments_in_scope(q, ds: DataSource) -> List[Segment]:
     a top-level filter conjunct whose values provably fall outside a
     segment's [min, max] excludes that segment without a dispatch.
     Module-level: the distributed engine shares this exact policy for its
-    metrics scope (its shards span the full set; the row mask excludes)."""
-    segs = list(ds.segments)
-    if q.intervals:
-        out = []
-        # graftlint: disable=checkpoint-coverage -- interval pruning is O(segments) metadata arithmetic, no per-iteration work
-        for s in segs:
-            if s.interval is None:
-                out.append(s)
-                continue
-            lo, hi = s.interval
-            if any(a <= hi and lo < b for a, b in q.intervals):
-                out.append(s)
-        segs = out
-    filt = getattr(q, "filter", None)
-    if filt is not None and segs:
-        vcols = frozenset(
-            v.name for v in getattr(q, "virtual_columns", ()) or ()
-        )
-        segs = _prune_by_stats(segs, filt, ds, vcols)
-    return segs
+    metrics scope (its shards span the full set; the row mask excludes).
+    Every caller's walk is one `scope` span (the lane classifier's, the
+    `lower` span's, the partials'): the receipt counts them."""
+    with span(SPAN_SCOPE, segments=len(ds.segments)) as sp:
+        segs = list(ds.segments)
+        if q.intervals:
+            out = []
+            # graftlint: disable=checkpoint-coverage -- interval pruning is O(segments) metadata arithmetic, no per-iteration work
+            for s in segs:
+                if s.interval is None:
+                    out.append(s)
+                    continue
+                lo, hi = s.interval
+                if any(a <= hi and lo < b for a, b in q.intervals):
+                    out.append(s)
+            segs = out
+        filt = getattr(q, "filter", None)
+        if filt is not None and segs:
+            vcols = frozenset(
+                v.name for v in getattr(q, "virtual_columns", ()) or ()
+            )
+            segs = _prune_by_stats(segs, filt, ds, vcols)
+        if sp is not None:
+            sp.attrs["kept"] = len(segs)
+        return segs
 
 
 # Above this many in-scope segments a query stops unrolling them into one

@@ -53,6 +53,7 @@ from .trace import (  # noqa: F401
     SPAN_COMPACT,
     SPAN_DEGRADED,
     SPAN_DEVICE_FETCH,
+    SPAN_ENGINE,
     SPAN_EXECUTE,
     SPAN_FALLBACK,
     SPAN_FALLBACK_DECODE,
@@ -60,6 +61,7 @@ from .trace import (  # noqa: F401
     SPAN_FUSED_BATCH,
     SPAN_GATHER,
     SPAN_H2D,
+    SPAN_HTTP_ACCEPT,
     SPAN_HTTP_READ,
     SPAN_INGEST,
     SPAN_INGEST_ENCODE,
@@ -68,6 +70,7 @@ from .trace import (  # noqa: F401
     SPAN_NAMES,
     SPAN_PARTIAL,
     SPAN_PLAN,
+    SPAN_POST_PROCESS,
     SPAN_PREFETCH,
     SPAN_PROGRAM_LOOKUP,
     SPAN_QUERY,
@@ -76,6 +79,7 @@ from .trace import (  # noqa: F401
     SPAN_ROLLUP,
     SPAN_ROUTE,
     SPAN_SCATTER,
+    SPAN_SCOPE,
     SPAN_SEGMENT_DISPATCH,
     SPAN_SNAPSHOT_FLUSH,
     SPAN_SPARSE_DISPATCH,

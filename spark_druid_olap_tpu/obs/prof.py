@@ -125,6 +125,9 @@ DISPATCH_SPANS = frozenset(
         "stream_chunk",
     }
 )
+# the blocking copy back of a launch's result: where the receipt's
+# `in_flight_ms` ends
+FETCH_SPANS = frozenset({"device_fetch"})
 
 
 class ProfScope:
@@ -452,14 +455,19 @@ def _new_acc() -> Dict[str, Any]:
         # span name -> [count, exclusive ms]: the same exclusive times
         # as the buckets, kept by name (the per-layer metrics read these)
         "spans": {},
+        # ms from the root's start: where the first launch span began,
+        # where the last launch span and the last `device_fetch` ended
+        # (None: the request has none) -- the receipt's `phases`
+        "first_launch": None, "last_launch": None, "last_fetch": None,
     }
 
 
 def _walk_exclusive(node: dict, acc: Dict[str, Any], depth: int) -> None:
-    if _is_overlay(node):
+    if depth == 0 and _is_overlay(node):
         # concurrent overlay / remote clock: handled by
         # _walk_cluster_nodes into per-node attribution, never the
-        # additive local buckets (their sum could exceed the wall)
+        # additive local buckets (their sum could exceed the wall).
+        # Below the root the children are filtered before the descent.
         return
     dur = float(node.get("duration_ms", 0.0))
     children = [
@@ -473,6 +481,13 @@ def _walk_exclusive(node: dict, acc: Dict[str, Any], depth: int) -> None:
     by_name[1] += excl
     if name in DISPATCH_SPANS:
         acc["dispatch_count"] += 1
+        start = float(node.get("start_ms", 0.0))
+        if acc["first_launch"] is None or start < acc["first_launch"]:
+            acc["first_launch"] = start
+        acc["last_launch"] = max(acc["last_launch"] or 0.0, start + dur)
+    elif name in FETCH_SPANS:
+        end = float(node.get("start_ms", 0.0)) + dur
+        acc["last_fetch"] = max(acc["last_fetch"] or 0.0, end)
     if depth == 0 and name == ROOT_SPAN:
         acc["unattributed"] += excl
     elif name in DEVICE_SPANS:
@@ -587,6 +602,30 @@ def _walk_cluster_nodes(node: dict, nodes: Dict[str, Dict[str, Any]]):
         _walk_cluster_nodes(c, nodes)
 
 
+def _phases(acc: Dict[str, Any], wall: float) -> Dict[str, float]:
+    """The request's wall by where device work was in flight, on the
+    tree's clock: root start -> the first launch span's start -> the
+    last `device_fetch`'s end (the last launch span's where nothing was
+    fetched) -> root end.  The three add up to `wall`; a request that
+    launched nothing is all `pre_launch_ms`.  `in_flight_ms` overstates
+    the device by the launch's own host time before its first operation
+    and the copy back after its last."""
+    first = acc["first_launch"]
+    if first is None:
+        return {"pre_launch_ms": round(wall, 3), "in_flight_ms": 0.0,
+                "post_fetch_ms": 0.0}
+    first = min(first, wall)
+    last = acc["last_fetch"]
+    if last is None or last < first:
+        last = acc["last_launch"]
+    last = min(max(last, first), wall)
+    return {
+        "pre_launch_ms": round(first, 3),
+        "in_flight_ms": round(last - first, 3),
+        "post_fetch_ms": round(wall - last, 3),
+    }
+
+
 def build_receipt(
     trace_doc: dict, scope: Optional[ProfScope] = None
 ) -> dict:
@@ -598,7 +637,10 @@ def build_receipt(
     root = trace_doc.get("spans")
     if isinstance(root, dict):
         _walk_exclusive(root, acc, 0)
-        _walk_cluster_nodes(root, cluster_nodes)
+        if not SCATTER_SPANS.isdisjoint(acc["spans"]):
+            # only a broker's tree has per-historical buckets to fold:
+            # the second walk is not every request's to pay at close
+            _walk_cluster_nodes(root, cluster_nodes)
     wall = float(trace_doc.get("total_ms") or 0.0)
     # overlap efficiency (ROADMAP direction 4's success metric):
     # device-busy time over (device-busy + transfer-stall).  Stall is the
@@ -626,6 +668,7 @@ def build_receipt(
             name: {"n": n, "self_ms": round(ms, 3)}
             for name, (n, ms) in acc["spans"].items()
         },
+        "phases": _phases(acc, wall),
         "overlap_efficiency": (
             round(acc["device"] / busy_stall, 4) if busy_stall > 0 else 1.0
         ),

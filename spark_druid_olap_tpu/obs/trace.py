@@ -8,9 +8,15 @@ cannot answer "which concurrent query retried?" or "where did this
 deadline die?"; this module can:
 
   * **Span tree per query** — a `QueryTrace` rooted at a `query` span,
-    with children for every lifecycle phase (`admission → plan → lower →
-    h2d → segment_dispatch → device_fetch → finalize`, plus
-    `fallback`/`retry`/`degraded` when a query leaves the happy path).  Span names are DRAWN FROM the `SPAN_*` constant
+    with children for every lifecycle phase of a served request
+    (`http_accept → http_read → lane → admission → plan → execute →
+    [scope, engine → lower → h2d → segment_dispatch → device_fetch →
+    finalize, post_process] → respond`, plus
+    `fallback`/`retry`/`degraded` when a query leaves the happy path;
+    `scope` is one walk of the segments' intervals and zone maps,
+    wherever it is made: under the root by the lane classifier, under
+    `lower`, under `engine` on the way to the partials).  Span names
+    are DRAWN FROM the `SPAN_*` constant
     registry below — the span-discipline lint pass (GL11xx) rejects
     ad-hoc strings so the taxonomy cannot fragment.
   * **query_id end-to-end** — generated at the server boundary (honoring
@@ -49,7 +55,7 @@ import threading
 import time
 import uuid
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from ..utils.log import get_logger
 
@@ -101,6 +107,10 @@ SPAN_ROUTE = "route"  # the cost model's choice of backend, tier and kernel
 SPAN_PROGRAM_LOOKUP = "program_lookup"  # program-cache lookup / jitted-fn build
 SPAN_ADAPTIVE_KEPT = "adaptive_kept"  # adaptive: kept-set memo, derive, nonzero
 SPAN_RESPOND = "respond"  # server: result frame -> buffered response bytes
+SPAN_SCOPE = "scope"  # one walk of the segments' intervals and zone maps
+SPAN_ENGINE = "engine"  # api/server: one call into an engine
+SPAN_POST_PROCESS = "post_process"  # api: host-side shaping of the frame
+SPAN_HTTP_ACCEPT = "http_accept"  # server: accept() -> do_POST's first line
 
 SPAN_NAMES = frozenset(
     {
@@ -143,6 +153,10 @@ SPAN_NAMES = frozenset(
         SPAN_PROGRAM_LOOKUP,
         SPAN_ADAPTIVE_KEPT,
         SPAN_RESPOND,
+        SPAN_SCOPE,
+        SPAN_ENGINE,
+        SPAN_POST_PROCESS,
+        SPAN_HTTP_ACCEPT,
     }
 )
 
@@ -354,12 +368,14 @@ class QueryTrace:
         with self._lock:
             s.grafts.append(subtree)
 
-    def adopt_early(self, early: Span) -> None:
-        """Put a span that closed before this trace opened (the server's
-        `http_read`) first under the root, and start the root with it."""
+    def adopt_early(self, early: Sequence[Span]) -> None:
+        """Put the spans that closed before this trace opened first under
+        the root, in the order given, which is the order in time (the
+        server's `http_accept`, then its `http_read`), and start the
+        root with the first of them."""
         with self._lock:
-            self.root.start = min(self.root.start, early.start)
-            self.root.children.insert(0, early)
+            self.root.start = min(self.root.start, *(s.start for s in early))
+            self.root.children[0:0] = early
 
     def stamp_receipt_on(self, metrics=None, frame=None) -> None:
         """Register who gets the closed trace's receipt: a
@@ -471,24 +487,55 @@ def current_span() -> Optional[Span]:
     return _active_span.get()
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
+class span:
     """Open a child span of the active trace; a no-op (one contextvar
     read) when no trace is active.  THE way instrumented code creates
     spans — every early return / raise path closes the span because the
-    context manager owns the pairing (span-discipline/GL1102)."""
-    tr = _active_trace.get()
-    if tr is None:
-        yield None
-        return
-    s = tr.start_span(name, _active_span.get(), attrs or None)
-    token = _active_span.set(s)
-    try:
-        with _mirror(name, tr.query_id):
-            yield s
-    finally:
-        _active_span.reset(token)
-        tr.end_span(s)
+    context manager owns the pairing (span-discipline/GL1102).  `with
+    span(NAME, **attrs) as s:` yields the open `Span`, or None without a
+    trace.  A class with `__enter__`/`__exit__` and not a generator
+    under `contextlib.contextmanager`: the same pairing at two thirds of
+    the cost a span, which every request pays some twenty times
+    (ISSUE 37)."""
+
+    __slots__ = ("_name", "_attrs", "_trace", "_span", "_token", "_mirror")
+
+    def __init__(self, name: str, **attrs):
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self) -> Optional[Span]:
+        tr = self._trace = _active_trace.get()
+        if tr is None:
+            return None
+        s = self._span = tr.start_span(
+            self._name, _active_span.get(), self._attrs or None
+        )
+        self._token = _active_span.set(s)
+        self._mirror = None
+        mirror = _mirror(self._name, tr.query_id)
+        if mirror is not _NO_SESSION:
+            try:
+                mirror.__enter__()
+            except BaseException:
+                self._close()
+                raise
+            self._mirror = mirror
+        return s
+
+    def __exit__(self, *exc) -> bool:
+        if self._trace is None:
+            return False
+        try:
+            if self._mirror is not None:
+                self._mirror.__exit__(*exc)
+        finally:
+            self._close()
+        return False
+
+    def _close(self) -> None:
+        _active_span.reset(self._token)
+        self._trace.end_span(self._span)
 
 
 @contextlib.contextmanager
@@ -618,7 +665,7 @@ class Tracer:
         self.sampler.force_next()
 
     @contextlib.contextmanager
-    def early_span(self, name: str, **attrs):
+    def early_span(self, name: str, start: Optional[float] = None, **attrs):
         """A span of work that has to happen BEFORE its trace can open:
         the server reads and decodes the request body to learn the query
         id the trace is opened under.  Yields a detached `Span` on this
@@ -626,10 +673,13 @@ class Tracer:
         root starts where the request did and the read is a child like
         any other phase.  Its profiler mirror carries no query id and
         precedes its root's (see "The profiler mirror" above).
+        `start` is an earlier reading of this tracer's clock (the
+        server's stamp at `accept()`): the span is back-dated to it and
+        has no mirror, since an annotation cannot be.
         Same pairing contract as `span(...)` (span-discipline/GL1102)."""
-        s = Span(name, self.clock(), attrs or None)
+        s = Span(name, self.clock() if start is None else start, attrs or None)
         try:
-            with _mirror(name, ""):
+            with _mirror(name, "") if start is None else _NO_SESSION:
                 yield s
         finally:
             s.end = self.clock()
@@ -641,14 +691,18 @@ class Tracer:
         query_type: str = "",
         slow_ms: float = 0.0,
         parent_span_id: str = "",
-        early: Optional[Span] = None,
+        early: Sequence[Span] = (),
     ):
         """Open (or join) the per-query trace.  The OUTERMOST scope wins,
         exactly like `resilience.deadline_scope`: the server boundary
         starts the trace and `ctx.sql` inside it joins rather than
         nesting a second root.  `parent_span_id` stamps cross-process
         parentage (a historical trace opened under a broker RPC span);
-        `early` is a closed `early_span` the new root adopts."""
+        `early` are the closed `early_span`s the new root adopts, in
+        their order in time.  The receipt's `close_ms` is this method's
+        own `finally`: what the tracer costs a request after its root
+        has ended and before the caller goes on (the server writes the
+        buffered answer only then)."""
         existing = _active_trace.get()
         if existing is not None:
             yield existing
@@ -661,7 +715,7 @@ class Tracer:
         )
         if parent_span_id:
             tr.parent_span_id = str(parent_span_id)
-        if early is not None:
+        if early:
             tr.adopt_early(early)
         tok_t = _active_trace.set(tr)
         tok_s = _active_span.set(tr.root)
@@ -674,6 +728,7 @@ class Tracer:
             _active_span.reset(tok_s)
             _active_trace.reset(tok_t)
             tr.finish()
+            t_close = self.clock()  # `wall_ms` ended; `close_ms` begins
             self.last = tr
             doc = tr.to_dict()
             # per-query cost receipt (ISSUE 9): fold the finished span
@@ -705,6 +760,12 @@ class Tracer:
                 log.warning(
                     "slow query %s: %.1fms >= %.0fms threshold\n%s",
                     tr.query_id, tr.total_ms, slow_ms, tr.render(),
+                )
+            if tr.receipt is not None:
+                # written into the dict the sinks, the ring's doc and the
+                # profiler's window already hold
+                tr.receipt["close_ms"] = round(
+                    (self.clock() - t_close) * 1e3, 3
                 )
 
     def last_trace_dict(self) -> Optional[dict]:

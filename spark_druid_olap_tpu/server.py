@@ -45,6 +45,8 @@ from .models.filters import _ms_to_iso
 from .models.wire import WireError, query_from_druid
 from .obs import (
     SPAN_ADMISSION,
+    SPAN_ENGINE,
+    SPAN_HTTP_ACCEPT,
     SPAN_HTTP_READ,
     SPAN_LANE,
     SPAN_RESPOND,
@@ -184,6 +186,10 @@ def druid_result_shape(q: Q.QuerySpec, df) -> Any:
     return _rows(df)
 
 
+def _tracer_of(ctx):
+    return getattr(ctx, "tracer", None) or default_tracer()
+
+
 class _Handler(BaseHTTPRequestHandler):
     # chunked transfer-encoding (the progressive streaming path) is only
     # defined for HTTP/1.1 — the stdlib default of HTTP/1.0 would make
@@ -201,8 +207,24 @@ class _Handler(BaseHTTPRequestHandler):
     # after the trace publishes to the ring
     _defer_buffered = False
     _buffered_response: Optional[tuple] = None
+    # the tracer's clock at `accept()` (see _OlapHTTPServer): the start
+    # of the connection's FIRST request's `http_accept` span; None for
+    # every later request of a kept-alive connection
+    _accepted_at: Optional[float] = None
 
     # -- plumbing ------------------------------------------------------------
+
+    def setup(self):
+        super().setup()
+        self._accepted_at = self.server.accepted_at.pop(self.request, None)
+
+    def handle_one_request(self):
+        try:
+            super().handle_one_request()
+        finally:
+            # whatever the first request was (a GET reads no stamp), the
+            # next one on this connection did not begin at `accept()`
+            self._accepted_at = None
 
     def log_message(self, fmt, *args):
         # library etiquette: no stderr spray; stdlib-internal messages
@@ -343,7 +365,7 @@ class _Handler(BaseHTTPRequestHandler):
         return getattr(self.ctx, "resilience", None)
 
     def _tracer(self):
-        return getattr(self.ctx, "tracer", None) or default_tracer()
+        return _tracer_of(self.ctx)
 
     def do_GET(self):
         import time as _time
@@ -503,11 +525,22 @@ class _Handler(BaseHTTPRequestHandler):
         # from the previous query must never echo on this response
         self._query_id = None
         self._req_t0 = _time.perf_counter()
+        tracer = self._tracer()
+        early = []
+        if self._accepted_at is not None:
+            # thread start, request line and header parse: from the
+            # server's stamp at `accept()` to here, made after the fact
+            with tracer.early_span(
+                SPAN_HTTP_ACCEPT, start=self._accepted_at
+            ) as accepted:
+                pass
+            early.append(accepted)
         # the body holds the id the trace opens under, so it is read
         # before there is a trace: as an early span the query's root
-        # adopts below, which makes the root start HERE
-        with self._tracer().early_span(SPAN_HTTP_READ) as read:
+        # adopts below, which makes the root start with the first of them
+        with tracer.early_span(SPAN_HTTP_READ) as read:
             body = self._body()
+        early.append(read)
         path = self.path.split("?")[0].rstrip("/")
         if body is None:
             return self._error(
@@ -536,11 +569,11 @@ class _Handler(BaseHTTPRequestHandler):
         self._buffered_response = None
         self._defer_buffered = True
         try:
-            with self._tracer().query_trace(
+            with tracer.query_trace(
                 query_id=self._query_id,
                 query_type="native" if path == "/druid/v2" else "sql",
                 slow_ms=cfg.slow_query_ms if cfg else 0.0,
-                early=read,
+                early=early,
             ):
                 return self._handle_query(path, body, qctx, res, cfg)
         finally:
@@ -1062,10 +1095,11 @@ class _Handler(BaseHTTPRequestHandler):
                 # the full set
                 from .api import execute_grouping_sets
 
-                df = execute_grouping_sets(
-                    dataclasses.replace(q, subtotals=()), q.subtotals, ds,
-                    self.ctx.engine,
-                )
+                with span(SPAN_ENGINE, backend="device"):
+                    df = execute_grouping_sets(
+                        dataclasses.replace(q, subtotals=()), q.subtotals,
+                        ds, self.ctx.engine,
+                    )
                 # internal bitmask column; real Druid events don't carry it
                 return df.drop(columns=["__grouping_id"])
             # the serving core's native path (serve/): result cache
@@ -1073,7 +1107,8 @@ class _Handler(BaseHTTPRequestHandler):
             # append) -> micro-batch fusion -> serial state-capturing
             # execution, with the computed answer published back
             if serve is None:
-                return self.ctx.engine.execute(q, ds)
+                with span(SPAN_ENGINE, backend="device"):
+                    return self.ctx.engine.execute(q, ds)
             # ONE key computation per request (it JSON-serializes the
             # spec), shared by lookup and store
             rkey = serve.native_key(q, ds)
@@ -1100,7 +1135,8 @@ class _Handler(BaseHTTPRequestHandler):
                 return df
             fusable = self.ctx.engine.fusable(q, ds)
             if fusable:
-                fused = serve.fused_execute(q, ds)
+                with span(SPAN_ENGINE, backend="device"):
+                    fused = serve.fused_execute(q, ds)
                 if fused is not None:
                     df, state, m = fused
                     self.ctx._last_engine_metrics = m
@@ -1110,7 +1146,8 @@ class _Handler(BaseHTTPRequestHandler):
                 # capture the merged host state alongside the serial
                 # execution so the next append refreshes this entry by
                 # scanning only the delta
-                with self.ctx.engine.state_capture() as cap:
+                with span(SPAN_ENGINE, backend="device"), \
+                        self.ctx.engine.state_capture() as cap:
                     df = self.ctx.engine.execute(q, ds)
                 # stamp the context's most-recent metrics: an earlier
                 # cache hit left its own object pinned there, and
@@ -1121,7 +1158,8 @@ class _Handler(BaseHTTPRequestHandler):
                 )
                 serve.store_native(q, ds, df, state=cap["state"], key=rkey)
                 return df
-            df = self.ctx.engine.execute(q, ds)
+            with span(SPAN_ENGINE, backend="device"):
+                df = self.ctx.engine.execute(q, ds)
             self.ctx._last_engine_metrics = self.ctx.engine.last_metrics
             if rkey is not None:
                 # non-fusable GroupBy-family shapes (sparse/adaptive
@@ -1322,6 +1360,25 @@ class _OlapHTTPServer(ThreadingHTTPServer):
     # a full second of invisible latency the handler never sees.  128
     # accommodates hammer-scale connection bursts.
     request_queue_size = 128
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        # connection -> the tracer's clock when it was accepted, from
+        # `get_request` (the serving thread) to the handler's `setup`
+        # (the connection's own thread), which takes it out again
+        self.accepted_at: dict = {}
+
+    def get_request(self):
+        request, client_address = super().get_request()
+        self.accepted_at[request] = _tracer_of(
+            self.RequestHandlerClass.ctx
+        ).clock()
+        return request, client_address
+
+    def shutdown_request(self, request):
+        # a connection refused before its handler ran leaves its stamp
+        self.accepted_at.pop(request, None)
+        super().shutdown_request(request)
 
 
 class OlapServer:

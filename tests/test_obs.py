@@ -73,10 +73,30 @@ def test_span_tree_exact_under_injected_clock():
     assert d["total_ms"] == root["duration_ms"]
 
 
-def test_span_outside_trace_is_noop():
+def test_span_outside_trace_is_noop(monkeypatch, ssb_ds):
     with span(SPAN_PLAN) as s:
         assert s is None
     assert current_query_id() == ""
+    # the cost without a trace is ONE contextvar read (there is no
+    # tracer, so no clock to read), also for a span that lives inside a
+    # shared function: the `scope`
+    # span of `segments_in_scope` (ISSUE 37), called here as a direct
+    # Engine user would
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+    from spark_druid_olap_tpu.models import query as Q
+    from spark_druid_olap_tpu.obs import trace as trace_mod
+
+    class CountingVar:
+        reads = 0
+
+        def get(self):
+            CountingVar.reads += 1
+            return None
+
+    monkeypatch.setattr(trace_mod, "_active_trace", CountingVar())
+    q = Q.GroupByQuery(datasource="ssb", dimensions=(), aggregations=())
+    assert segments_in_scope(q, ssb_ds) == list(ssb_ds.segments)
+    assert CountingVar.reads == 1
 
 
 def test_query_trace_outermost_wins():
@@ -251,7 +271,12 @@ def test_tracer_overhead_on_cached_ssb_query_counted_not_timed():
     n_spans = sum(
         v["n"] for v in d["receipt"]["spans"].values()
     )
-    assert clk.calls == 2 * n_spans, (clk.calls, d["receipt"]["spans"])
+    # ISSUE 37: and two for the tracer's own close (`close_ms`), which
+    # under the frozen clock reads 0: it is counted on the injected
+    # clock like every span, never in wall time
+    assert clk.calls == 2 * n_spans + 2, (clk.calls, d["receipt"]["spans"])
+    assert d["receipt"]["close_ms"] == 0.0
+    assert {"scope", "engine", "post_process"} <= set(d["receipt"]["spans"])
 
 
 def test_engine_publishes_into_process_registry():
@@ -653,7 +678,7 @@ def test_early_span_is_adopted_and_back_dates_the_root():
     with tracer.early_span(SPAN_HTTP_READ) as read:  # ticks 0, 1
         pass
     clk()  # a tick between the read and the trace's opening
-    with tracer.query_trace(query_id="q-early", early=read) as tr:
+    with tracer.query_trace(query_id="q-early", early=[read]) as tr:
         with span(SPAN_PLAN):
             pass
     d = tr.to_dict()
@@ -666,6 +691,41 @@ def test_early_span_is_adopted_and_back_dates_the_root():
     spans = tr.receipt["spans"]
     assert sum(v["self_ms"] for v in spans.values()) == 6_000.0
     assert spans["http_read"] == {"n": 1, "self_ms": 1_000.0}
+
+
+def test_two_early_spans_are_adopted_in_their_order_in_time():
+    """The server's pair (ISSUE 37): `http_accept`, made after the fact
+    from a stamp of the tracer's clock, then `http_read`; the root adopts
+    them in the order given and starts with the first."""
+    from spark_druid_olap_tpu.obs import SPAN_HTTP_ACCEPT, SPAN_HTTP_READ
+
+    clk = TickClock(step=1.0)
+    tracer = Tracer(clock=clk)
+    stamp = clk()  # tick 0: the server's `get_request`
+    clk()  # tick 1: thread start, header parse
+    with tracer.early_span(SPAN_HTTP_ACCEPT, start=stamp) as accepted:
+        pass  # ends at tick 2, and read no clock to start
+    with tracer.early_span(SPAN_HTTP_READ) as read:  # ticks 3, 4
+        pass
+    with tracer.query_trace(
+        query_id="q-two", early=[accepted, read]
+    ) as tr:  # root's own start: tick 5, back-dated to 0
+        with span(SPAN_PLAN):  # 6, 7
+            pass
+    root = tr.to_dict()["spans"]  # root end: tick 8
+    assert [(c["name"], c["start_ms"], c["duration_ms"])
+            for c in root["children"]] == [
+        ("http_accept", 0.0, 2_000.0), ("http_read", 3_000.0, 1_000.0),
+        ("plan", 6_000.0, 1_000.0),
+    ]
+    assert tr.total_ms == 8_000.0
+    spans = tr.receipt["spans"]
+    assert sum(v["self_ms"] for v in spans.values()) == 8_000.0
+    assert spans["http_accept"] == {"n": 1, "self_ms": 2_000.0}
+    # nothing launched: the whole wall lies before any device work
+    assert tr.receipt["phases"] == {
+        "pre_launch_ms": 8_000.0, "in_flight_ms": 0.0, "post_fetch_ms": 0.0,
+    }
 
 
 def test_receipt_is_built_once_and_is_the_closed_traces(monkeypatch):
@@ -938,7 +998,7 @@ def test_spans_mirror_into_a_profiler_session(tmp_path):
         with jax.profiler.TraceAnnotation("request:unit"):
             with tracer.early_span(SPAN_HTTP_READ) as read:
                 pass
-            with tracer.query_trace(query_id="q-mirror", early=read):
+            with tracer.query_trace(query_id="q-mirror", early=[read]):
                 with span(SPAN_PLAN):
                     pass
                 with span(SPAN_EXECUTE):

@@ -169,8 +169,10 @@ def test_trace_endpoint_returns_span_tree_with_phase_sums(srv):
     assert phase_sum <= total * 1.01 + 0.5
     assert phase_sum >= total * 0.5
     # the execute phase contains the engine spans
+    # (under `engine`, the span around the call into it: ISSUE 37)
     execute = next(c for c in root["children"] if c["name"] == "execute")
-    inner = {c["name"] for c in execute.get("children", ())}
+    engine = next(c for c in execute["children"] if c["name"] == "engine")
+    inner = {c["name"] for c in engine.get("children", ())}
     assert "segment_dispatch" in inner or "lower" in inner
 
 
@@ -368,9 +370,9 @@ def test_served_request_tree_covers_front_end_to_response(srv):
     assert {"http_read", "sql_parse", "route", "program_lookup",
             "respond", "lane", "admission", "plan", "execute"} <= set(names)
     top = [c["name"] for c in root["children"]]
-    # the body read comes first and starts the root; the answer is
-    # encoded last, inside the tree
-    assert top[0] == "http_read" and top[-1] == "respond"
+    # `http_accept` (this connection's first request) starts the root,
+    # the body read follows; the answer is encoded last, inside the tree
+    assert top[:2] == ["http_accept", "http_read"] and top[-1] == "respond"
     assert root["children"][0]["start_ms"] == 0.0
     plans = [c for c in root["children"] if c["name"] == "plan"]
     assert [p["attrs"]["cache_hit"] for p in plans] == [False, True]
@@ -406,5 +408,6 @@ def test_native_request_tree_has_read_and_respond(srv):
     assert code == 200
     root = _get_trace(server.port, "tree-native")["spans"]
     top = [c["name"] for c in root["children"]]
-    assert top[0] == "http_read" and top[-1] == "respond"
-    assert "program_lookup" in [s["name"] for s in _spans(root)]
+    assert top[:2] == ["http_accept", "http_read"] and top[-1] == "respond"
+    names = [s["name"] for s in _spans(root)]
+    assert "program_lookup" in names and "engine" in names
