@@ -510,14 +510,14 @@ class AdaptiveDomainMixin:
 
     def _dispatch_groupby_adaptive(
         self, q: Q.GroupByQuery, ds: DataSource, lowering: GroupByLowering,
-        cfg,
+        segs, cfg,
     ):
         """Adaptive-compaction attempt.  Returns None when declining at
         dispatch time (no shrink to be had; caller falls through to the
         sparse/scatter paths in the same phase), else resolve() -> df.
-        A device error in either phase raises.  `cfg`: the cost
-        constants phase B's kernel is chosen by."""
-        segs = self._segments_in_scope(q, ds)
+        A device error in either phase raises.  `segs`: the query's
+        scope as `_dispatch_groupby_once` resolved it, for both phases;
+        `cfg`: the cost constants phase B's kernel is chosen by."""
         if not segs:
             return None
         try:
@@ -572,7 +572,7 @@ class AdaptiveDomainMixin:
                 sp.attrs.update(kernel=strat, groups=clow.num_groups)
         state = self._partials_for_query(
             q, ds, lowering=clow, key_extra=("adaptive",) + cards,
-            strategy_override=strat, span_attrs={"phase": "B"},
+            strategy_override=strat, segs=segs, span_attrs={"phase": "B"},
         )
 
         def resolve():

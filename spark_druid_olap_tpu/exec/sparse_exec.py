@@ -95,11 +95,13 @@ class SparseExecMixin:
         return seg_fn
 
     def _dispatch_groupby_sparse(
-        self, q: Q.GroupByQuery, ds: DataSource, lowering: "GroupByLowering"
+        self, q: Q.GroupByQuery, ds: DataSource, lowering: "GroupByLowering",
+        segs,
     ):
-        """Sparse execution attempt over the (non-empty) segment scope,
-        split into an eager dispatch phase and a deferred fetch so N queries
-        (a grouping-set expansion) can overlap their device round trips.
+        """Sparse execution attempt over the (non-empty) segment scope
+        `segs`, as `_dispatch_groupby_once` resolved it, split into an
+        eager dispatch phase and a deferred fetch so N queries (a
+        grouping-set expansion) can overlap their device round trips.
 
         Dispatches the tier-1 program asynchronously and returns
         `resolve() -> (df, reason)`: df is None when declining, with reason
@@ -110,7 +112,6 @@ class SparseExecMixin:
         the engine's retry machinery like any other device error."""
         from ..ops.sparse_groupby import merge_sparse_states
 
-        segs = self._segments_in_scope(q, ds)
         G = lowering.num_groups
         # The selective-filter fast path only makes sense when rows can
         # actually be masked out (a filter or time intervals); an unfiltered

@@ -13,9 +13,10 @@ deadline die?"; this module can:
     [scope, engine → lower → h2d → segment_dispatch → device_fetch →
     finalize, post_process] → respond`, plus
     `fallback`/`retry`/`degraded` when a query leaves the happy path;
-    `scope` is one walk of the segments' intervals and zone maps,
-    wherever it is made: under the root by the lane classifier, under
-    `lower`, under `engine` on the way to the partials).  Span names
+    `scope` is the request's one walk of the segments' intervals and
+    zone maps, made for whoever asks first: the lane classifier under
+    the root, else `lower`; `QueryTrace.scopes` answers the later asks
+    and the span's `asks` counts them, ISSUE 38).  Span names
     are DRAWN FROM the `SPAN_*` constant
     registry below — the span-discipline lint pass (GL11xx) rejects
     ad-hoc strings so the taxonomy cannot fragment.
@@ -107,7 +108,7 @@ SPAN_ROUTE = "route"  # the cost model's choice of backend, tier and kernel
 SPAN_PROGRAM_LOOKUP = "program_lookup"  # program-cache lookup / jitted-fn build
 SPAN_ADAPTIVE_KEPT = "adaptive_kept"  # adaptive: kept-set memo, derive, nonzero
 SPAN_RESPOND = "respond"  # server: result frame -> buffered response bytes
-SPAN_SCOPE = "scope"  # one walk of the segments' intervals and zone maps
+SPAN_SCOPE = "scope"  # the request's walk of the segments' intervals and zone maps
 SPAN_ENGINE = "engine"  # api/server: one call into an engine
 SPAN_POST_PROCESS = "post_process"  # api: host-side shaping of the frame
 SPAN_HTTP_ACCEPT = "http_accept"  # server: accept() -> do_POST's first line
@@ -338,6 +339,12 @@ class QueryTrace:
         # "receipt" key), registered by `stamp_receipt_on` while the
         # query runs and stamped once, at close
         self._receipt_sinks: List[Any] = []
+        # the segment scopes this request has resolved, one entry a walk
+        # (exec/engine.py `segments_in_scope` writes and reads them): a
+        # request walks the zone maps once and every later ask of the
+        # same filter over the same `DataSource` is answered from here
+        # (ISSUE 38).  Emptied at `finish`: nothing outlives the request
+        self.scopes: List[tuple] = []
 
     def start_span(
         self, name: str, parent: Optional[Span], attrs: Optional[dict] = None
@@ -400,6 +407,7 @@ class QueryTrace:
         with self._lock:
             if self.root.end is None:
                 self.root.end = self._clock()
+        self.scopes.clear()
 
     @property
     def total_ms(self) -> float:

@@ -309,9 +309,11 @@ class DistributedEngine:
 
     def _scope_for_metrics(self, q, ds: DataSource):
         """Interval + zone-map pruned segment scope — shared with the
-        local engine's exact pruning policy.  Both the metrics AND the
-        shard layout read it: `_place_shards` assembles only the pruned
-        scope (the row mask still excludes within surviving segments)."""
+        local engine's exact pruning policy.  `_execute_groupby_once`
+        resolves it once and hands it down: the metrics, the arena's
+        window and the shard layout all read that one list
+        (`_place_shards` assembles only the pruned scope; the row mask
+        still excludes within surviving segments)."""
         from ..exec.engine import segments_in_scope
 
         return segments_in_scope(q, ds)
@@ -704,8 +706,9 @@ class DistributedEngine:
             num_groups=lowering.num_groups,
             shards=self._row_device_count(),
         )
-        # metrics scope: what pruning WOULD scan (parity with the local
-        # engine's numbers); shards themselves always span the full set
+        # the scope, resolved once and handed to every tier below: the
+        # metrics read what pruning scans (parity with the local engine's
+        # numbers), the tiers place and step through the same list
         from ..exec.engine import _bytes_scanned
 
         scope = self._scope_for_metrics(q, ds)
@@ -716,12 +719,14 @@ class DistributedEngine:
         out = None
         try:
             if strategy == "adaptive":
-                out = self._execute_adaptive(q, ds, lowering, qkey, m, cfg)
+                out = self._execute_adaptive(
+                    q, ds, lowering, qkey, m, cfg, scope
+                )
                 if out is None:  # declined: re-route without adaptive
                     strategy = self._route(q, ds, lowering, qkey, handed, cfg)
                     m.strategy = strategy
             if out is None and strategy == "sparse":
-                out = self._execute_sparse(q, ds, lowering, qkey, m)
+                out = self._execute_sparse(q, ds, lowering, qkey, m, scope)
                 if out is None:  # ladder exhausted: dense-state scatter
                     strategy = "segment"
                     m.strategy = strategy
@@ -732,9 +737,13 @@ class DistributedEngine:
                 # program with scope as data.  None => ineligible (layout
                 # declined / sketch aggs / groups axis) — fall through to
                 # the legacy dense-state path unchanged.
-                out = self._execute_arena_spmd(q, ds, lowering, m, strategy)
+                out = self._execute_arena_spmd(
+                    q, ds, lowering, m, strategy, scope
+                )
             if out is None:
-                out = self._execute_dense_state(q, ds, lowering, m, strategy)
+                out = self._execute_dense_state(
+                    q, ds, lowering, m, strategy, scope
+                )
         except BaseException as err:
             # failed executions must reach the process registry too: a
             # dashboard's outcome="error" rate would otherwise show zero
@@ -758,16 +767,15 @@ class DistributedEngine:
         log.info("%s", m.describe())
         return out
 
-    def _place_shards(self, ds, columns, m, q=None):
-        """Place (or reuse) the sharded column set for `q`'s pruned scope
-        — `q=None` spans the full set (scope-free callers only)."""
+    def _place_shards(self, ds, columns, m, segs=None):
+        """Place (or reuse) the sharded column set for the pruned scope
+        `segs` — None spans the full set (scope-free callers only)."""
         from ..resilience import fire
 
         fire("h2d")  # fault-injection site: shard placement
         t0 = _time.perf_counter()
         known = len(self._shard_cache)
         before_bytes = self._shard_cache.bytes_used
-        segs = self._scope_for_metrics(q, ds) if q is not None else None
         cols, padded = self._global_columns(ds, columns, segs=segs)
         # the scope's rows lie end to end, cut evenly: every shard runs
         # its cut of them, counted in (padded) segments' worth of rows
@@ -797,7 +805,8 @@ class DistributedEngine:
         return cols, padded
 
     def _execute_dense_state(
-        self, q, ds, lowering, m, strategy, key_extra=(), span_attrs=None
+        self, q, ds, lowering, m, strategy, scope, key_extra=(),
+        span_attrs=None,
     ):
         """The dense-[Gl, M]-state path (dense / Pallas / scatter kernels
         share it — only the per-shard kernel differs).  One launch span,
@@ -806,7 +815,7 @@ class DistributedEngine:
             allgather_factor, allreduce_factor, groupby_state_bytes,
         )
 
-        cols, padded = self._place_shards(ds, lowering.columns, m, q=q)
+        cols, padded = self._place_shards(ds, lowering.columns, m, scope)
         local_rows = padded // self.mesh.shape[DATA_AXIS]
         compiled = self._spmd_cache
         key_count = len(compiled)
@@ -894,7 +903,7 @@ class DistributedEngine:
             (c for c in _sg.ROW_CAPACITY_LADDER if c >= need), None
         )
 
-    def _execute_sparse(self, q, ds, lowering, qkey, m):
+    def _execute_sparse(self, q, ds, lowering, qkey, m, scope):
         """Sparse sort-compaction over the mesh with the full rung ladder
         (row capacity + slots).  Returns None when the slots ladder is
         exhausted by an exact count — the caller falls back to the
@@ -908,7 +917,7 @@ class DistributedEngine:
             # strategy="sparse" on such a query falls through to scatter
             self._sparse_declined.add(qkey)
             return None
-        cols, padded = self._place_shards(ds, lowering.columns, m, q=q)
+        cols, padded = self._place_shards(ds, lowering.columns, m, scope)
         local_rows = padded // self.mesh.shape[DATA_AXIS]
         cap = self._initial_row_capacity(q, ds, lowering, qkey, local_rows)
         slots = self._sparse_slots.get(qkey, _sg.SPARSE_SLOTS)
@@ -1004,7 +1013,7 @@ class DistributedEngine:
 
     # -- adaptive tier -------------------------------------------------------
 
-    def _execute_adaptive(self, q, ds, lowering, qkey, m, cfg):
+    def _execute_adaptive(self, q, ds, lowering, qkey, m, cfg, scope):
         """Adaptive dictionary-domain compaction as a distributed phase A
         (presence counts psum-merged over the data axis) + the normal SPMD
         program over the compacted lowering (phase B).  Returns None when
@@ -1042,7 +1051,9 @@ class DistributedEngine:
                 if kept is not None:
                     self._adaptive_kept[qkey] = ("derived", kept)
             if kept is None:
-                kept, source = self._measure_kept(q, ds, lowering, m), "measured"
+                kept, source = (
+                    self._measure_kept(q, ds, lowering, m, scope), "measured"
+                )
                 self._adaptive_kept[qkey] = ("measured", seg_sig, kept)
             Gc = 1
             for kd in kept:
@@ -1090,11 +1101,11 @@ class DistributedEngine:
                 sp.attrs.update(kernel=strat, groups=groups)
         m.num_groups = clow.num_groups
         return self._execute_dense_state(
-            q, ds, clow, m, strat, key_extra=("adaptive",) + cards,
+            q, ds, clow, m, strat, scope, key_extra=("adaptive",) + cards,
             span_attrs={"phase": "B"},
         )
 
-    def _measure_kept(self, q, ds, lowering, m) -> List[np.ndarray]:
+    def _measure_kept(self, q, ds, lowering, m, scope) -> List[np.ndarray]:
         """Adaptive phase A on the mesh: one presence program over the
         scope's shards, its per-dim counts psum-merged, then the codes
         seen.  A failure of the pass raises (transient ones into
@@ -1106,7 +1117,7 @@ class DistributedEngine:
         # phase A reads only mask + dim-code columns (the shared helper
         # keeps the physical time column when intervals need it)
         need = presence_columns(q, lowering, ds)
-        cols, padded = self._place_shards(ds, need, m, q=q)
+        cols, padded = self._place_shards(ds, need, m, scope)
         nd = self.mesh.shape[DATA_AXIS]
         run = self._presence_fn(
             lowering, padded // nd, ds, tuple(cols.keys())
@@ -1333,7 +1344,7 @@ class DistributedEngine:
             else 1
         )
 
-    def _execute_arena_spmd(self, q, ds, lowering, m, strategy):
+    def _execute_arena_spmd(self, q, ds, lowering, m, strategy, scope):
         """The unified executor core on the mesh: ONE dispatch folds the
         scope inside the trace and merges at the boundary.  Returns None
         to decline (caller falls through to the legacy dense-state
@@ -1347,7 +1358,6 @@ class DistributedEngine:
 
         la, G = lowering.la, lowering.num_groups
         pc = current_partial()
-        scope = self._scope_for_metrics(q, ds)
         if not scope:
             if pc is not None:
                 pc.begin_pass()

@@ -40,7 +40,9 @@ _METADATA_TYPES = (
 
 def _rows_in_scope(q, ds) -> int:
     """Rows the query would scan after interval/zone-map pruning — the
-    same metadata-only scoping the engine performs before dispatch."""
+    same metadata-only scoping the engine performs before dispatch, and
+    for a served request the same walk: this is the request's first ask,
+    and its trace holds the scope for the engine's (`segments_in_scope`)."""
     from ..exec.engine import segments_in_scope
 
     try:

@@ -83,7 +83,10 @@ def served():
     cfg = SessionConfig.load_calibrated()
     cfg.result_cache_entries = 0  # every request executes
     ctx = sd.TPUOlapContext(cfg)
-    ssb.register(ctx, tables=ssb.gen_tables(scale=0.01, seed=7))
+    # eight time-sorted segments, so that the zone maps have what to prune
+    ssb.register(
+        ctx, tables=ssb.gen_tables(scale=0.01, seed=7), rows_per_segment=8192
+    )
     srv = OlapServer(ctx, port=0).start()
     try:
         yield ctx, srv, ssb.QUERIES
@@ -110,18 +113,38 @@ def _self_times_add_up(doc):
     )
 
 
-@pytest.mark.parametrize("query", ["q1_1", "q2_1", "q4_3"])
-def test_served_request_counts_its_scope_walks(served, monkeypatch, query):
-    """`spans["scope"]["n"]` is the number of `segments_in_scope` calls
-    the request made, whoever made them; with `http_accept` adopted the
-    root starts at accept and the self times still add up to `wall_ms`."""
-    ctx, srv, queries = served
+def _count_calls(monkeypatch, name):
+    """Count the calls of `exec.engine.<name>` (every caller looks it up
+    on the module when it calls)."""
     calls = []
-    real = engine_mod.segments_in_scope
-    monkeypatch.setattr(
-        engine_mod, "segments_in_scope",
-        lambda q, ds: calls.append(1) or real(q, ds),
-    )
+    real = getattr(engine_mod, name)
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, name, counted)
+    return calls
+
+
+def _scope_spans(doc):
+    return [s for s in _walk(doc["spans"]) if s["name"] == "scope"]
+
+
+@pytest.mark.parametrize("query", [
+    "q1_1", "q1_2", "q1_3", "q2_1", "q2_2", "q2_3", "q3_1", "q3_2", "q3_3",
+    "q3_4", "q4_1", "q4_2", "q4_3",
+])
+def test_served_request_counts_its_scope_walks(served, monkeypatch, query):
+    """A served request walks the segments' zone maps ONCE (ISSUE 38):
+    `spans["scope"]["n"]` is 1 whoever asked, the span's `asks` is the
+    number of `segments_in_scope` calls the request made (the lane
+    classifier's and the engine's at least); with `http_accept` adopted
+    the root starts at accept and the self times still add up to
+    `wall_ms`."""
+    ctx, srv, queries = served
+    asks = _count_calls(monkeypatch, "segments_in_scope")
+    walks = _count_calls(monkeypatch, "_walk_segments")
     conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=120)
     try:
         _post(conn, queries[query], "walks-" + query)
@@ -129,12 +152,13 @@ def test_served_request_counts_its_scope_walks(served, monkeypatch, query):
         conn.close()
     doc = ctx.tracer.ring.get("walks-" + query)
     rc = doc["receipt"]
-    assert len(calls) >= 2  # the lane classifier's and the engine's
-    assert rc["spans"]["scope"]["n"] == len(calls)
-    scopes = [s for s in _walk(doc["spans"]) if s["name"] == "scope"]
-    assert all(
-        0 < s["attrs"]["kept"] <= s["attrs"]["segments"] for s in scopes
-    )
+    assert rc["spans"]["scope"]["n"] == len(walks) == 1
+    (scope,) = _scope_spans(doc)
+    assert scope["attrs"]["asks"] == len(asks) >= 2
+    assert 0 <= scope["attrs"]["kept"] <= scope["attrs"]["segments"] == 8
+    # the classifier asked first: the one walk lies under the root, in
+    # front of the engine
+    assert scope in doc["spans"]["children"]
     first, second = doc["spans"]["children"][:2]
     assert (first["name"], second["name"]) == ("http_accept", "http_read")
     assert first["start_ms"] == 0.0  # the root starts at accept
@@ -144,7 +168,150 @@ def test_served_request_counts_its_scope_walks(served, monkeypatch, query):
     assert {"engine", "post_process"} <= set(rc["spans"])
     engine = next(s for s in _walk(doc["spans"]) if s["name"] == "engine")
     assert engine["attrs"]["backend"] in ("device", "mesh")
+    # the engine scanned what the walk kept
+    m = ctx.last_metrics
+    assert m.segments == scope["attrs"]["kept"]
     _self_times_add_up(doc)
+
+
+def test_nothing_of_a_scope_outlives_its_request(served, monkeypatch):
+    """Two requests on one connection, the same query: each walks once
+    and has its own `scope` span; the closed trace holds no scope."""
+    ctx, srv, queries = served
+    walks = _count_calls(monkeypatch, "_walk_segments")
+    conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=120)
+    try:
+        _post(conn, queries["q1_1"], "twice-1")
+        assert ctx.tracer.last.scopes == []
+        _post(conn, queries["q1_1"], "twice-2")
+    finally:
+        conn.close()
+    assert len(walks) == 2
+    docs = [ctx.tracer.ring.get(f"twice-{i}") for i in (1, 2)]
+    for doc in docs:
+        assert doc["receipt"]["spans"]["scope"]["n"] == 1
+        (scope,) = _scope_spans(doc)
+        assert scope["attrs"]["asks"] >= 2
+    assert ctx.tracer.last.query_id == "twice-2"
+    assert ctx.tracer.last.scopes == []
+
+
+@pytest.fixture()
+def appendable():
+    cfg = SessionConfig()
+    cfg.prefer_distributed = False
+    cfg.result_cache_entries = 0
+    ctx = sd.TPUOlapContext(cfg)
+    n = 4_096
+    ctx.register_table(
+        "ap_t",
+        {
+            "k": np.array(["x", "y"], dtype=object)[np.arange(n) % 2],
+            "v": np.ones(n, np.float32),
+            "t": (np.arange(n) * 1_000).astype(np.int64),
+        },
+        dimensions=["k"], metrics=["v"], time_column="t",
+        rows_per_segment=1_024,
+    )
+    srv = OlapServer(ctx, port=0).start()
+    try:
+        yield ctx, srv
+    finally:
+        srv.shutdown()
+
+
+def test_request_after_an_append_walks_the_new_segment_set(appendable):
+    """A streamed append publishes a new `DataSource`: the next request
+    walks it anew and its scope holds the appended segment; a scope held
+    for the old object never answers for the new one, inside one trace
+    either."""
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+
+    ctx, srv = appendable
+    sql = "SELECT k, sum(v) AS s FROM ap_t WHERE k = 'x' GROUP BY k"
+
+    def ask(qid):
+        conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=120)
+        try:
+            body = _post(conn, sql, qid)
+        finally:
+            conn.close()
+        (scope,) = _scope_spans(ctx.tracer.ring.get(qid))
+        return body, scope["attrs"]
+
+    body, before = ask("ap-1")
+    assert (before["segments"], before["kept"]) == (4, 4)
+    assert body[0]["s"] == 2_048.0
+    old = ctx.catalog.get("ap_t")
+    ack = ctx.append_rows(
+        "ap_t", [{"k": "x", "v": 5.0, "t": 5_000_000}] * 3
+    )
+    new = ctx.catalog.get("ap_t")
+    assert new is not old and new.version == ack["datasourceVersion"]
+    body, after = ask("ap-2")
+    assert (after["segments"], after["kept"]) == (5, 5)
+    assert body[0]["s"] == 2_048.0 + 15.0
+    # one trace, two DataSource objects of one name: two walks
+    q = ctx.plan_sql(sql).query
+    with ctx.tracer.query_trace(query_id="ap-3") as tr:
+        assert len(segments_in_scope(q, old)) == 4
+        assert len(segments_in_scope(q, new)) == 5
+        assert len(segments_in_scope(q, old)) == 4
+        assert [w.attrs["asks"] for *_, w in tr.scopes] == [2, 1]
+    assert tr.receipt["spans"]["scope"]["n"] == 2
+
+
+def test_held_scope_is_keyed_by_value_and_handed_out_as_a_copy(served):
+    """Inside one trace: an equal filter built anew shares the walk,
+    another filter or other intervals walk for themselves, an explicit
+    `segs=` never asks, and no caller can alter what the next one gets."""
+    import dataclasses
+
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+
+    ctx, _, queries = served
+    q = ctx.plan_sql(queries["q1_1"]).query
+    ds = ctx.catalog.get(q.datasource)
+    same = dataclasses.replace(
+        q, filter=dataclasses.replace(q.filter), limit_spec=None
+    )
+    other = ctx.plan_sql(queries["q1_2"]).query
+    assert same.filter is not q.filter and same.filter == q.filter
+    with ctx.tracer.query_trace(query_id="held") as tr:
+        first = segments_in_scope(q, ds)
+        kept = list(first)
+        first.clear()  # a caller that filters its list in place
+        assert segments_in_scope(same, ds) == kept
+        assert segments_in_scope(other, ds) != kept
+        assert segments_in_scope(
+            dataclasses.replace(q, intervals=((0, 1),)), ds
+        ) == []
+        ctx.engine._partials_for_query(q, ds, segs=kept[:1])
+        assert [w.attrs["asks"] for *_, w in tr.scopes] == [2, 1, 1]
+    assert tr.receipt["spans"]["scope"]["n"] == 3
+    assert tr.scopes == []
+
+
+def test_fused_batch_walks_once_a_filter(served):
+    """A fused batch resolves every member's scope inside the leader's
+    request: members of one filter share a walk, a member of another
+    filter walks for itself."""
+    ctx, _, queries = served
+    qa = ctx.plan_sql(queries["q1_1"]).query
+    qb = ctx.plan_sql(queries["q1_2"]).query
+    ds = ctx.catalog.get(qa.datasource)
+    with ctx.tracer.query_trace(query_id="fused-walks") as tr:
+        out = ctx.engine.execute_fused([qa, qb, qa], ds)
+    assert len(out) == 3
+    assert out[0][2].segments != out[1][2].segments
+    scopes = [
+        s for s in _walk(tr.to_dict()["spans"]) if s["name"] == "scope"
+    ]
+    assert [s["attrs"]["asks"] for s in scopes] == [2, 1]
+    assert [s["attrs"]["kept"] for s in scopes] == [
+        out[0][2].segments, out[1][2].segments,
+    ]
+    assert tr.receipt["spans"]["scope"]["n"] == 2
 
 
 def test_kept_alive_connection_has_one_http_accept(served):

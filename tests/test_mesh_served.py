@@ -149,6 +149,28 @@ def test_mesh_receipt_splits_launch_from_fetch(served, name):
     assert receipt["dispatch_count"] == 1  # warm: one SPMD launch
 
 
+@pytest.mark.parametrize("name, tier", [
+    ("q1_1", "dense"), ("q4_1", "dense"), ("q4_3", "adaptive"),
+])
+def test_mesh_request_walks_its_scope_once(served, name, tier):
+    """On the mesh too a request resolves its scope once (ISSUE 38): the
+    lane classifier's ask is the walk, `_execute_groupby_once`'s ask
+    reuses it, and the arena, the dense-state program, the presence pass
+    and the shard placement take the list as an argument."""
+    system, _ = served
+    query = next(q for q in QUERIES if q["name"] == name)
+    for _ in range(2):  # cold (the adaptive tier measures), then warm
+        status, _, m = system.send(query)
+        assert status == 200 and m.distributed
+        assert (m.strategy == "adaptive") == (tier == "adaptive")
+        tree = system.ctx.tracer.ring.get(m.query_id)["spans"]
+        assert m.receipt["spans"]["scope"]["n"] == 1
+        (scope,) = [n for n in _nodes(tree) if n["name"] == "scope"]
+        assert scope["attrs"]["asks"] == 2
+        assert scope["attrs"]["kept"] == m.segments
+        assert scope in tree["children"]  # the classifier's, under the root
+
+
 def test_mesh_adaptive_kept_span_has_the_engines_attributes(served):
     """The mesh's adaptive tier names its kept-set step as the engine's
     does: `adaptive_kept` with `source`, `compact_groups`, `remap`, the
@@ -358,7 +380,8 @@ def test_mesh_programs_put_their_collectives_under_boundary_merge(
         ))
     else:
         cols, padded = mesh4._place_shards(
-            dealt_ds, lowering.columns, scratch, q=q
+            dealt_ds, lowering.columns, scratch,
+            segs=segments_in_scope(q, dealt_ds),
         )
         local_rows, keys = padded // SHARDS, tuple(cols.keys())
         if family == "dense-state":

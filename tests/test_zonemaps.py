@@ -358,3 +358,345 @@ def test_nested_and_or_conjuncts_prune(clustered):
     assert len(segs2) == 2
     got = ctx.sql("SELECT count(*) AS n FROM cl WHERE k = 7 OR k = 80")
     assert int(got["n"].iloc[0]) == int(((df.k == 7) | (df.k == 80)).sum())
+
+
+# ---------------------------------------------------------------------------
+# The walk translates each literal once (ISSUE 38): parity with the plain
+# per-segment pruner it replaced, kept here literal for literal
+# ---------------------------------------------------------------------------
+
+
+def _reference_scope(q, ds):
+    """`segments_in_scope` as it stood before ISSUE 38: every conjunct's
+    literals translated anew for every segment."""
+    from spark_druid_olap_tpu.models import filters as F
+    from spark_druid_olap_tpu.ops.filters import numeric_dict_code_bounds
+
+    vcol_names = frozenset(
+        v.name for v in getattr(q, "virtual_columns", ()) or ()
+    )
+
+    def conjuncts(f):
+        if isinstance(f, F.And):
+            return [y for x in f.fields for y in conjuncts(x)]
+        return [f]
+
+    def excluded(seg, c) -> bool:
+        if getattr(c, "dimension", None) in vcol_names:
+            return False
+        if isinstance(c, F.Or):
+            return bool(c.fields) and all(excluded(seg, x) for x in c.fields)
+        if isinstance(c, F.And):
+            return any(excluded(seg, x) for x in c.fields)
+        st = seg.stats or {}
+        if isinstance(c, F.Selector):
+            if c.value is None or c.dimension not in ds.dicts:
+                return False
+            code = ds.dicts[c.dimension].code_of(c.value)
+            if code is None:
+                return True
+            b = st.get(c.dimension)
+            return b is not None and not (b[0] <= code <= b[1])
+        if isinstance(c, F.InFilter):
+            if c.dimension not in ds.dicts:
+                return False
+            if any(v is None for v in c.values):
+                return False
+            codes = [
+                x
+                for x in (ds.dicts[c.dimension].code_of(v) for v in c.values)
+                if x is not None
+            ]
+            if not codes:
+                return True
+            b = st.get(c.dimension)
+            return b is not None and not any(
+                b[0] <= x <= b[1] for x in codes
+            )
+        if isinstance(c, F.Bound) and c.ordering == "numeric":
+            b = st.get(c.dimension)
+            if b is None:
+                return False
+            if c.dimension in ds.dicts:
+                nv = ds.dicts[c.dimension].numeric_values
+                if nv is None:
+                    return False
+                cb = numeric_dict_code_bounds(c, np.asarray(nv))
+                if cb is None:
+                    return False
+                lo_code, hi_code = cb
+                if lo_code is not None and b[1] < lo_code:
+                    return True
+                if hi_code is not None and b[0] > hi_code:
+                    return True
+                return False
+            try:
+                if c.lower is not None:
+                    lo = float(c.lower)
+                    if b[1] < lo or (c.lower_strict and b[1] <= lo):
+                        return True
+                if c.upper is not None:
+                    hi = float(c.upper)
+                    if b[0] > hi or (c.upper_strict and b[0] >= hi):
+                        return True
+            except ValueError:
+                return False
+            return False
+        return False
+
+    segs = list(ds.segments)
+    if q.intervals:
+        segs = [
+            s for s in segs
+            if s.interval is None
+            or any(a <= s.interval[1] and s.interval[0] < b
+                   for a, b in q.intervals)
+        ]
+    filt = getattr(q, "filter", None)
+    if filt is not None and segs:
+        cs = conjuncts(filt)
+        segs = [s for s in segs if not any(excluded(s, c) for c in cs)]
+    return segs
+
+
+@pytest.fixture(scope="module")
+def shapes_ds():
+    """Eight time-sorted segments whose string dimension, numeric
+    dimension and bare metric all rise with row order, so every zone map
+    prunes; the third segment has lost its `stats`, the sixth its
+    `interval`."""
+    import dataclasses
+
+    n, segs = 16_000, 8
+    k = np.sort(np.random.default_rng(38).integers(0, 200, n)) * 5
+    ctx = sd.TPUOlapContext()
+    ctx.register_table(
+        "shp",
+        {
+            "city": np.array([f"c{x:04d}" for x in k], dtype=object),
+            "k": k,
+            "m": k.astype(np.float32) / 2,
+            "v": np.random.default_rng(39).random(n).astype(np.float32),
+            "t": (np.arange(n) * 1_000).astype(np.int64),
+        },
+        dimensions=["city", "k"],
+        metrics=["m", "v"],
+        time_column="t",
+        rows_per_segment=n // segs,
+    )
+    ds = ctx.catalog.get("shp")
+    held = list(ds.segments)
+    assert len(held) == segs and all(s.stats and s.interval for s in held)
+    held[2] = dataclasses.replace(held[2], stats=None)
+    held[5] = dataclasses.replace(held[5], interval=None)
+    return dataclasses.replace(ds, segments=tuple(held))
+
+
+def _filter_shapes():
+    from spark_druid_olap_tpu.models.filters import (
+        And, Bound, InFilter, Not, Or, Selector,
+    )
+
+    def num(dim, **kw):
+        return Bound(dim, ordering="numeric", **kw)
+
+    present, absent = "c0100", "c0101"  # k is a multiple of 5
+    return {
+        "selector-present": Selector("city", present),
+        "selector-absent": Selector("city", absent),
+        "selector-none": Selector("city", None),
+        "selector-numeric-dict": Selector("k", "500"),
+        "selector-numeric-dict-absent": Selector("k", "501"),
+        "selector-numeric-dict-unparsable": Selector("k", "five"),
+        "selector-unknown-dimension": Selector("nope", "x"),
+        "selector-on-metric": Selector("m", "3"),
+        "in-all-present": InFilter("city", ("c0005", "c0900", "c0500")),
+        "in-some-absent": InFilter("city", (absent, "c0900", "zzz")),
+        "in-all-absent": InFilter("city", (absent, "zzz")),
+        "in-with-none": InFilter("city", ("c0005", None)),
+        "in-numeric-dict": InFilter("k", ("995", "0", "7")),
+        "in-unknown-dimension": InFilter("nope", ("a",)),
+        "bound-dict-closed": num("k", lower="100", upper="300"),
+        "bound-dict-strict": num(
+            "k", lower="100", upper="300", lower_strict=True,
+            upper_strict=True,
+        ),
+        # 120 and 875 are a segment's first and another's last value: the
+        # strict end excludes that segment, the closed end keeps it
+        "bound-dict-lower-only": num("k", lower="875"),
+        "bound-dict-lower-only-strict": num(
+            "k", lower="875", lower_strict=True
+        ),
+        "bound-dict-upper-only": num("k", upper="120"),
+        "bound-dict-upper-only-strict": num(
+            "k", upper="120", upper_strict=True
+        ),
+        "bound-dict-between-values": num("k", lower="101", upper="104"),
+        "bound-dict-unparsable": num("k", lower="abc"),
+        "bound-dict-date-literal": num("k", upper="1970-01-01"),
+        "bound-string-dict-numeric-ordering": num("city", lower="3"),
+        "bound-lexicographic": Bound("city", lower="c0100", upper="c0200"),
+        "bound-metric-closed": num("m", lower="50", upper="150"),
+        "bound-metric-strict": num(
+            "m", lower="50", upper="150", lower_strict=True,
+            upper_strict=True,
+        ),
+        "bound-metric-lower-only": num("m", lower="437.5"),
+        "bound-metric-lower-only-strict": num(
+            "m", lower="437.5", lower_strict=True
+        ),
+        "bound-metric-upper-only": num("m", upper="60"),
+        "bound-metric-upper-only-strict": num(
+            "m", upper="60", upper_strict=True
+        ),
+        "bound-metric-unparsable-lower": num("m", lower="x", upper="10"),
+        "bound-metric-unparsable-upper": num("m", lower="400", upper="x"),
+        "bound-unknown-column": num("nope", lower="1"),
+        "and-nested": And((
+            And((Selector("city", present), num("m", lower="10"))),
+            num("k", upper="900"),
+        )),
+        "and-with-absent": And((
+            num("m", lower="10"), Selector("city", absent),
+        )),
+        "or-of-bounds": Or((
+            num("k", upper="50"), num("k", lower="900"),
+        )),
+        "or-with-absent": Or((
+            Selector("city", absent), Selector("city", "c0900"),
+        )),
+        "or-all-absent": Or((
+            Selector("city", absent), InFilter("city", ("zzz",)),
+        )),
+        "or-with-unprunable": Or((
+            Selector("city", present), Not(Selector("city", present)),
+        )),
+        "or-empty": Or(()),
+        "or-of-ands": Or((
+            And((num("k", upper="50"), num("m", upper="10"))),
+            And((Selector("city", "c0900"), Selector("city", absent))),
+        )),
+        "and-of-ors": And((
+            Or((Selector("k", "0"), Selector("k", "995"))),
+            Or((num("m", upper="100"), Selector("city", None))),
+        )),
+        "not": Not(Selector("city", present)),
+    }
+
+
+def _scope_query(filt=None, intervals=(), virtual_columns=()):
+    from spark_druid_olap_tpu.models.aggregations import DoubleSum
+    from spark_druid_olap_tpu.models.query import GroupByQuery
+
+    return GroupByQuery(
+        datasource="shp", dimensions=(),
+        aggregations=(DoubleSum("s", "v"),), filter=filt,
+        intervals=intervals, virtual_columns=virtual_columns,
+    )
+
+
+_SHAPES = _filter_shapes()
+# what a shape must do besides agree with the reference: how many of the
+# eight segments it keeps, where that is what the shape is for
+_KEPT = {
+    "selector-absent": 0, "selector-none": 8, "in-all-absent": 0,
+    "in-with-none": 8, "selector-unknown-dimension": 8,
+    "bound-dict-unparsable": 8, "bound-lexicographic": 8,
+    "bound-metric-unparsable-lower": 8, "and-with-absent": 0,
+    "or-all-absent": 0, "or-with-unprunable": 8, "or-empty": 8, "not": 8,
+    "bound-dict-lower-only": 3, "bound-dict-lower-only-strict": 2,
+    "bound-dict-upper-only": 3, "bound-dict-upper-only-strict": 2,
+    "bound-metric-lower-only": 3, "bound-metric-lower-only-strict": 2,
+    "bound-metric-upper-only": 3, "bound-metric-upper-only-strict": 2,
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_translated_walk_keeps_what_the_plain_walk_kept(shapes_ds, shape):
+    """Same kept uids, in the same order, for every filter shape the
+    pruner knows, alone and under intervals; a segment without `stats`
+    is never pruned by a zone map, one without `interval` never by
+    time."""
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+
+    ds = shapes_ds
+    for intervals in ((), ((2_000_000, 9_000_000),),
+                      ((0, 1_000), (15_000_000, 15_000_001))):
+        q = _scope_query(_SHAPES[shape], intervals)
+        got = [s.uid for s in segments_in_scope(q, ds)]
+        assert got == [s.uid for s in _reference_scope(q, ds)]
+        if got:
+            assert ds.segments[2].uid in got or intervals
+        if not intervals and shape in _KEPT:
+            assert len(got) == _KEPT[shape]
+
+
+@pytest.mark.parametrize("case", [
+    "intervals-alone", "no-filter-no-intervals", "shadowed-metric",
+    "shadowed-inside-or",
+])
+def test_translated_walk_parity_beside_the_filter(shapes_ds, case):
+    """`intervals` alone, nothing at all, and a virtual column shadowing
+    the filtered name (at the top level and inside an `Or`): the shadow
+    switches that conjunct's pruning off, and only that conjunct's."""
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+    from spark_druid_olap_tpu.models.filters import And, Bound, Or
+    from spark_druid_olap_tpu.models.query import VirtualColumn
+    from spark_druid_olap_tpu.plan.expr import Literal, col
+
+    ds = shapes_ds
+    shadow = (VirtualColumn("m", Literal(500.0) - col("m")),)
+    low_m = Bound("m", upper="10", ordering="numeric")
+    low_k = Bound("k", upper="300", ordering="numeric")
+    q = {
+        "intervals-alone": _scope_query(None, ((3_000_000, 5_000_000),)),
+        "no-filter-no-intervals": _scope_query(),
+        "shadowed-metric": _scope_query(
+            And((low_m, low_k)), virtual_columns=shadow
+        ),
+        "shadowed-inside-or": _scope_query(
+            Or((low_m, low_k)), virtual_columns=shadow
+        ),
+    }[case]
+    got = [s.uid for s in segments_in_scope(q, ds)]
+    assert got == [s.uid for s in _reference_scope(q, ds)]
+    if case == "intervals-alone":
+        # the segment that lost its interval is always in scope
+        assert ds.segments[5].uid in got and 1 < len(got) < 8
+    elif case == "no-filter-no-intervals":
+        assert got == [s.uid for s in ds.segments]
+    elif case == "shadowed-metric":
+        # low_k still prunes; low_m alone would have kept fewer
+        plain = _scope_query(And((low_m, low_k)))
+        assert len(segments_in_scope(plain, ds)) < len(got) < 8
+    else:
+        assert len(got) == 8  # an unprunable disjunct keeps everything
+
+
+@pytest.fixture(scope="module")
+def ssb_115():
+    """SSB's flat lineorder at the cells' 115 segments (scale 0.01,
+    522 rows a segment)."""
+    from spark_druid_olap_tpu.workloads import ssb
+
+    ctx = sd.TPUOlapContext()
+    ssb.register(
+        ctx, tables=ssb.gen_tables(scale=0.01, seed=7), rows_per_segment=522
+    )
+    return ctx, ssb.QUERIES
+
+
+@pytest.mark.parametrize("name", [
+    "q1_1", "q1_2", "q1_3", "q2_1", "q2_2", "q2_3", "q3_1", "q3_2", "q3_3",
+    "q3_4", "q4_1", "q4_2", "q4_3",
+])
+def test_translated_walk_parity_on_the_ssb_filters(ssb_115, name):
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+
+    ctx, queries = ssb_115
+    rw = ctx.plan_sql(queries[name])
+    ds = ctx.catalog.get(rw.datasource)
+    assert len(ds.segments) == 115
+    got = [s.uid for s in segments_in_scope(rw.query, ds)]
+    assert got == [s.uid for s in _reference_scope(rw.query, ds)]
+    assert name not in ("q1_1", "q1_2", "q1_3") or 0 < len(got) < 40
