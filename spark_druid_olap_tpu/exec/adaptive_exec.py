@@ -65,7 +65,7 @@ from ..obs import (
 from ..plan.cost import presence_kernels, shape_kernel
 from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
-from .finalize import finalize_groupby
+from .finalize import finalize_groupby, state_nbytes
 from .lowering import (
     GroupByLowering,
     ResolvedDim,
@@ -582,6 +582,8 @@ class AdaptiveDomainMixin:
                 sums, mins, maxs, sketch_states = jax.device_get(
                     (sums, mins, maxs, sketch_states)
                 )
+            if self._m is not None:
+                self._m.sketch_state_bytes = state_nbytes(sketch_states)
             t0 = time.perf_counter()
             with span(SPAN_FINALIZE):
                 out = finalize_groupby(

@@ -65,9 +65,11 @@ from .finalize import (  # noqa: F401
     finalize_groupby,
     finalize_timeseries,
     finalize_topn,
+    state_nbytes,
 )
 from ..obs import (
     SCOPE_CARRY_MERGE,
+    SCOPE_SKETCH_FOLD,
     SPAN_DEVICE_FETCH,
     SPAN_FINALIZE,
     SPAN_H2D,
@@ -418,22 +420,23 @@ def _segment_partials(
         strategy=strategy,
     )
     sk = {}
-    for agg in la.sketch_aggs:
-        # per-agg FILTER mask (SQL `agg(...) FILTER (WHERE ...)`)
-        # composes with the row mask — sketches must honor it the
-        # same way sum/min/max columns do
-        mfn = la.mask_fns.get(agg.name)
-        amask = mask & mfn(cols) if mfn is not None else mask
-        if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-            sk[agg.name] = hll_ops.partial_hll(agg, cols, gid, amask, G)
-        elif isinstance(agg, A.QuantilesSketch):
-            sk[agg.name] = quantiles_ops.partial_quantiles(
-                agg, cols, gid, amask, G
-            )
-        else:
-            sk[agg.name] = theta_ops.partial_theta(
-                agg, cols, gid, amask, G
-            )
+    with device_scope(SCOPE_SKETCH_FOLD):
+        for agg in la.sketch_aggs:
+            # per-agg FILTER mask (SQL `agg(...) FILTER (WHERE ...)`)
+            # composes with the row mask — sketches must honor it the
+            # same way sum/min/max columns do
+            mfn = la.mask_fns.get(agg.name)
+            amask = mask & mfn(cols) if mfn is not None else mask
+            if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
+                sk[agg.name] = hll_ops.partial_hll(agg, cols, gid, amask, G)
+            elif isinstance(agg, A.QuantilesSketch):
+                sk[agg.name] = quantiles_ops.partial_quantiles(
+                    agg, cols, gid, amask, G
+                )
+            else:
+                sk[agg.name] = theta_ops.partial_theta(
+                    agg, cols, gid, amask, G
+                )
     return s, mn, mx, sk
 
 
@@ -921,14 +924,19 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # -change tail, deltas, budget overflow) fall through to the loop
         # below with the fold continuing in canonical order, so results
         # stay byte-identical arena-on vs arena-off.  Sketch aggs decline
-        # (their merge states carry no exact in-scan fold identity).
+        # (their merge states carry no exact in-scan fold identity): a
+        # scope the arena would take runs the loop below, and its
+        # dispatch spans say so (ROADMAP M6).
         phase = span_attrs or {}
         plan = run = None
-        if self.arena_execution and not la.sketch_aggs:
+        if self.arena_execution:
             from . import arena as _arena
 
             if not _arena.query_disabled():
                 plan = _arena.plan_for(self, batches, need)
+            if plan is not None and la.sketch_aggs:
+                plan = None
+                phase = {**phase, "arena": "declined:sketch"}
         if plan is not None:
             strategy = strategy_override or concrete_kernel(self.strategy, G)
             run = self._pipeline.start(
@@ -1843,6 +1851,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     sums, mins, maxs, sketch_states = jax.device_get(
                         (sums, mins, maxs, sketch_states)
                     )
+                m.sketch_state_bytes = state_nbytes(sketch_states)
                 # state capture (serve/result_cache.py delta-aware reuse):
                 # stash the merged HOST state for the caller — only on
                 # this dense path (sparse/adaptive returned above) and

@@ -10,6 +10,7 @@ implementation).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Mapping, Optional
 
 import jax.numpy as jnp
@@ -18,8 +19,12 @@ import numpy as np
 from ..catalog.segment import DataSource
 from ..models import aggregations as A
 from ..models import query as Q
+from ..obs import SCOPE_SKETCH_MERGE, SPAN_SKETCH_ESTIMATE, device_scope, span
 from ..utils.granularity import bucket_starts
 from .lowering import LoweredAggs, ResolvedDim
+
+_NO_SPAN = contextlib.nullcontext()
+
 
 def finalize_timeseries(df, q: Q.TimeseriesQuery, ds: DataSource):
     """Shared Timeseries finalization: empty-bucket zero-fill + ordering."""
@@ -207,19 +212,28 @@ def _merge_sketch_states(
     HLL registers max-merge; theta states union (shared with streaming)."""
     from ..ops import theta as theta_ops
 
-    for agg in la.sketch_aggs:
-        st = new[agg.name]
-        prev = acc.get(agg.name)
-        if prev is None:
-            acc[agg.name] = st
-        elif isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-            acc[agg.name] = jnp.maximum(prev, st)
-        elif isinstance(agg, A.QuantilesSketch):
-            from ..ops import quantiles as quantiles_ops
+    if not la.sketch_aggs:
+        return
+    with device_scope(SCOPE_SKETCH_MERGE):
+        for agg in la.sketch_aggs:
+            st = new[agg.name]
+            prev = acc.get(agg.name)
+            if prev is None:
+                acc[agg.name] = st
+            elif isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
+                acc[agg.name] = jnp.maximum(prev, st)
+            elif isinstance(agg, A.QuantilesSketch):
+                from ..ops import quantiles as quantiles_ops
 
-            acc[agg.name] = quantiles_ops.merge_states(prev, st, agg.size)
-        else:
-            acc[agg.name] = theta_ops.merge_states(prev, st, agg.size)
+                acc[agg.name] = quantiles_ops.merge_states(prev, st, agg.size)
+            else:
+                acc[agg.name] = theta_ops.merge_states(prev, st, agg.size)
+
+
+def state_nbytes(sketch_states: Mapping[str, Any]) -> int:
+    """Bytes of a fetched set of sketch states (`QueryMetrics.
+    sketch_state_bytes`)."""
+    return sum(int(np.asarray(v).nbytes) for v in sketch_states.values())
 
 
 # ---------------------------------------------------------------------------
@@ -298,29 +312,33 @@ def finalize_groupby(
         table[n] = _finalize_extremum(maxs[sel, j], la.long_valued[n])
 
     raw_states: Dict[str, np.ndarray] = {}
-    for agg in la.sketch_aggs:
-        from ..ops import hll as hll_ops
-        from ..ops import theta as theta_ops
+    # the states -> estimates work and the post-aggs that read the raw
+    # states are one span, opened only where there are sketches
+    with span(SPAN_SKETCH_ESTIMATE) if la.sketch_aggs else _NO_SPAN:
+        for agg in la.sketch_aggs:
+            from ..ops import hll as hll_ops
+            from ..ops import theta as theta_ops
 
-        st = sketch_states[agg.name][sel]
-        raw_states[agg.name] = st
-        if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-            table[agg.name] = np.rint(hll_ops.estimate(st)).astype(np.int64)
-        elif isinstance(agg, A.QuantilesSketch):
-            from ..ops import quantiles as quantiles_ops
+            st = sketch_states[agg.name][sel]
+            raw_states[agg.name] = st
+            if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
+                table[agg.name] = np.rint(hll_ops.estimate(st)).astype(np.int64)
+            elif isinstance(agg, A.QuantilesSketch):
+                from ..ops import quantiles as quantiles_ops
 
-            # Druid finalizes a quantiles sketch to its N; the state
-            # carries the exact per-group row count in its trailing
-            # counter row, so this is exact at any scale.  Quantile values
-            # come from the QuantileFromSketch post-agg over the raw state
-            table[agg.name] = quantiles_ops.count(st).astype(np.int64)
-        else:
-            table[agg.name] = np.rint(theta_ops.estimate(st)).astype(np.int64)
+                # Druid finalizes a quantiles sketch to its N; the state
+                # carries the exact per-group row count in its trailing
+                # counter row, so this is exact at any scale.  Quantile
+                # values come from the QuantileFromSketch post-agg over
+                # the raw state
+                table[agg.name] = quantiles_ops.count(st).astype(np.int64)
+            else:
+                table[agg.name] = np.rint(theta_ops.estimate(st)).astype(np.int64)
 
-    for p in q.post_aggregations:
-        table[p.name] = np.broadcast_to(
-            eval_post_agg(p, table, raw_states), sel.shape
-        ).copy()
+        for p in q.post_aggregations:
+            table[p.name] = np.broadcast_to(
+                eval_post_agg(p, table, raw_states), sel.shape
+            ).copy()
 
     if q.having is not None:
         m = _eval_having(q.having, table)

@@ -123,6 +123,10 @@ class QueryMetrics:
     # the batch totals split evenly across members (the batch moves one
     # shared column set).
     fused_batch: int = 0
+    # sketch aggregations (PR 39): the bytes of merged sketch state the
+    # request fetched from the device to the host (int32[G, 2^p] HLL
+    # registers per hyperUnique); 0 for a query without sketches
+    sketch_state_bytes: int = 0
 
     @property
     def rows_per_sec(self) -> float:

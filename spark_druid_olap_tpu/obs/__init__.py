@@ -43,6 +43,8 @@ from .trace import (  # noqa: F401
     SCOPE_NAMES,
     SCOPE_PARTIAL_AGG,
     SCOPE_PRESENCE,
+    SCOPE_SKETCH_FOLD,
+    SCOPE_SKETCH_MERGE,
     SCOPE_SPARSE_SORT,
     SPAN_ADAPTIVE_KEPT,
     SPAN_ADAPTIVE_PROBE,
@@ -81,6 +83,7 @@ from .trace import (  # noqa: F401
     SPAN_SCATTER,
     SPAN_SCOPE,
     SPAN_SEGMENT_DISPATCH,
+    SPAN_SKETCH_ESTIMATE,
     SPAN_SNAPSHOT_FLUSH,
     SPAN_SPARSE_DISPATCH,
     SPAN_SQL_PARSE,

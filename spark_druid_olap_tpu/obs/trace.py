@@ -112,6 +112,7 @@ SPAN_SCOPE = "scope"  # the request's walk of the segments' intervals and zone m
 SPAN_ENGINE = "engine"  # api/server: one call into an engine
 SPAN_POST_PROCESS = "post_process"  # api: host-side shaping of the frame
 SPAN_HTTP_ACCEPT = "http_accept"  # server: accept() -> do_POST's first line
+SPAN_SKETCH_ESTIMATE = "sketch_estimate"  # finalize: sketch states -> estimates
 
 SPAN_NAMES = frozenset(
     {
@@ -158,6 +159,7 @@ SPAN_NAMES = frozenset(
         SPAN_ENGINE,
         SPAN_POST_PROCESS,
         SPAN_HTTP_ACCEPT,
+        SPAN_SKETCH_ESTIMATE,
     }
 )
 
@@ -178,6 +180,8 @@ SCOPE_PRESENCE = "sdol.presence"  # adaptive phase A: per-dim presence counts
 SCOPE_KEPT_REMAP = "sdol.kept_remap"  # adaptive phase B: code -> compact code
 SCOPE_SPARSE_SORT = "sdol.sparse_sort"  # sparse tier: sort-compaction of keys
 SCOPE_BOUNDARY_MERGE = "sdol.boundary_merge"  # mesh: every collective over ICI
+SCOPE_SKETCH_FOLD = "sdol.sketch_fold"  # a segment's sketch partials (HLL fold)
+SCOPE_SKETCH_MERGE = "sdol.sketch_merge"  # sketch states merged across segments
 
 SCOPE_NAMES = frozenset(
     {
@@ -191,6 +195,8 @@ SCOPE_NAMES = frozenset(
         SCOPE_KEPT_REMAP,
         SCOPE_SPARSE_SORT,
         SCOPE_BOUNDARY_MERGE,
+        SCOPE_SKETCH_FOLD,
+        SCOPE_SKETCH_MERGE,
     }
 )
 

@@ -613,32 +613,43 @@ def test_chooser_high_cardinality_class(on_tpu):
 
 @pytest.fixture(scope="module")
 def ssb_sf10_shapes():
-    """The 13 SSB queries planned against SF10's dictionaries: dimension
-    tables at SF1 already hold every attribute value SF10 has (the fact is
-    4,096 rows), and the model is then asked about SF10's own 60 M rows in
-    115 segments.  name -> (query, datasource stand-in, G)."""
+    """The 13 SSB queries, and the native topN queries of the
+    `druid-topn-hll` mix (PR 39) as the engine's inner group-bys, planned
+    against SF10's dictionaries: dimension tables at SF1 already hold every
+    attribute value SF10 has (the fact is 4,096 rows), and the model is then
+    asked about SF10's own 60 M rows in 115 segments.  name -> (query,
+    datasource stand-in, G)."""
     import types
 
+    from spark_druid_olap_tpu.exec.engine import groupby_family
     from spark_druid_olap_tpu.exec.lowering import lower_groupby
+    from spark_druid_olap_tpu.models.wire import query_from_druid
     from spark_druid_olap_tpu.sql.parser import parse_sql
     from spark_druid_olap_tpu.workloads import ssb
 
     ctx = sd.TPUOlapContext()
     ssb.register(ctx, tables=ssb.gen_tables(1.0, seed=7, fact_rows=4096))
-    out = {}
+    planned = []
     for name in ssb.QUERIES:
         lp, _, _ = parse_sql(ssb.QUERIES[name])
         rw = ctx._planner().plan(lp)
-        ds = ctx.catalog.get(rw.datasource)
+        planned.append((name, rw.query, ctx.catalog.get(rw.datasource)))
+    with open(os.path.join(_ROOT, "benchmark", "traffic", "druid-topn-hll.json")) as f:
+        for spec in json.load(f)["queries"]:
+            q = query_from_druid(spec["native"])
+            ds = ctx.catalog.get(q.datasource)
+            planned.append((spec["name"], groupby_family(q, ds)[0], ds))
+    out = {}
+    for name, q, ds in planned:
         sf10 = types.SimpleNamespace(
             num_rows=_ROWS_1CHIP, segments=[None] * 115, dicts=ds.dicts
         )
-        out[name] = (rw.query, sf10, lower_groupby(rw.query, ds).num_groups)
+        out[name] = (q, sf10, lower_groupby(q, ds).num_groups)
     return out
 
 
 def _cell_expectations():
-    """(cell, query, the cell file's `expect_strategy`) for the three cells."""
+    """(cell, query, the cell file's `expect_strategy`) for every cell."""
     import glob
 
     rows = []
@@ -656,7 +667,7 @@ def _cell_expectations():
 def test_ssb_classes_are_the_cell_files(
     on_tpu, ssb_sf10_shapes, cell, name, expected
 ):
-    """Under the committed `calibration.tpu.json` each SSB query's class is
+    """Under the committed `calibration.tpu.json` each cell query's class is
     what its cell's file expects (`QueryMetrics.strategy`: the tier, or the
     kernel the dense class runs as), on one chip and on the (4, 1) mesh: a
     re-measured constant that moved one would print "routing differs from
