@@ -65,7 +65,11 @@ from ..obs import (
 from ..plan.cost import presence_kernels, shape_kernel
 from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
-from .finalize import finalize_groupby, state_nbytes
+from .finalize import (
+    estimable_sketch_states,
+    finalize_groupby,
+    state_nbytes,
+)
 from .lowering import (
     GroupByLowering,
     ResolvedDim,
@@ -577,6 +581,9 @@ class AdaptiveDomainMixin:
 
         def resolve():
             dims, la, G, sums, mins, maxs, sketch_states = state
+            # nothing merges this state after the fetch (the tier never
+            # captures it): HLL registers cross as their histograms
+            sketch_states = estimable_sketch_states(la, sketch_states)
             with span(SPAN_DEVICE_FETCH):
                 prof.fetch_sync((sums, mins, maxs, sketch_states))
                 sums, mins, maxs, sketch_states = jax.device_get(

@@ -61,6 +61,7 @@ from .finalize import (  # noqa: F401
     _eval_having,
     _merge_sketch_states,
     apply_limit_spec,
+    estimable_sketch_states,
     eval_post_agg,
     finalize_groupby,
     finalize_timeseries,
@@ -1841,6 +1842,14 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 t_fetch = _time.perf_counter()
                 dims, la, G, sums, mins, maxs, sketch_states = dense_state
                 dense_state = None  # free the device partials promptly
+                # state capture (serve/result_cache.py delta-aware reuse)
+                # merges the registers later: only without it does the
+                # fetch carry HLL register histograms instead
+                holder = getattr(self._m_local, "capture", None)
+                if holder is None:
+                    sketch_states = estimable_sketch_states(
+                        la, sketch_states
+                    )
                 # ONE device_get for everything: each separate host fetch
                 # of a device buffer pays a full round trip; a single
                 # pytree fetch pays one.
@@ -1852,12 +1861,11 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                         (sums, mins, maxs, sketch_states)
                     )
                 m.sketch_state_bytes = state_nbytes(sketch_states)
-                # state capture (serve/result_cache.py delta-aware reuse):
-                # stash the merged HOST state for the caller — only on
-                # this dense path (sparse/adaptive returned above) and
-                # only when the scan was NOT deadline-truncated (a
-                # partial state must never seed the cache)
-                holder = getattr(self._m_local, "capture", None)
+                # state capture: stash the merged HOST state for the
+                # caller — only on this dense path (sparse/adaptive
+                # returned above) and only when the scan was NOT
+                # deadline-truncated (a partial state must never seed
+                # the cache)
                 pc_cap = current_partial()
                 if holder is not None and (
                     pc_cap is None or not pc_cap.triggered

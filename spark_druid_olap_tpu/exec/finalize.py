@@ -19,7 +19,13 @@ import numpy as np
 from ..catalog.segment import DataSource
 from ..models import aggregations as A
 from ..models import query as Q
-from ..obs import SCOPE_SKETCH_MERGE, SPAN_SKETCH_ESTIMATE, device_scope, span
+from ..obs import (
+    SCOPE_SKETCH_MERGE,
+    SPAN_SEGMENT_DISPATCH,
+    SPAN_SKETCH_ESTIMATE,
+    device_scope,
+    span,
+)
 from ..utils.granularity import bucket_starts
 from .lowering import LoweredAggs, ResolvedDim
 
@@ -85,8 +91,9 @@ def eval_post_agg(
     states: Optional[Mapping[str, np.ndarray]] = None,
 ) -> np.ndarray:
     """`states` maps sketch-agg name -> raw per-group sketch state (HLL
-    registers / theta hash sets); sketch post-aggs must finalize from the raw
-    state, not from the already-finalized estimate column in `table`."""
+    register histograms / theta hash sets); sketch post-aggs must finalize
+    from the raw state, not from the already-finalized estimate column in
+    `table`."""
     if isinstance(p, A.FieldAccess):
         return np.asarray(table[p.field_name])
     if isinstance(p, A.ConstantPost):
@@ -118,7 +125,7 @@ def eval_post_agg(
                 raise ValueError(f"arithmetic fn {p.fn!r}")
         return acc
     if isinstance(p, A.HyperUniqueCardinality):
-        from ..ops.hll import estimate as hll_estimate
+        from ..ops import hll as hll_ops
 
         if states is None or p.field_name not in states:
             raise KeyError(
@@ -126,7 +133,11 @@ def eval_post_agg(
                 "state available (field must name a hyperUnique/cardinality "
                 "aggregation in the same query)"
             )
-        return hll_estimate(states[p.field_name])
+        # finalize_groupby hands every HLL state on as its register
+        # histogram, K = 34 - p columns: m = 2^p = 2^(34 - K)
+        hist = np.asarray(states[p.field_name])
+        m = 1 << (34 - hist.shape[-1])
+        return hll_ops.estimate_from_histogram(hist, m)
     if isinstance(p, A.ExpressionPost):
         from ..plan.expr import compile_expr
 
@@ -230,6 +241,35 @@ def _merge_sketch_states(
                 acc[agg.name] = theta_ops.merge_states(prev, st, agg.size)
 
 
+def _is_hll(agg) -> bool:
+    return isinstance(agg, (A.HyperUnique, A.CardinalityAgg))
+
+
+def estimable_sketch_states(
+    la: LoweredAggs, states: Dict[str, Any]
+) -> Dict[str, Any]:
+    """The device sketch states `finalize_groupby` needs, made before the
+    fetch that feeds it: each HLL agg's merged registers int32[G, 2^p]
+    become their register histogram int32[G, 34 - p] on the device
+    (`ops/hll.register_histogram`), so the host fetches and estimates
+    from 34 - p integers a group, not 2^p.  Other sketches pass as they
+    are.  A caller whose state must survive as registers (a captured
+    state, one handed to a merger) does not call this.  The launch is a
+    `segment_dispatch` span (attr `sketch`): it counts as a dispatch."""
+    from ..ops import hll as hll_ops
+
+    hll = [a for a in la.sketch_aggs if _is_hll(a)]
+    if not hll:
+        return states
+    out = dict(states)
+    with span(SPAN_SEGMENT_DISPATCH, sketch="histogram"):
+        for agg in hll:
+            out[agg.name] = hll_ops.register_histogram(
+                states[agg.name], agg.precision
+            )
+    return out
+
+
 def state_nbytes(sketch_states: Mapping[str, Any]) -> int:
     """Bytes of a fetched set of sketch states (`QueryMetrics.
     sketch_state_bytes`)."""
@@ -311,18 +351,34 @@ def finalize_groupby(
     for j, n in enumerate(la.max_names):
         table[n] = _finalize_extremum(maxs[sel, j], la.long_valued[n])
 
+    from ..ops import hll as hll_ops
+
     raw_states: Dict[str, np.ndarray] = {}
+    # an HLL state arrives as its register histogram where the fetch made
+    # one (estimable_sketch_states), else as registers: the span says which
+    fetched = {
+        sketch_states[a.name].shape[-1] == hll_ops.histogram_width(a.precision)
+        for a in la.sketch_aggs
+        if _is_hll(a)
+    }
+    attrs = (
+        {"source": "histogram" if fetched == {True} else "registers"}
+        if fetched else {}
+    )
     # the states -> estimates work and the post-aggs that read the raw
     # states are one span, opened only where there are sketches
-    with span(SPAN_SKETCH_ESTIMATE) if la.sketch_aggs else _NO_SPAN:
+    with span(SPAN_SKETCH_ESTIMATE, **attrs) if la.sketch_aggs else _NO_SPAN:
         for agg in la.sketch_aggs:
-            from ..ops import hll as hll_ops
             from ..ops import theta as theta_ops
 
             st = sketch_states[agg.name][sel]
-            raw_states[agg.name] = st
-            if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-                table[agg.name] = np.rint(hll_ops.estimate(st)).astype(np.int64)
+            if _is_hll(agg):
+                prec = agg.precision
+                if st.shape[-1] != hll_ops.histogram_width(prec):
+                    st = hll_ops.histogram_np(st, prec)
+                table[agg.name] = np.rint(
+                    hll_ops.estimate_from_histogram(st, 1 << prec)
+                ).astype(np.int64)
             elif isinstance(agg, A.QuantilesSketch):
                 from ..ops import quantiles as quantiles_ops
 
@@ -334,6 +390,7 @@ def finalize_groupby(
                 table[agg.name] = quantiles_ops.count(st).astype(np.int64)
             else:
                 table[agg.name] = np.rint(theta_ops.estimate(st)).astype(np.int64)
+            raw_states[agg.name] = st
 
         for p in q.post_aggregations:
             table[p.name] = np.broadcast_to(

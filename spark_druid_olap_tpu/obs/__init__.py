@@ -44,6 +44,7 @@ from .trace import (  # noqa: F401
     SCOPE_PARTIAL_AGG,
     SCOPE_PRESENCE,
     SCOPE_SKETCH_FOLD,
+    SCOPE_SKETCH_HISTOGRAM,
     SCOPE_SKETCH_MERGE,
     SCOPE_SPARSE_SORT,
     SPAN_ADAPTIVE_KEPT,

@@ -182,6 +182,7 @@ SCOPE_SPARSE_SORT = "sdol.sparse_sort"  # sparse tier: sort-compaction of keys
 SCOPE_BOUNDARY_MERGE = "sdol.boundary_merge"  # mesh: every collective over ICI
 SCOPE_SKETCH_FOLD = "sdol.sketch_fold"  # a segment's sketch partials (HLL fold)
 SCOPE_SKETCH_MERGE = "sdol.sketch_merge"  # sketch states merged across segments
+SCOPE_SKETCH_HISTOGRAM = "sdol.sketch_histogram"  # HLL registers -> value counts
 
 SCOPE_NAMES = frozenset(
     {
@@ -197,6 +198,7 @@ SCOPE_NAMES = frozenset(
         SCOPE_BOUNDARY_MERGE,
         SCOPE_SKETCH_FOLD,
         SCOPE_SKETCH_MERGE,
+        SCOPE_SKETCH_HISTOGRAM,
     }
 )
 

@@ -69,6 +69,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..catalog.segment import ROW_PAD, DataSource
 from ..exec.engine import (
     GroupByLowering,
+    estimable_sketch_states,
     finalize_groupby,
     groupby_family,
     groupby_with_time_granularity,
@@ -143,7 +144,9 @@ def _fetch(tree):
 
 
 def _nbytes(tree) -> int:
-    return sum(int(np.asarray(x).nbytes) for x in jax.tree.leaves(tree))
+    """Bytes of a tree's arrays, host or device (no device array is
+    fetched to count it)."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 class DistributedEngine:
@@ -834,14 +837,11 @@ class DistributedEngine:
         t0 = _time.perf_counter()
         with span(SPAN_SEGMENT_DISPATCH, shards=nd, **(span_attrs or {})):
             out_state = _launch(run, cols)
-        # single host fetch (one round trip — see engine._execute_groupby):
-        # it blocks on the SPMD program, so the ICI merge's wall time is
-        # paid here
-        sums, mins, maxs, sk = _fetch(out_state)
-        # what `_spmd_fn`'s collectives moved, a device: the fetched arrays
-        # are the merged ones (a groups axis leaves each device 1/ng of
-        # them); HLL registers are reduced like the sums, theta and
-        # quantile states gathered
+        # what `_spmd_fn`'s collectives moved, a device: the launched
+        # arrays are the merged ones (a groups axis leaves each device
+        # 1/ng of them); HLL registers are reduced like the sums, theta
+        # and quantile states gathered
+        sums, mins, maxs, sk = out_state
         ng, _ = self._groups_split(lowering.num_groups)
         hll = _nbytes([
             sk[a.name] for a in lowering.la.sketch_aggs
@@ -852,6 +852,13 @@ class DistributedEngine:
                 allreduce_factor(nd) * (_nbytes((sums, mins, maxs)) + hll)
                 + allgather_factor(nd) * (_nbytes(sk) - hll)
             ) / ng
+        )
+        # single host fetch (one round trip — see engine._execute_groupby):
+        # it blocks on the SPMD program, so the ICI merge's wall time is
+        # paid here; finalize alone reads the state, so HLL registers
+        # cross as their histograms
+        sums, mins, maxs, sk = _fetch(
+            (sums, mins, maxs, estimable_sketch_states(lowering.la, sk))
         )
         dt = (_time.perf_counter() - t0) * 1e3
         if m.program_cache_hit:

@@ -1142,10 +1142,16 @@ class _Handler(BaseHTTPRequestHandler):
                     self.ctx._last_engine_metrics = m
                     serve.store_native(q, ds, df, state=state, key=rkey)
                     return df
-            if fusable and rkey is not None:
+            if (
+                fusable
+                and rkey is not None
+                and self.ctx.config.result_cache_entries > 0
+            ):
                 # capture the merged host state alongside the serial
                 # execution so the next append refreshes this entry by
-                # scanning only the delta
+                # scanning only the delta (with the cache off nothing
+                # would store it, and the fetch may carry HLL register
+                # histograms instead of registers)
                 with span(SPAN_ENGINE, backend="device"), \
                         self.ctx.engine.state_capture() as cap:
                     df = self.ctx.engine.execute(q, ds)

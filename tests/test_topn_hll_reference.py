@@ -284,10 +284,10 @@ def _walk(node):
 @pytest.mark.parametrize("sketch", [True, False], ids=["hyperUnique", "sum-only"])
 def test_sketch_requests_carry_their_span_counter_and_arena_decline(served, sketch):
     """A sketch request's receipt holds `sketch_estimate` and its
-    `QueryMetrics.sketch_state_bytes` are the fetched registers; the
-    dispatches of a scope the arena would have taken say
-    `arena="declined:sketch"`.  A request without sketches has none of
-    it and reads 0."""
+    `QueryMetrics.sketch_state_bytes` are the fetched register histogram
+    (PR 40: 23 integers a group at p = 11); the segment dispatches of a
+    scope the arena would have taken say `arena="declined:sketch"`.  A
+    request without sketches has none of it and reads 0."""
     ctx, srv, tables = served
     q = _native("hll_a", qid=f"obs-{sketch}")
     if not sketch:
@@ -297,11 +297,14 @@ def test_sketch_requests_carry_their_span_counter_and_arena_decline(served, sket
     doc = ctx.tracer.ring.get(f"obs-{sketch}")
     m = ctx.last_metrics
     spans = doc["receipt"]["spans"]
-    dispatches = [s for s in _walk(doc["spans"]) if s["name"] == "segment_dispatch"]
+    dispatches = [
+        s for s in _walk(doc["spans"])
+        if s["name"] == "segment_dispatch" and "sketch" not in s["attrs"]
+    ]
     declined = [s for s in dispatches if s["attrs"].get("arena") == "declined:sketch"]
     if sketch:
         assert spans["sketch_estimate"]["n"] == 1
-        assert m.sketch_state_bytes == m.num_groups * 2048 * 4 > 0
+        assert m.sketch_state_bytes == m.num_groups * 23 * 4 > 0
         assert dispatches and declined == dispatches  # 8 segments, 4 batches
     else:
         assert "sketch_estimate" not in spans
