@@ -810,8 +810,9 @@ def bench_sketch_mesh(scale: float):
     for name, q in queries.items():
         eng = Engine()
         # raw partial sketch states from BOTH executors
-        _, la, G, _, _, _, sk_single = eng._partials_for_query(q, ds)
-        sk_single = {k: np.asarray(v) for k, v in jax.device_get(sk_single).items()}
+        lowering = eng._lowering_for(q, ds)
+        la, G = lowering.la, lowering.num_groups
+        sk_single = jax.device_get(eng._partials_for_query(q, ds).sketches)
         low = dist._lowering_for(q, ds)
         from spark_druid_olap_tpu.exec.metrics import QueryMetrics
 

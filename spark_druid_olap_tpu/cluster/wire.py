@@ -1,12 +1,8 @@
 """Cluster wire codec: the host partial-state currency over HTTP.
 
-The broker/historical RPC moves exactly the interchange currency the
-unified executor core already defines (exec/engine.py):
-
-    {"sums": f64[G, A], "mins": f64[G, M], "maxs": f64[G, M],
-     "sketches": {name: i8/u8[G, W]}}
-
-encoded as JSON — per-array dtype + shape + base64 payload — because
+The broker/historical RPC moves the host `PartialState` (exec/state.py)
+as one JSON document, `{"sums", "mins", "maxs", "sketches": {name: ...}}`,
+each array as its dtype + shape + base64 payload — because
 the historical surface is the existing stdlib HTTP server and JSON is
 its wire format.  Decode is STRICT: a torn body (the
 `cluster.torn_response` fault site truncates mid-payload), a missing
@@ -34,6 +30,8 @@ import json
 from typing import Dict, Optional
 
 import numpy as np
+
+from ..exec.state import PartialState
 
 __all__ = [
     "WireDecodeError",
@@ -99,18 +97,17 @@ def _decode_array(doc) -> np.ndarray:
     return np.frombuffer(raw, dtype=dtype).reshape(shape).copy()
 
 
-def encode_state(state: dict) -> dict:
-    """Host partial-state dict -> JSON-safe document."""
-    doc = {k: _encode_array(state[k]) for k in _STATE_KEYS}
+def encode_state(state: PartialState) -> dict:
+    """Host partial state -> JSON-safe document."""
+    doc = {k: _encode_array(getattr(state, k)) for k in _STATE_KEYS}
     doc["sketches"] = {
-        str(name): _encode_array(arr)
-        for name, arr in (state.get("sketches") or {}).items()
+        str(name): _encode_array(arr) for name, arr in state.sketches.items()
     }
     return doc
 
 
-def decode_state(doc) -> Dict[str, object]:
-    """JSON document -> host partial-state dict (strict; raises
+def decode_state(doc) -> PartialState:
+    """JSON document -> host partial state (strict; raises
     `WireDecodeError` on anything short of a complete valid state)."""
     if not isinstance(doc, dict):
         raise WireDecodeError(
@@ -119,14 +116,13 @@ def decode_state(doc) -> Dict[str, object]:
     missing = [k for k in _STATE_KEYS if k not in doc]
     if missing:
         raise WireDecodeError(f"state doc missing keys {missing}")
-    state = {k: _decode_array(doc[k]) for k in _STATE_KEYS}
+    sums, mins, maxs = (_decode_array(doc[k]) for k in _STATE_KEYS)
     sk = doc.get("sketches")
     if sk is not None and not isinstance(sk, dict):
         raise WireDecodeError("sketches member is not a dict")
-    state["sketches"] = {
+    return PartialState(sums, mins, maxs, {
         str(name): _decode_array(arr) for name, arr in (sk or {}).items()
-    }
-    return state
+    })
 
 
 # ---------------------------------------------------------------------------

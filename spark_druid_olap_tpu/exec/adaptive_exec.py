@@ -65,18 +65,14 @@ from ..obs import (
 from ..plan.cost import presence_kernels, shape_kernel
 from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
-from .finalize import (
-    estimable_sketch_states,
-    finalize_groupby,
-    state_nbytes,
-)
+from .finalize import finalize_groupby
 from .lowering import (
     GroupByLowering,
     ResolvedDim,
     _query_key,
-    empty_partials,
     memo_key,
 )
+from .state import PartialState, estimable, sketch_nbytes, to_host
 
 log = get_logger("exec.adaptive")
 
@@ -553,11 +549,8 @@ class AdaptiveDomainMixin:
                 pc.add_scope(len(segs), *_row_counts(segs))
                 pc.add_seen(len(segs), *_row_counts(segs))
             la = lowering.la
-            sums, mins, maxs, sketch_states = empty_partials(la, 0)
             df = finalize_groupby(
-                q, lowering.dims, la,
-                np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                {k: np.asarray(v) for k, v in sketch_states.items()},
+                q, lowering.dims, la, to_host(PartialState.empty(la, 0))
             )
             return lambda: df
 
@@ -580,24 +573,17 @@ class AdaptiveDomainMixin:
         )
 
         def resolve():
-            dims, la, G, sums, mins, maxs, sketch_states = state
             # nothing merges this state after the fetch (the tier never
             # captures it): HLL registers cross as their histograms
-            sketch_states = estimable_sketch_states(la, sketch_states)
+            dev = estimable(clow.la, state)
             with span(SPAN_DEVICE_FETCH):
-                prof.fetch_sync((sums, mins, maxs, sketch_states))
-                sums, mins, maxs, sketch_states = jax.device_get(
-                    (sums, mins, maxs, sketch_states)
-                )
+                prof.fetch_sync(dev)
+                host = to_host(dev)
             if self._m is not None:
-                self._m.sketch_state_bytes = state_nbytes(sketch_states)
+                self._m.sketch_state_bytes = sketch_nbytes(host)
             t0 = time.perf_counter()
             with span(SPAN_FINALIZE):
-                out = finalize_groupby(
-                    q, dims, la,
-                    np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                    {k: np.asarray(v) for k, v in sketch_states.items()},
-                )
+                out = finalize_groupby(q, clow.dims, clow.la, host)
             if self._m is not None:
                 self._m.finalize_ms = (time.perf_counter() - t0) * 1e3
             return out

@@ -404,7 +404,7 @@ def finish_member(carry_i):
     """Final batch->total flush of one member's carry (the loop path's
     last host-side fold).  Returns (sums, mins, maxs, live) — `live` is
     False when the member touched no block (empty scope: the caller
-    substitutes `empty_partials`, exactly like the loop path)."""
+    substitutes `PartialState.empty`, exactly like the loop path)."""
     import jax.numpy as jnp
 
     t_s, t_mn, t_mx, t_live, b_s, b_mn, b_mx, b_live = carry_i

@@ -49,7 +49,6 @@ from .lowering import (  # noqa: F401
     _decoded_expr_fn,
     _filter_columns,
     _query_key,
-    empty_partials,
     groupby_with_time_granularity,
     lower_groupby,
     memo_key,
@@ -59,16 +58,20 @@ from .lowering import (  # noqa: F401
 )
 from .finalize import (  # noqa: F401
     _eval_having,
-    _merge_sketch_states,
     apply_limit_spec,
-    estimable_sketch_states,
     eval_post_agg,
     finalize_groupby,
     finalize_timeseries,
     finalize_topn,
-    histogram_states,
     rollup_states,
-    state_nbytes,
+)
+from .state import (
+    PartialState,
+    estimable,
+    merge,
+    sketch_nbytes,
+    to_host,
+    with_histograms,
 )
 from ..obs import (
     SCOPE_CARRY_MERGE,
@@ -139,22 +142,6 @@ def _row_counts(segs) -> Tuple[int, int]:
         if isinstance(s, DeltaSegment):
             delta += s.num_rows
     return rows, delta
-
-
-def _pack_host_state(sums, mins, maxs, sketches=None) -> dict:
-    """Canonical HOST partial-state dict — the interchange currency of
-    the unified executor core: the result cache stores it,
-    merge_groupby_states ⊕'s it, finalize_groupby_state renders it, and
-    the mesh executor (parallel/distributed) returns the identical shape
-    from its collective merge, so every consumer stays backend-agnostic."""
-    return {
-        "sums": np.asarray(sums),
-        "mins": np.asarray(mins),
-        "maxs": np.asarray(maxs),
-        "sketches": {
-            k: np.asarray(v) for k, v in (sketches or {}).items()
-        },
-    }
 
 
 def _outside_codes(dim, codes):
@@ -464,7 +451,7 @@ def _segment_partials(
             else:
                 # the rows, folded once a batch (`_batch_partials`)
                 sk[agg.name] = (theta_ops.theta_hash(agg, cols), gid, amask)
-    return s, mn, mx, sk
+    return PartialState(s, mn, mx, sk)
 
 
 def _theta_aggs(la) -> list:
@@ -489,29 +476,27 @@ def _batch_partials(lowering, strategy, cols_seq, memo=None, shares=None):
     without a theta aggregation traces what the per-segment loop did."""
     la = lowering.la
     thetas = _theta_aggs(la)
-    sums = mins = maxs = None
-    sketch_states: Dict[str, Any] = {}
+    state = None
     rows: Dict[str, list] = {}  # a theta aggregation's rows a segment
     for i, cols in enumerate(cols_seq):
-        s, mn, mx, sk = _segment_partials(
+        seg = _segment_partials(
             lowering, strategy, cols, memo=memo,
             share=None if shares is None else shares[i],
         )
         for agg in thetas:
-            rows.setdefault(agg.name, []).append(sk.pop(agg.name))
+            rows.setdefault(agg.name, []).append(seg.sketches.pop(agg.name))
         with device_scope(SCOPE_CARRY_MERGE):
-            sums = s if sums is None else sums + s
-            mins = mn if mins is None else jnp.minimum(mins, mn)
-            maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-            _merge_sketch_states(la, sketch_states, sk)
+            state = merge(la, state, seg)
     if rows:  # none where a fused member has no segment in the batch
+        sketches = dict(state.sketches)
         with device_scope(SCOPE_SKETCH_FOLD):
             for agg in thetas:
                 hs, gids, masks = zip(*rows[agg.name])
-                sketch_states[agg.name] = theta_ops.batch_theta(
+                sketches[agg.name] = theta_ops.batch_theta(
                     hs, gids, masks, lowering.num_groups, agg.size
                 )
-    return sums, mins, maxs, sketch_states
+        state = state._replace(sketches=sketches)
+    return state
 
 
 def _default_device_budget() -> int:
@@ -554,7 +539,60 @@ def groupby_family(q: Q.QuerySpec, ds: DataSource):
     return None, None
 
 
-class Engine(AdaptiveDomainMixin, SparseExecMixin):
+class HostStateSurface:
+    """The host partial-state surface both engines (this one and
+    `parallel.distributed.DistributedEngine`) serve to the result cache
+    and the cluster broker: state capture, ⊕ and finalize of host
+    `PartialState`s.  Either engine's state merges with the other's."""
+
+    @contextlib.contextmanager
+    def state_capture(self):
+        """Capture the merged HOST partial state of the next execution on
+        this thread (the dense path, on the mesh the SPMD arena, stashes
+        it just before finalize).  Yields a dict whose "state" key holds
+        the capture — None when the execution took a path with no dense
+        state (sparse/adaptive/fallback, the mesh's legacy paths) or was
+        deadline-truncated (a partial state must never seed the
+        delta-aware result cache)."""
+        holder = {"state": None}
+        self._m_local.capture = holder
+        try:
+            yield holder
+        finally:
+            self._m_local.capture = None
+
+    def _family_lowering(self, q: Q.QuerySpec, ds: DataSource):
+        """(inner GroupBy, result shaper, lowering) of a GroupBy-family
+        query; ValueError for any other type."""
+        inner, shape = groupby_family(q, ds)
+        if inner is None:
+            raise ValueError(f"{type(q).__name__} has no partial state")
+        inner = groupby_with_time_granularity(inner)
+        return inner, shape, self._lowering_for(inner, ds)
+
+    def merge_groupby_states(self, q: Q.QuerySpec, ds: DataSource, a, b):
+        """a ⊕ b, two host partial states of the SAME query over the same
+        dictionary domain.  Raises ValueError on a shape mismatch (a
+        dictionary change reshapes G — callers treat that as a cache
+        miss)."""
+        if a.sums.shape != b.sums.shape:
+            raise ValueError(
+                f"partial-state shape mismatch {a.sums.shape} vs "
+                f"{b.sums.shape} (dictionary domain changed)"
+            )
+        _, _, lowering = self._family_lowering(q, ds)
+        return merge(lowering.la, a, b)
+
+    def finalize_groupby_state(self, q: Q.QuerySpec, ds: DataSource, state):
+        """Host partial state -> the query's final result frame (the same
+        finalize the live execution path runs)."""
+        inner, shape, lowering = self._family_lowering(q, ds)
+        with span(SPAN_FINALIZE):
+            df = finalize_groupby(inner, lowering.dims, lowering.la, state)
+        return shape(df)
+
+
+class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
     """Executes query specs on the local device set.
 
     Which kernel runs is plan/cost.py's to say.  A planned query brings
@@ -934,14 +972,13 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         with none (None) has it resolved here.  `span_attrs` go on every
         dispatch span (the adaptive tier marks its phase B with them).
 
-        Returns (dims, la, G, sums[G, Ms], mins, maxs, sketch_states)."""
+        Returns the merged device `PartialState`."""
         if lowering is None:
             lowering = self._lowering_for(q, ds)
-        dims, la, G = lowering.dims, lowering.la, lowering.num_groups
+        la, G = lowering.la, lowering.num_groups
         need = lowering.columns
 
-        sums = mins = maxs = None
-        sketch_states: Dict[str, Any] = {}
+        state = None
         if segs is None:
             segs = self._segments_in_scope(q, ds)
         pc = current_partial()
@@ -953,8 +990,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             if pc is not None:
                 pc.begin_pass()
                 pc.add_scope(0, 0)
-            sums, mins, maxs, sketch_states = empty_partials(la, G)
-            return dims, la, G, sums, mins, maxs, sketch_states
+            return PartialState.empty(la, G)
         # deadline-bounded partial answers: declare the pass's scope so a
         # mid-scan expiry can stamp an honest coverage fraction onto the
         # merged partials (resilience.PartialCollector)
@@ -965,13 +1001,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # cross-segment merge inside; built lazily below — the arena may
         # cover the whole scope, needing no per-batch program at all)
 
-        def fold(st):
-            nonlocal sums, mins, maxs
-            s, mn, mx, sk = st
-            sums = s if sums is None else sums + s
-            mins = mn if mins is None else jnp.minimum(mins, mn)
-            maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-            _merge_sketch_states(la, sketch_states, sk)
+        def fold(new):
+            nonlocal state
+            state = merge(la, state, new)
 
         batches = list(self._segment_batches(segs, need, la))
         # transfer pipeline (exec/pipeline.py): residency-aware dispatch
@@ -1026,8 +1058,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 checkpoint_site="engine.segment_loop", span_attrs=phase,
             )
             batches = plan.remainder
-            if plan.folded:
-                sums, mins, maxs, _live = states[0]
+            if plan.folded:  # the arena declines sketches
+                state = PartialState(*states[0][:3], {})
             if plan.folded < len(plan.batches):
                 # truncated mid-arena: the remainder must not run
                 # (and its pending prefetch cancels with it)
@@ -1057,43 +1089,57 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             if checkpoint_partial("engine.segment_loop"):
                 run.cancel()
                 break
-            batch = batches[bi]
-            with span(SPAN_H2D, batch=bi, segments=len(batch)):
-                cols_list = [
-                    self._cols_for_segment(seg, ds, need) for seg in batch
-                ]
-            run.advance(pos)
-            with span(
-                SPAN_SEGMENT_DISPATCH, batch=bi, segments=len(batch),
-                **phase, **_theta_attrs(n_theta, cols_list),
-            ):
-                s, mn, mx, sk = self._call_segment_program(
-                    seg_fn, cols_list
-                )
-            if self._m is not None:
-                self._m.theta_folds += n_theta
-            folder.add(bi, (s, mn, mx, sk))
-            if pc is not None:
-                pc.add_seen(len(batch), *_row_counts(batch))
+            folder.add(bi, self._dispatch_batch(
+                seg_fn, ds, need, batches[bi], bi, run, pos, pc, n_theta,
+                phase,
+            ))
         # a truncation can leave batches dispatched AHEAD of canonical
         # order un-folded: drain them (still canonical) so every batch
         # pc accounted merges
         folder.drain()
-        if sums is None:
+        if state is None:
             # the deadline expired before the FIRST batch dispatched: the
             # well-formed zero-coverage answer is the empty partial state
-            sums, mins, maxs, sketch_states = empty_partials(la, G)
-        return dims, la, G, sums, mins, maxs, sketch_states
+            state = PartialState.empty(la, G)
+        return state
+
+    def _dispatch_batch(
+        self, seg_fn, ds, need, batch, bi, run, pos, pc, n_theta, phase
+    ) -> PartialState:
+        """One batch of a single-query loop: its columns to the device
+        (`h2d`), the pipeline's next step (`run.advance(pos)`), the
+        program's launch (`segment_dispatch`, with `phase` as attrs), and
+        the batch counted as seen.  Returns the batch's state."""
+        with span(SPAN_H2D, batch=bi, segments=len(batch)):
+            cols_list = [
+                self._cols_for_segment(seg, ds, need) for seg in batch
+            ]
+        run.advance(pos)
+        with span(
+            SPAN_SEGMENT_DISPATCH, batch=bi, segments=len(batch),
+            **phase, **_theta_attrs(n_theta, cols_list),
+        ):
+            state = self._call_segment_program(seg_fn, cols_list)
+        if self._m is not None:
+            self._m.theta_folds += n_theta
+        if pc is not None:
+            pc.add_seen(len(batch), *_row_counts(batch))
+        return state
 
     def _call_segment_program(self, seg_fn, cols_list):
         """Run one segment program over a list of per-segment column
         dicts.  A kernel the compiler refuses raises like any other
         static error: there is no second path to hide it behind."""
-        import time as _time
-
         # fault-injection site: an injected (or real pre-dispatch)
         # transient fault reaches the retry/breaker machinery
         fire("device_dispatch")
+        return self._timed_launch(seg_fn, cols_list)
+
+    def _timed_launch(self, fn, cols_list):
+        """Launch a program; its first call on a newly built program (a
+        trace and compile) is this thread's `compile_ms`."""
+        import time as _time
+
         m = self._m  # one read: this thread's in-flight metrics object
         # first call of a newly-built program = trace+compile (+async
         # dispatch); attribute it to compile_ms (see metrics.py)
@@ -1105,7 +1151,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
             else None
         )
         t_call = _time.perf_counter()
-        result = seg_fn(cols_list)
+        result = fn(cols_list)
         # sampled query: block here so the enclosing dispatch span
         # splits into enqueue vs device-complete time (obs/prof.py);
         # a literal no-op at the default sample rate of 0
@@ -1266,22 +1312,11 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         acc: List[Any] = [None] * n
         n_theta = [len(_theta_aggs(m[3].la)) for m in members]
         theta_folds = [0] * n
-        acc_sk: List[Dict[str, Any]] = [{} for _ in range(n)]
 
         def fold(outs):
-            for i, (s, mn, mx, sk) in enumerate(outs):
-                if s is None:
-                    continue
-                if acc[i] is None:
-                    acc[i] = (s, mn, mx)
-                else:
-                    ps, pmn, pmx = acc[i]
-                    acc[i] = (
-                        ps + s,
-                        jnp.minimum(pmn, mn),
-                        jnp.maximum(pmx, mx),
-                    )
-                _merge_sketch_states(members[i][3].la, acc_sk[i], sk)
+            for i, st in enumerate(outs):
+                if st is not None:  # None: no member segment in the batch
+                    acc[i] = merge(members[i][3].la, acc[i], st)
 
         try:
             from .pipeline import CanonicalFold
@@ -1331,7 +1366,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     # membership is host-known: a member with no covered
                     # block keeps acc[i] = None (the loop's None-skip)
                     if len(plan.segs) and memb[:, i].any():
-                        acc[i] = states[i][:3]
+                        acc[i] = PartialState(*states[i][:3], {})
                 batches = plan.remainder
             # transfer pipeline: resident batches dispatch first, cold
             # batches' columns stream behind the fused compute; the
@@ -1369,20 +1404,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     SPAN_SEGMENT_DISPATCH, batch=bi, segments=len(batch),
                     fused=n,
                 ):
-                    t_c = (
-                        _time.perf_counter()
-                        if not batch_m.program_cache_hit
-                        and batch_m.compile_ms == 0
-                        else None
-                    )
-                    t_call = _time.perf_counter()
-                    outs = fn(cols_list)
-                    outs = prof.dispatch_sync(outs, t_call)
-                    if t_c is not None:
-                        batch_m.compile_ms = (
-                            (_time.perf_counter() - t_c) * 1e3
-                        )
-                        prof.note_compile(batch_m.compile_ms)
+                    outs = self._timed_launch(fn, cols_list)
                 folder.add(bi, outs)
                 for i in range(n):
                     theta_folds[i] += n_theta[i] if sel[i] else 0
@@ -1394,30 +1416,21 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         # as the live states (a per-member fetch would re-pay the device
         # round trip the fused batch exists to amortize)
         empties = {
-            i: empty_partials(m[3].la, m[3].num_groups)
+            i: PartialState.empty(m[3].la, m[3].num_groups)
             for i, m in enumerate(members)
             if acc[i] is None
         }
         with span(SPAN_DEVICE_FETCH, fused=n):
             prof.fetch_sync(acc)
-            host = jax.device_get((acc, acc_sk, empties))
-        acc_h, sk_h, empties_h = host
+            acc_h, empties_h = to_host((acc, empties))
         out = []
         elapsed_ms = (_time.perf_counter() - t0) * 1e3
         # graftlint: disable=checkpoint-coverage -- demux loop: all device states are already fetched; discarding finished answers at expiry would re-pay the whole batch
         for i, (q, inner, shape, lowering, segs) in enumerate(members):
-            la, G = lowering.la, lowering.num_groups
-            if acc_h[i] is None:
-                sums, mins, maxs, sk = empties_h[i]
-            else:
-                sums, mins, maxs = acc_h[i]
-                sk = sk_h[i]
-            state = _pack_host_state(sums, mins, maxs, sk)
+            state = empties_h[i] if acc_h[i] is None else acc_h[i]
             with span(SPAN_FINALIZE, member=i):
                 df = shape(finalize_groupby(
-                    inner, lowering.dims, la,
-                    state["sums"], state["mins"], state["maxs"],
-                    state["sketches"],
+                    inner, lowering.dims, lowering.la, state
                 ))
             try:
                 qt = q.to_druid().get("queryType", type(q).__name__)
@@ -1432,7 +1445,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 rows_scanned=rows,
                 bytes_scanned=_bytes_scanned(segs, lowering.columns),
                 segments=len(segs),
-                num_groups=G,
+                num_groups=lowering.num_groups,
                 # the batch's shared h2d/compile split evenly: the fused
                 # program moved ONE column set for all members
                 h2d_bytes=batch_m.h2d_bytes // n,
@@ -1542,21 +1555,6 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
 
     # -- host partial-state surface (delta-aware result cache, ISSUE 8) -----
 
-    @contextlib.contextmanager
-    def state_capture(self):
-        """Capture the merged HOST partial state of the next execution on
-        this thread (the dense resolve path stashes it just before
-        finalize).  Yields a dict whose "state" key holds the capture —
-        None when the execution took a path with no dense state (sparse/
-        adaptive/fallback) or was deadline-truncated (a partial state
-        must never seed the delta-aware result cache)."""
-        holder = {"state": None}
-        self._m_local.capture = holder
-        try:
-            yield holder
-        finally:
-            self._m_local.capture = None
-
     def groupby_partials_host(
         self, q: Q.QuerySpec, ds: DataSource, within_uids=None
     ):
@@ -1565,64 +1563,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         full scope).  The delta-aware result cache calls this with the
         freshly-appended uids so a dashboard refresh after an append
         scans ONLY the delta.  Returns (state, rows_scanned)."""
-        inner, _ = groupby_family(q, ds)
-        if inner is None:
-            raise ValueError(f"{type(q).__name__} has no partial state")
-        inner = groupby_with_time_granularity(inner)
-        lowering = self._lowering_for(inner, ds)
+        inner, _, lowering = self._family_lowering(q, ds)
         segs = self._segments_in_scope(inner, ds)
         if within_uids is not None:
             within_uids = frozenset(within_uids)
             segs = [s for s in segs if s.uid in within_uids]
-        dims, la, G, sums, mins, maxs, sk = self._partials_for_query(
+        state = self._partials_for_query(
             inner, ds, lowering=lowering, segs=segs
         )
-        sums, mins, maxs, sk = jax.device_get((sums, mins, maxs, sk))
-        state = _pack_host_state(sums, mins, maxs, sk)
-        return state, sum(s.num_rows for s in segs)
-
-    def merge_groupby_states(self, q: Q.QuerySpec, ds: DataSource, a, b):
-        """⊕ of two host partial states of the SAME query over the same
-        dictionary domain (the partial-aggregate-state algebra): sums
-        add, mins/maxs fold, sketches merge by type.  Raises ValueError
-        on a shape mismatch (a dictionary change reshapes G — callers
-        treat that as a cache miss)."""
-        if a["sums"].shape != b["sums"].shape:
-            raise ValueError(
-                f"partial-state shape mismatch {a['sums'].shape} vs "
-                f"{b['sums'].shape} (dictionary domain changed)"
-            )
-        inner, _ = groupby_family(q, ds)
-        lowering = self._lowering_for(
-            groupby_with_time_granularity(inner), ds
-        )
-        merged = {
-            "sums": a["sums"] + b["sums"],
-            "mins": np.minimum(a["mins"], b["mins"]),
-            "maxs": np.maximum(a["maxs"], b["maxs"]),
-            "sketches": dict(a["sketches"]),
-        }
-        _merge_sketch_states(lowering.la, merged["sketches"], b["sketches"])
-        merged["sketches"] = {
-            k: np.asarray(v) for k, v in merged["sketches"].items()
-        }
-        return merged
-
-    def finalize_groupby_state(self, q: Q.QuerySpec, ds: DataSource, state):
-        """Host partial state -> the query's final result frame (the same
-        finalize the live execution path runs)."""
-        inner, shape = groupby_family(q, ds)
-        inner = groupby_with_time_granularity(inner)
-        lowering = self._lowering_for(inner, ds)
-        with span(SPAN_FINALIZE):
-            df = finalize_groupby(
-                inner, lowering.dims, lowering.la,
-                np.asarray(state["sums"]),
-                np.asarray(state["mins"]),
-                np.asarray(state["maxs"]),
-                {k: np.asarray(v) for k, v in state["sketches"].items()},
-            )
-        return shape(df)
+        return to_host(state), sum(s.num_rows for s in segs)
 
     def _execute_groupby(
         self, q: Q.GroupByQuery, ds: DataSource, strategy=None, cfg=None
@@ -1710,7 +1659,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
     def _rollup_program(self, uq, ds, lowering, rollup) -> Callable:
         """The rollup's one program for (union query, sets): the union's
         merged state -> each set's state, HLL registers leaving as their
-        register histograms (what `estimable_sketch_states` makes on the
+        register histograms (what `exec/state.py estimable` makes on the
         other paths).  Cached under its own "rollup"-tagged key family."""
         union, sets = rollup.union, rollup.sets
         key = _query_key(uq, ds) + ("rollup", union, sets)
@@ -1728,10 +1677,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         @jax.jit
         def rollup_fn(state):
             return [
-                (sums, mins, maxs, histogram_states(la, sketches))
-                for sums, mins, maxs, sketches in rollup_states(
-                    la, cards, union, sets, state
-                )
+                with_histograms(la, s)
+                for s in rollup_states(la, cards, union, sets, state)
             ]
 
         self._query_fn_cache[key] = rollup_fn
@@ -1987,8 +1934,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                         segs=segs,
                     )
                 t_fetch = _time.perf_counter()
-                dims, la, G, *state = dense_state
-                dense_state = None  # free the device partials promptly
+                dims, la = lowering.dims, lowering.la
+                state, dense_state = dense_state, None  # freed promptly
                 # state capture (serve/result_cache.py delta-aware reuse)
                 # merges the registers later: only without it does the
                 # fetch carry HLL register histograms instead.  A
@@ -1999,15 +1946,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     holder = None
                     program = self._rollup_program(q, ds, lowering, rollup)
                     with span(SPAN_SEGMENT_DISPATCH, rollup=len(rollup.sets)):
-                        parts = self._call_segment_program(program, tuple(state))
+                        parts = self._call_segment_program(program, state)
                     targets = [
                         (sq, [dims[rollup.union.index(d)] for d in s])
                         for sq, s in zip(rollup.queries, rollup.sets)
                     ]
                 else:
                     if holder is None:
-                        state[3] = estimable_sketch_states(la, state[3])
-                    parts, targets = [tuple(state)], [(q, dims)]
+                        state = estimable(la, state)
+                    parts, targets = [state], [(q, dims)]
                 del state
                 # ONE device_get for everything: each separate host fetch
                 # of a device buffer pays a full round trip; a single
@@ -2016,8 +1963,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                     # sampled query: separate device-wait from the host
                     # copy inside the fetch span (obs/prof.py)
                     prof.fetch_sync(parts)
-                    parts = jax.device_get(parts)
-                m.sketch_state_bytes = sum(state_nbytes(p[3]) for p in parts)
+                    parts = to_host(parts)
+                m.sketch_state_bytes = sum(sketch_nbytes(p) for p in parts)
                 # state capture: stash the merged HOST state for the
                 # caller — only on this dense path (sparse/adaptive
                 # returned above) and only when the scan was NOT
@@ -2027,7 +1974,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 if holder is not None and (
                     pc_cap is None or not pc_cap.triggered
                 ):
-                    holder["state"] = _pack_host_state(*parts[0])
+                    holder["state"] = parts[0]
                 # the phase-1 dispatch share (minus its h2d/compile) plus
                 # this query's own fetch wait is the device time; overlap
                 # hidden behind other queries' resolves is deliberately NOT
@@ -2041,14 +1988,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                 )
                 t0 = _time.perf_counter()
                 frames = []
-                for (sq, sdims), (sums, mins, maxs, sk) in zip(targets, parts):
+                for (sq, sdims), part in zip(targets, parts):
                     with span(SPAN_FINALIZE):
-                        frames.append(finalize_groupby(
-                            sq, sdims, la,
-                            np.asarray(sums), np.asarray(mins),
-                            np.asarray(maxs),
-                            {k: np.asarray(v) for k, v in sk.items()},
-                        ))
+                        frames.append(finalize_groupby(sq, sdims, la, part))
                 m.finalize_ms = (_time.perf_counter() - t0) * 1e3
                 return frames if rollup is not None else frames[0]
             except BaseException as err:
@@ -2409,8 +2351,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
         if pc is not None:
             pc.begin_pass()
             pc.add_scope(len(segs), rows_total, delta_total)
-        sums = mins = maxs = None
-        sketch_states: Dict[str, Any] = {}
+        state = None
         rows_seen = 0
         seen_segs = 0
         seq = 0
@@ -2431,43 +2372,18 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                         run.cancel()
                         truncated = True
                         break
-                    with span(SPAN_H2D, batch=bi, segments=len(batch)):
-                        cols_list = [
-                            self._cols_for_segment(seg, ds, need)
-                            for seg in batch
-                        ]
-                    run.advance(bi)
-                    with span(
-                        SPAN_SEGMENT_DISPATCH, batch=bi,
-                        segments=len(batch),
-                        **_theta_attrs(n_theta, cols_list),
-                    ):
-                        s, mn, mx, sk = self._call_segment_program(
-                            seg_fn, cols_list
-                        )
-                    m.theta_folds += n_theta
-                    sums = s if sums is None else sums + s
-                    mins = mn if mins is None else jnp.minimum(mins, mn)
-                    maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-                    _merge_sketch_states(la, sketch_states, sk)
-                    br, bd = _row_counts(batch)
-                    rows_seen += br
+                    state = merge(la, state, self._dispatch_batch(
+                        seg_fn, ds, need, batch, bi, run, bi, pc, n_theta,
+                        {},
+                    ))
+                    rows_seen += _row_counts(batch)[0]
                     seen_segs += len(batch)
-                    if pc is not None:
-                        pc.add_seen(len(batch), br, bd)
                     final = bi + 1 == len(batches)
                     with span(SPAN_DEVICE_FETCH, batch=bi):
                         # graftlint: disable=trace-purity -- per-batch fetch IS progressive streaming: each refinement ships the running state to the client
-                        hs, hmn, hmx, hsk = jax.device_get(
-                            (sums, mins, maxs, sketch_states)
-                        )
+                        host = to_host(state)
                     with span(SPAN_FINALIZE, batch=bi):
-                        df = shape(finalize_groupby(
-                            inner, dims, la,
-                            np.asarray(hs), np.asarray(hmn),
-                            np.asarray(hmx),
-                            {k: np.asarray(v) for k, v in hsk.items()},
-                        ))
+                        df = shape(finalize_groupby(inner, dims, la, host))
                     yield df, {
                         "sequence": seq,
                         "coverage": (
@@ -2481,21 +2397,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin):
                         "partial": False,
                     }
                     seq += 1
-            if not segs or truncated or sums is None:
+            if not segs or truncated or state is None:
                 # empty scope, or a deadline cut the scan short: emit the
                 # (possibly empty) merged state as the final answer with
                 # its honest coverage
-                if sums is None:
-                    sums, mins, maxs, sketch_states = empty_partials(la, G)
-                hs, hmn, hmx, hsk = jax.device_get(
-                    (sums, mins, maxs, sketch_states)
-                )
+                if state is None:
+                    state = PartialState.empty(la, G)
+                host = to_host(state)
                 with span(SPAN_FINALIZE):
-                    df = shape(finalize_groupby(
-                        inner, dims, la,
-                        np.asarray(hs), np.asarray(hmn), np.asarray(hmx),
-                        {k: np.asarray(v) for k, v in hsk.items()},
-                    ))
+                    df = shape(finalize_groupby(inner, dims, la, host))
                 cov = rows_seen / rows_total if rows_total else (
                     None if truncated else 1.0
                 )

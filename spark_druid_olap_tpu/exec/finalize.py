@@ -3,17 +3,15 @@
 Split out of exec/engine.py (VERDICT r1 weak #8).  Everything that turns
 merged partial aggregate state into the result DataFrame — group-id decode,
 post-aggregations, having, sort/limit, empty-bucket fill, TopN ranking — plus
-the device-state merge helpers shared by the local, distributed, and
-streaming executors (semantics cannot drift when there is one
-implementation).
+the rollup of a grouping-set request's merged state.  The state itself and
+its merge are exec/state.py's.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, List, Mapping, Optional
+from typing import Dict, List, Mapping, Optional
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..catalog.segment import DataSource
@@ -21,14 +19,13 @@ from ..models import aggregations as A
 from ..models import query as Q
 from ..obs import (
     SCOPE_SET_ROLLUP,
-    SCOPE_SKETCH_MERGE,
-    SPAN_SEGMENT_DISPATCH,
     SPAN_SKETCH_ESTIMATE,
     device_scope,
     span,
 )
 from ..utils.granularity import bucket_starts
 from .lowering import LoweredAggs, ResolvedDim
+from .state import PartialState, is_hll
 
 _NO_SPAN = contextlib.nullcontext()
 
@@ -213,73 +210,8 @@ def _eval_having(h: Q.Having, table: Mapping[str, np.ndarray]) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Engine
+# Grouping-set rollup
 # ---------------------------------------------------------------------------
-
-
-def _merge_sketch_states(
-    la: LoweredAggs, acc: Dict[str, Any], new: Dict[str, Any]
-) -> None:
-    """Merge one segment's or batch's sketch states into the accumulator
-    in place: HLL registers max-merge; theta states union (shared with
-    streaming).  An aggregation `new` lacks is left as it is: a batch
-    program folds a theta aggregation's rows apart (engine
-    `_batch_partials`)."""
-    from ..ops import theta as theta_ops
-
-    if not la.sketch_aggs:
-        return
-    with device_scope(SCOPE_SKETCH_MERGE):
-        for agg in la.sketch_aggs:
-            st = new.get(agg.name)
-            if st is None:
-                continue
-            prev = acc.get(agg.name)
-            if prev is None:
-                acc[agg.name] = st
-            elif isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-                acc[agg.name] = jnp.maximum(prev, st)
-            elif isinstance(agg, A.QuantilesSketch):
-                from ..ops import quantiles as quantiles_ops
-
-                acc[agg.name] = quantiles_ops.merge_states(prev, st, agg.size)
-            else:
-                acc[agg.name] = theta_ops.merge_states(prev, st, agg.size)
-
-
-def _is_hll(agg) -> bool:
-    return isinstance(agg, (A.HyperUnique, A.CardinalityAgg))
-
-
-def estimable_sketch_states(
-    la: LoweredAggs, states: Dict[str, Any]
-) -> Dict[str, Any]:
-    """The device sketch states `finalize_groupby` needs, made before the
-    fetch that feeds it: each HLL agg's merged registers int32[G, 2^p]
-    become their register histogram int32[G, 34 - p] on the device
-    (`ops/hll.register_histogram`), so the host fetches and estimates
-    from 34 - p integers a group, not 2^p.  Other sketches pass as they
-    are.  A caller whose state must survive as registers (a captured
-    state, one handed to a merger) does not call this.  The launch is a
-    `segment_dispatch` span (attr `sketch`): it counts as a dispatch."""
-    if not any(_is_hll(a) for a in la.sketch_aggs):
-        return states
-    with span(SPAN_SEGMENT_DISPATCH, sketch="histogram"):
-        return histogram_states(la, states)
-
-
-def histogram_states(la: LoweredAggs, states: Dict[str, Any]) -> Dict[str, Any]:
-    """`states` with each HLL agg's registers replaced by their register
-    histogram (`ops/hll.register_histogram`), eagerly or inside a trace."""
-    from ..ops import hll as hll_ops
-
-    out = dict(states)
-    for agg in la.sketch_aggs:
-        if _is_hll(agg):
-            out[agg.name] = hll_ops.register_histogram(
-                states[agg.name], agg.precision
-            )
-    return out
 
 
 def rollup_states(la: LoweredAggs, cards, union, sets, state):
@@ -294,7 +226,7 @@ def rollup_states(la: LoweredAggs, cards, union, sets, state):
     and maxs fold, HLL registers take the max, theta states take the KMV
     union of a group's children.  A set reduces from the smallest state
     already made that covers it (the lattice), larger sets first.
-    `state` is (sums, mins, maxs, sketches); returns one such a set."""
+    `state` is a `PartialState`; returns one a set."""
     with device_scope(SCOPE_SET_ROLLUP):
         made = {tuple(union): state}
         out = [None] * len(sets)
@@ -332,22 +264,16 @@ def _reduce_state(la: LoweredAggs, cards, src, dst, state):
     out = {}
     for agg in la.sketch_aggs:
         c = children(sketches[agg.name])
-        if _is_hll(agg):
+        if is_hll(agg):
             out[agg.name] = c.max(axis=1)
         else:  # rollup_takes admits HLL and theta sketches only
             out[agg.name] = theta_ops.union_children(c, agg.size)
-    return (
+    return PartialState(
         children(sums).sum(axis=1, dtype=sums.dtype),
         children(mins).min(axis=1),
         children(maxs).max(axis=1),
         out,
     )
-
-
-def state_nbytes(sketch_states: Mapping[str, Any]) -> int:
-    """Bytes of a fetched set of sketch states (`QueryMetrics.
-    sketch_state_bytes`)."""
-    return sum(int(np.asarray(v).nbytes) for v in sketch_states.values())
 
 
 # ---------------------------------------------------------------------------
@@ -359,20 +285,18 @@ def finalize_groupby(
     q: Q.GroupByQuery,
     dims: List[ResolvedDim],
     la: LoweredAggs,
-    sums: np.ndarray,
-    mins: np.ndarray,
-    maxs: np.ndarray,
-    sketch_states: Dict[str, np.ndarray],
+    state: PartialState,
     slot_gids: Optional[np.ndarray] = None,
 ):
-    """Merged partial state -> result DataFrame (decode, post-aggs, having,
-    order/limit) — the broker-side finalization of SURVEY.md §3.3.
+    """Merged host partial state -> result DataFrame (decode, post-aggs,
+    having, order/limit) — the broker-side finalization of SURVEY.md §3.3.
 
     `slot_gids` switches to sparse-state layout (ops/sparse_groupby.py):
     arrays are slot-indexed and slot_gids maps slot -> combined gid (-1 =
     empty slot)."""
     import pandas as pd
 
+    sums, mins, maxs, sketch_states = state
     rows_per_group = sums[:, 0]
     if slot_gids is not None:
         present = (slot_gids >= 0) & (rows_per_group > 0)
@@ -429,11 +353,11 @@ def finalize_groupby(
 
     raw_states: Dict[str, np.ndarray] = {}
     # an HLL state arrives as its register histogram where the fetch made
-    # one (estimable_sketch_states), else as registers: the span says which
+    # one (`state.estimable`), else as registers: the span says which
     fetched = {
         sketch_states[a.name].shape[-1] == hll_ops.histogram_width(a.precision)
         for a in la.sketch_aggs
-        if _is_hll(a)
+        if is_hll(a)
     }
     attrs = (
         {"source": "histogram" if fetched == {True} else "registers"}
@@ -446,7 +370,7 @@ def finalize_groupby(
             from ..ops import theta as theta_ops
 
             st = sketch_states[agg.name][sel]
-            if _is_hll(agg):
+            if is_hll(agg):
                 prec = agg.precision
                 if st.shape[-1] != hll_ops.histogram_width(prec):
                     st = hll_ops.histogram_np(st, prec)

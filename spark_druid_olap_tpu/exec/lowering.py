@@ -697,38 +697,6 @@ def _needed_columns(q, ds: DataSource, dims) -> List[str]:
     return need
 
 
-def empty_partials(la: LoweredAggs, G: int):
-    """Zero-row partial state (identity of every merge class) — shared by
-    the segment-pruned-to-nothing path and the empty-stream path."""
-    sums = jnp.zeros((G, len(la.sum_names)), jnp.float32)
-    mins = jnp.full((G, len(la.min_names)), jnp.inf, jnp.float32)
-    maxs = jnp.full((G, len(la.max_names)), -jnp.inf, jnp.float32)
-    sketch_states: Dict[str, jnp.ndarray] = {}
-    for agg in la.sketch_aggs:
-        if isinstance(agg, (A.HyperUnique, A.CardinalityAgg)):
-            sketch_states[agg.name] = jnp.zeros(
-                (G, 1 << agg.precision), jnp.int32
-            )
-        elif isinstance(agg, A.QuantilesSketch):
-            from ..ops.quantiles import SENTINEL_P
-
-            # [G, K+1, 2]: K empty sample slots + the zero N-counter row
-            pr = jnp.full((G, agg.size), SENTINEL_P, jnp.int32)
-            vb = jnp.zeros((G, agg.size), jnp.int32)
-            sample = jnp.stack([pr, vb], axis=-1)
-            extra = jnp.zeros((G, 1, 2), jnp.int32)
-            sketch_states[agg.name] = jnp.concatenate(
-                [sample, extra], axis=1
-            )
-        else:
-            from ..ops.theta import SENTINEL
-
-            sketch_states[agg.name] = jnp.full(
-                (G, agg.size), SENTINEL, jnp.uint32
-            )
-    return sums, mins, maxs, sketch_states
-
-
 def groupby_with_time_granularity(q: Q.GroupByQuery) -> Q.GroupByQuery:
     """Druid semantics shared by all executors: a non-'all' granularity on
     GroupBy adds an implicit leading time-bucket dimension (one result row

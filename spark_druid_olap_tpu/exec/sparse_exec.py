@@ -18,7 +18,6 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-import numpy as np
 
 from ..catalog.segment import DataSource
 from ..models import query as Q
@@ -27,6 +26,7 @@ from ..plan.cost import sparse_inner_kernel
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
 from .lowering import GroupByLowering, _query_key, memo_key
+from .state import PartialState
 
 log = get_logger("exec.sparse")
 
@@ -347,14 +347,9 @@ class SparseExecMixin:
             if bool(host["overflow"]):
                 return None, "overflow"
             df = finalize_groupby(
-                q,
-                lowering.dims,
-                lowering.la,
-                np.asarray(host["sums"]),
-                np.asarray(host["mins"]),
-                np.asarray(host["maxs"]),
-                {},
-                slot_gids=np.asarray(host["gids"]),
+                q, lowering.dims, lowering.la,
+                PartialState(host["sums"], host["mins"], host["maxs"], {}),
+                slot_gids=host["gids"],
             )
             return df, "ok"
 

@@ -42,8 +42,6 @@ from ..obs import SPAN_PROGRAM_LOOKUP, span_around
 from ..plan.cost import concrete_kernel, shape_kernel
 from .engine import (
     Engine,
-    _merge_sketch_states,
-    empty_partials,
     finalize_groupby,
     finalize_timeseries,
     finalize_topn,
@@ -52,6 +50,7 @@ from .engine import (
     timeseries_to_groupby,
     topn_to_groupby,
 )
+from .state import PartialState, merge, to_host
 
 _STOP = object()
 
@@ -227,8 +226,7 @@ class StreamExecutor:
             strat = self._stream_strategy(G, chunk_rows)
             run = self._fused_local_fn(q, ds, lowering, prep, strat)
 
-        sums = mins = maxs = None
-        sketch_states: Dict[str, jnp.ndarray] = {}
+        state = None
         self.stats = StreamStats()
         t_disp = 0.0
 
@@ -258,35 +256,26 @@ class StreamExecutor:
             with span(SPAN_STREAM_CHUNK, chunk=self.stats.chunks):
                 from ..obs import prof
 
-                s, mn, mx, sk = run(dev, base, nrows)
+                chunk = PartialState(*run(dev, base, nrows))
                 # sampled query: honest device split on the chunk span
                 # (obs/prof.py; a strict no-op at the default rate)
-                s = prof.dispatch_sync(s, t0)
-            sums = s if sums is None else sums + s
-            mins = mn if mins is None else jnp.minimum(mins, mn)
-            maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-            _merge_sketch_states(la, sketch_states, sk)
+                prof.dispatch_sync(chunk.sums, t0)
+            state = merge(la, state, chunk)
             self.stats.chunks += 1
             if pc is not None:
                 pc.add_seen(1, int(nrows))
             t_disp += _time.perf_counter() - t0
         self.stats.dispatch_s = t_disp
 
-        if sums is None:  # empty stream
-            sums, mins, maxs, sketch_states = empty_partials(la, G)
+        if state is None:  # empty stream
+            state = PartialState.empty(la, G)
 
         from ..obs import SPAN_DEVICE_FETCH, SPAN_FINALIZE
 
         with span(SPAN_DEVICE_FETCH):
-            sums, mins, maxs, sketch_states = jax.device_get(
-                (sums, mins, maxs, sketch_states)
-            )
+            state = to_host(state)
         with span(SPAN_FINALIZE):
-            return finalize_groupby(
-                q, lowering.dims, la,
-                np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                {k: np.asarray(v) for k, v in sketch_states.items()},
-            )
+            return finalize_groupby(q, lowering.dims, la, state)
 
     def _stream_strategy(self, G: int, rows_per_dispatch: int) -> str:
         """Per-dispatch kernel.  An engine constructed with an explicit

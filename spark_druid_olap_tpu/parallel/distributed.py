@@ -55,7 +55,6 @@ group-by past one chip's HBM without materializing anything global.
 
 from __future__ import annotations
 
-import contextlib
 import threading as _threading
 import time as _time
 from typing import Dict, List, Optional, Tuple
@@ -69,11 +68,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..catalog.segment import ROW_PAD, DataSource
 from ..exec.engine import (
     GroupByLowering,
-    estimable_sketch_states,
+    HostStateSurface,
     finalize_groupby,
     groupby_family,
     groupby_with_time_granularity,
 )
+from ..exec.state import PartialState, estimable, to_host
 from ..models import aggregations as A
 from ..models import query as Q
 from ..ops import hll as hll_ops
@@ -149,7 +149,7 @@ def _nbytes(tree) -> int:
     return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
-class DistributedEngine:
+class DistributedEngine(HostStateSurface):
     """Executes GroupBy-family queries SPMD over a mesh.
 
     Row shards are built host-side by concatenating ALL segment columns and
@@ -857,9 +857,7 @@ class DistributedEngine:
         # it blocks on the SPMD program, so the ICI merge's wall time is
         # paid here; finalize alone reads the state, so HLL registers
         # cross as their histograms
-        sums, mins, maxs, sk = _fetch(
-            (sums, mins, maxs, estimable_sketch_states(lowering.la, sk))
-        )
+        state = _fetch(estimable(lowering.la, PartialState(*out_state)))
         dt = (_time.perf_counter() - t0) * 1e3
         if m.program_cache_hit:
             m.device_ms = dt
@@ -868,15 +866,7 @@ class DistributedEngine:
             prof.note_compile(dt, family="dense-state")
         t0 = _time.perf_counter()
         with span(SPAN_FINALIZE):
-            out = finalize_groupby(
-                q,
-                lowering.dims,
-                lowering.la,
-                np.asarray(sums),
-                np.asarray(mins),
-                np.asarray(maxs),
-                {k: np.asarray(v) for k, v in sk.items()},
-            )
+            out = finalize_groupby(q, lowering.dims, lowering.la, state)
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
 
@@ -1006,14 +996,9 @@ class DistributedEngine:
             m.compile_ms = (_time.perf_counter() - t0) * 1e3
         t0 = _time.perf_counter()
         out = finalize_groupby(
-            q,
-            lowering.dims,
-            lowering.la,
-            np.asarray(state["sums"]),
-            np.asarray(state["mins"]),
-            np.asarray(state["maxs"]),
-            {},
-            slot_gids=np.asarray(state["gids"]),
+            q, lowering.dims, lowering.la,
+            PartialState(state["sums"], state["mins"], state["maxs"], {}),
+            slot_gids=state["gids"],
         )
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
@@ -1032,7 +1017,6 @@ class DistributedEngine:
             filter_derived_kept,
             remap_form,
         )
-        from ..exec.lowering import empty_partials
 
         # measured kept sets are only valid for the segment set they
         # scanned (a fresh delta may hold codes the scan never saw —
@@ -1088,11 +1072,8 @@ class DistributedEngine:
             # some grouping dim has NO present code under the filter: the
             # exact result is the empty grouped frame
             la = lowering.la
-            sums, mins, maxs, sketch_states = empty_partials(la, 0)
             return finalize_groupby(
-                q, lowering.dims, la,
-                np.asarray(sums), np.asarray(mins), np.asarray(maxs),
-                {k: np.asarray(v) for k, v in sketch_states.items()},
+                q, lowering.dims, la, to_host(PartialState.empty(la, 0))
             )
         clow = compacted_lowering(lowering, kept)
         cards = tuple(d.cardinality for d in clow.dims)
@@ -1360,7 +1341,6 @@ class DistributedEngine:
         if layout is None or lowering.la.sketch_aggs:
             return None
         from ..exec.engine import _row_counts
-        from ..exec.lowering import empty_partials
         from ..resilience import current_deadline, current_partial
 
         la, G = lowering.la, lowering.num_groups
@@ -1369,7 +1349,7 @@ class DistributedEngine:
             if pc is not None:
                 pc.begin_pass()
                 pc.add_scope(0, 0)
-            sums, mins, maxs, _sk = jax.device_get(empty_partials(la, G))
+            state = to_host(PartialState.empty(la, G))
         else:
             canonical = sorted(layout.index[s.uid] for s in scope)
             j_lo, Lk = spmd_arena.scope_window(layout, canonical)
@@ -1406,7 +1386,7 @@ class DistributedEngine:
                     )
                     out_state = _launch(run, cols, np.int32(j_lo), memb)
                 merged = _fetch(out_state[0])
-                sums, mins, maxs, _live = merged
+                state = PartialState(*merged[:3], {})
                 m.collective_bytes += spmd_arena.boundary_merge_bytes(
                     self._arena_mesh(), tree, merged
                 )
@@ -1419,7 +1399,7 @@ class DistributedEngine:
                 if pc is not None:
                     pc.add_seen(len(scope), rows, delta)
             else:
-                sums, mins, maxs = self._arena_spmd_deadline(
+                state = self._arena_spmd_deadline(
                     ds, lowering, m, strategy, layout, cols, memb,
                     canonical, j_lo, Lk, tree, pc,
                 )
@@ -1427,13 +1407,10 @@ class DistributedEngine:
         # the collective — never a deadline-truncated one
         holder = getattr(self._m_local, "capture", None)
         if holder is not None and (pc is None or not pc.triggered):
-            holder["state"] = self._pack_state(sums, mins, maxs)
+            holder["state"] = state
         t0 = _time.perf_counter()
         with span(SPAN_FINALIZE):
-            out = finalize_groupby(
-                q, lowering.dims, la,
-                np.asarray(sums), np.asarray(mins), np.asarray(maxs), {},
-            )
+            out = finalize_groupby(q, lowering.dims, la, state)
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
 
@@ -1481,7 +1458,6 @@ class DistributedEngine:
         ):
             out_state = _launch(merge_fn, carry)
         merged = _fetch(out_state[0])
-        sums, mins, maxs, _live = merged
         m.collective_bytes += spmd_arena.boundary_merge_bytes(
             self._arena_mesh(), tree, merged
         )
@@ -1491,33 +1467,9 @@ class DistributedEngine:
         else:
             m.compile_ms = dt
             prof.note_compile(dt, family="arena-spmd-chunk")
-        return sums, mins, maxs
-
-    @staticmethod
-    def _pack_state(sums, mins, maxs, sketches=None) -> Dict:
-        """Host partial-state dict in the result cache's schema — the
-        engine's canonical packing, so mesh- and single-device-produced
-        states are interchangeable under merge/finalize."""
-        from ..exec.engine import _pack_host_state
-
-        return _pack_host_state(sums, mins, maxs, sketches)
+        return PartialState(*merged[:3], {})
 
     # -- host partial-state surface (delta-aware result cache) ---------------
-
-    @contextlib.contextmanager
-    def state_capture(self):
-        """Capture the merged HOST partial state of the next execution on
-        this thread (the arena path stashes it just before finalize).
-        Yields a dict whose "state" key holds the capture — None when the
-        execution declined to the legacy paths or was deadline-truncated
-        (a partial state must never seed the delta-aware result
-        cache)."""
-        holder = {"state": None}
-        self._m_local.capture = holder
-        try:
-            yield holder
-        finally:
-            self._m_local.capture = None
 
     def groupby_partials_host(
         self, q: Q.QuerySpec, ds: DataSource, within_uids=None
@@ -1529,13 +1481,9 @@ class DistributedEngine:
         the fresh blocks.  Raises ValueError when the query/datasource
         cannot produce mesh partial state (callers treat it as a cache
         decline)."""
-        from ..exec.lowering import empty_partials, memo_key
+        from ..exec.lowering import memo_key
 
-        inner, _ = groupby_family(q, ds)
-        if inner is None:
-            raise ValueError(f"{type(q).__name__} has no partial state")
-        inner = groupby_with_time_granularity(inner)
-        lowering = self._lowering_for(inner, ds)
+        inner, _, lowering = self._family_lowering(q, ds)
         layout = self._arena_layout(ds)
         if layout is None or lowering.la.sketch_aggs:
             raise ValueError(
@@ -1552,7 +1500,7 @@ class DistributedEngine:
             segs = [s for s in segs if s.uid in w]
         la, G = lowering.la, lowering.num_groups
         if not segs:
-            sums, mins, maxs, _sk = jax.device_get(empty_partials(la, G))
+            state = to_host(PartialState.empty(la, G))
         else:
             from ..exec.metrics import QueryMetrics
 
@@ -1567,53 +1515,8 @@ class DistributedEngine:
                 SPAN_SEGMENT_DISPATCH, arena=1, merge_tree=tree, partials=1,
             ):
                 out_state = _launch(run, cols, np.int32(j_lo), memb)
-            sums, mins, maxs, _live = _fetch(out_state[0])
-        state = self._pack_state(sums, mins, maxs)
+            state = PartialState(*_fetch(out_state[0])[:3], {})
         return state, sum(s.num_rows for s in segs)
-
-    def merge_groupby_states(self, q: Q.QuerySpec, ds: DataSource, a, b):
-        """⊕ of two host partial states of the SAME query (the
-        partial-aggregate-state algebra, identical to the local
-        engine's).  Raises ValueError on a shape mismatch (dictionary
-        domain changed — callers treat it as a cache miss)."""
-        from ..exec.engine import _merge_sketch_states
-
-        if a["sums"].shape != b["sums"].shape:
-            raise ValueError(
-                f"partial-state shape mismatch {a['sums'].shape} vs "
-                f"{b['sums'].shape} (dictionary domain changed)"
-            )
-        inner, _ = groupby_family(q, ds)
-        lowering = self._lowering_for(
-            groupby_with_time_granularity(inner), ds
-        )
-        merged = {
-            "sums": a["sums"] + b["sums"],
-            "mins": np.minimum(a["mins"], b["mins"]),
-            "maxs": np.maximum(a["maxs"], b["maxs"]),
-            "sketches": dict(a["sketches"]),
-        }
-        _merge_sketch_states(lowering.la, merged["sketches"], b["sketches"])
-        merged["sketches"] = {
-            k: np.asarray(v) for k, v in merged["sketches"].items()
-        }
-        return merged
-
-    def finalize_groupby_state(self, q: Q.QuerySpec, ds: DataSource, state):
-        """Host partial state -> the query's final result frame (the same
-        finalize the live mesh execution runs)."""
-        inner, shape = groupby_family(q, ds)
-        inner = groupby_with_time_granularity(inner)
-        lowering = self._lowering_for(inner, ds)
-        with span(SPAN_FINALIZE):
-            df = finalize_groupby(
-                inner, lowering.dims, lowering.la,
-                np.asarray(state["sums"]),
-                np.asarray(state["mins"]),
-                np.asarray(state["maxs"]),
-                {k: np.asarray(v) for k, v in state["sketches"].items()},
-            )
-        return shape(df)
 
     # -- micro-batch fusion on the shared arena ------------------------------
 
@@ -1684,7 +1587,7 @@ class DistributedEngine:
         (df, state, metrics) contract as the local engine's
         execute_fused; an ineligible batch falls back to serial
         per-member execution (state still captured)."""
-        from ..exec.lowering import empty_partials, memo_key
+        from ..exec.lowering import memo_key
         from ..exec.metrics import QueryMetrics
         from ..resilience import checkpoint, fire
 
@@ -1763,8 +1666,8 @@ class DistributedEngine:
                 prof.note_compile(dt, family="arena-spmd-fused")
         # empty-scope members in ONE host fetch before the demux loop
         # (GL204: no per-member device round trips while demuxing)
-        empties = jax.device_get({
-            i: empty_partials(mm[3].la, mm[3].num_groups)
+        empties = to_host({
+            i: PartialState.empty(mm[3].la, mm[3].num_groups)
             for i, mm in enumerate(members)
             if states is None or not member_scopes[i]
         })
@@ -1773,19 +1676,15 @@ class DistributedEngine:
         from ..exec.engine import _bytes_scanned, _row_counts
 
         for i, (q, inner, shape, lowering, segs) in enumerate(members):
-            la, G = lowering.la, lowering.num_groups
             if i in empties:
-                # empty scope: dead-shard identities ARE empty_partials,
+                # empty scope: dead-shard identities ARE the empty state,
                 # but skip the device state entirely when nothing ran
-                sums, mins, maxs, _sk = empties[i]
+                state = empties[i]
             else:
-                sums, mins, maxs, _live = states[i]
-            state = self._pack_state(sums, mins, maxs)
+                state = PartialState(*states[i][:3], {})
             with span(SPAN_FINALIZE, member=i):
                 df = shape(finalize_groupby(
-                    inner, lowering.dims, la,
-                    state["sums"], state["mins"], state["maxs"],
-                    state["sketches"],
+                    inner, lowering.dims, lowering.la, state
                 ))
             try:
                 qt = q.to_druid().get("queryType", type(q).__name__)
@@ -1802,7 +1701,7 @@ class DistributedEngine:
                 rows_scanned=rows,
                 bytes_scanned=_bytes_scanned(segs, lowering.columns),
                 segments=len(segs),
-                num_groups=G,
+                num_groups=lowering.num_groups,
                 # the batch's shared h2d/compile split evenly: ONE
                 # stacked column set moved for all members
                 h2d_bytes=batch_m.h2d_bytes // n,

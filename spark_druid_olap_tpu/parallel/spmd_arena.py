@@ -161,7 +161,7 @@ def _boundary_merge(mesh, tree: str, member_carry):
     """finish_member + the collective merge of one member's carry.
     Returns (sums, mins, maxs, live_count) — live_count is the number of
     shards that folded at least one block (0 => empty scope on every
-    shard; the host substitutes `empty_partials`)."""
+    shard; the host substitutes `PartialState.empty`)."""
     import jax.numpy as jnp
     from jax import lax
 

@@ -35,6 +35,7 @@ from __future__ import annotations
 import threading
 from typing import Dict, FrozenSet, Optional
 
+from ..exec.state import nbytes
 from ..utils.log import get_logger
 from ..utils.lru import CountBudgetCache
 
@@ -44,17 +45,6 @@ log = get_logger("serve.result_cache")
 # state is HOST RAM held per entry; a huge-G state would let 64 cached
 # dashboards pin gigabytes) — the entry degrades to frame-only
 _STATE_BYTES_MAX = 32 << 20
-
-
-def _state_nbytes(state) -> int:
-    if state is None:
-        return 0
-    total = 0
-    for k in ("sums", "mins", "maxs"):
-        total += int(getattr(state[k], "nbytes", 0))
-    for v in state.get("sketches", {}).values():
-        total += int(getattr(v, "nbytes", 0))
-    return total
 
 
 class CacheEntry:
@@ -154,10 +144,10 @@ class ResultCache:
         the execution path produced one (enables delta-aware reuse)."""
         if not self.enabled:
             return
-        if state is not None and _state_nbytes(state) > _STATE_BYTES_MAX:
+        if state is not None and nbytes(state) > _STATE_BYTES_MAX:
             log.info(
                 "partial state too large to retain (%d B); caching the "
-                "frame only", _state_nbytes(state),
+                "frame only", nbytes(state),
             )
             state = None
         self._cache[key] = CacheEntry(

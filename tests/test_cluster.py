@@ -29,6 +29,7 @@ from spark_druid_olap_tpu.cluster import (
     save_assignment,
     WireDecodeError,
 )
+from spark_druid_olap_tpu.exec.state import PartialState
 from spark_druid_olap_tpu.resilience import injector
 
 T0 = int(np.datetime64("2023-01-01", "ms").astype(np.int64))
@@ -99,23 +100,24 @@ def cluster(tmp_path):
 
 def _state(g=5, a=3, m=2, w=8):
     rng = np.random.default_rng(0)
-    return {
-        "sums": rng.random((g, a)),
-        "mins": rng.random((g, m)),
-        "maxs": rng.random((g, m)),
-        "sketches": {"hll$u": rng.integers(0, 255, (g, w)).astype(np.uint8)},
-    }
+    return PartialState(
+        rng.random((g, a)),
+        rng.random((g, m)),
+        rng.random((g, m)),
+        {"hll$u": rng.integers(0, 255, (g, w)).astype(np.uint8)},
+    )
 
 
 def test_wire_roundtrip_preserves_dtype_shape_values():
     st = _state()
     out = decode_state(json.loads(json.dumps(encode_state(st))))
+    assert isinstance(out, PartialState)
     for k in ("sums", "mins", "maxs"):
-        assert out[k].dtype == st[k].dtype
-        assert np.array_equal(out[k], st[k])
-    assert np.array_equal(st["sketches"]["hll$u"], out["sketches"]["hll$u"])
+        assert getattr(out, k).dtype == getattr(st, k).dtype
+        assert np.array_equal(getattr(out, k), getattr(st, k))
+    assert np.array_equal(st.sketches["hll$u"], out.sketches["hll$u"])
     # decoded arrays must be writable: the ⊕ accumulates in place
-    out["sums"][0, 0] = 7.0
+    out.sums[0, 0] = 7.0
 
 
 def test_wire_decode_rejects_torn_and_malformed():

@@ -27,7 +27,7 @@ from spark_druid_olap_tpu.catalog.segment import build_datasource
 from spark_druid_olap_tpu.config import SessionConfig
 from spark_druid_olap_tpu.exec import engine as engine_mod
 from spark_druid_olap_tpu.exec.engine import Engine
-from spark_druid_olap_tpu.exec.finalize import _merge_sketch_states
+from spark_druid_olap_tpu.exec.state import merge
 from spark_druid_olap_tpu.exec.metrics import QueryMetrics, fold_set_metrics
 from spark_druid_olap_tpu.models import aggregations as A
 from spark_druid_olap_tpu.models.dimensions import DimensionSpec
@@ -291,12 +291,13 @@ def test_theta_scope_folds_once_a_batch(table, monkeypatch, rows_per_segment):
     batches = -(-len(ds.segments) // engine_mod.THETA_SEGMENT_UNROLL_MAX)
     eng.execute(q, ds)
     assert eng.last_metrics.theta_folds == batches
-    dims, _la, _G, _s, _mn, _mx, sk = eng._partials_for_query(q, ds)
+    dims = eng._lowering_for(q, ds).dims
+    sk = eng._partials_for_query(q, ds).sketches
     every = np.ones(ROWS, dtype=bool)
     _assert_reference_states(np.asarray(sk["u"]), dims, table, every, K_EST)
     (_, state, m), (_, state_m, m_m) = eng.execute_fused([q, q_m], ds)
-    np.testing.assert_array_equal(state["sketches"]["u"], np.asarray(sk["u"]))
-    _assert_reference_states(state_m["sketches"]["u"], dims, table,
+    np.testing.assert_array_equal(state.sketches["u"], np.asarray(sk["u"]))
+    _assert_reference_states(state_m.sketches["u"], dims, table,
                              table["c"] == "m", K_EST)
     # a fused batch's unroll takes no theta bound: 32 segments a program
     fused = -(-len(ds.segments) // engine_mod.MULTI_SEGMENT_UNROLL_MAX)
@@ -327,16 +328,12 @@ def test_query_without_theta_traces_the_per_segment_program(table):
     @jax.jit
     # graftlint: disable=jit-cache -- one reference program a test, lowered once
     def seg_fn(cols_list):
-        sums = mins = maxs = None
-        sketch_states = {}
+        state = None
         for cols in cols_list:
-            s, mn, mx, sk = engine_mod._segment_partials(lowering, strategy, cols)
+            seg = engine_mod._segment_partials(lowering, strategy, cols)
             with device_scope(SCOPE_CARRY_MERGE):
-                sums = s if sums is None else sums + s
-                mins = mn if mins is None else jnp.minimum(mins, mn)
-                maxs = mx if maxs is None else jnp.maximum(maxs, mx)
-                _merge_sketch_states(lowering.la, sketch_states, sk)
-        return sums, mins, maxs, sketch_states
+                state = merge(lowering.la, state, seg)
+        return state
 
     assert program.lower(cols_list).as_text() == seg_fn.lower(cols_list).as_text()
 
@@ -400,8 +397,11 @@ def test_engine_theta_state_equals_reference(table, k, selector, rows_per_segmen
         aggregations=(A.ThetaSketch("u", "k", size=k), A.DoubleSum("rev", "r")),
         filter=Selector("c", "m") if selector else None,
     )
-    dims, la, G, sums, mins, maxs, sk = Engine()._partials_for_query(q, ds)
-    got = np.asarray(sk["u"])
+    eng = Engine()
+    lowering = eng._lowering_for(q, ds)
+    dims, G = lowering.dims, lowering.num_groups
+    state = eng._partials_for_query(q, ds, lowering=lowering)
+    got = np.asarray(state.sketches["u"])
     assert got.shape == (G, k) and got.dtype == np.uint32
     keep = table["c"] == "m" if selector else np.ones(ROWS, dtype=bool)
     decoded = dims[0].decode(np.arange(G))

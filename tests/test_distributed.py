@@ -697,7 +697,7 @@ def test_unified_matrix_exact_and_fused(unified_dist, unified_ds):
     for (gdf, gst, gm), (wdf, wst, wm) in zip(got, want):
         assert gm.distributed and gm.fused_batch == 3
         for k in ("sums", "mins", "maxs"):
-            np.testing.assert_array_equal(gst[k], wst[k])
+            np.testing.assert_array_equal(getattr(gst, k), getattr(wst, k))
         _frames_identical(
             gdf.reset_index(drop=True), wdf.reset_index(drop=True)
         )
@@ -716,7 +716,9 @@ def test_unified_matrix_result_cache_states(unified_dist, unified_ds):
         eng.execute(q1, unified_ds)
     assert cap_d["state"] is not None
     for k in ("sums", "mins", "maxs"):
-        np.testing.assert_array_equal(cap_d["state"][k], cap_e["state"][k])
+        np.testing.assert_array_equal(
+            getattr(cap_d["state"], k), getattr(cap_e["state"], k)
+        )
 
     uids = [s.uid for s in unified_ds.segments]
     sa, ra = unified_dist.groupby_partials_host(
@@ -725,7 +727,7 @@ def test_unified_matrix_result_cache_states(unified_dist, unified_ds):
     wa, wr = eng.groupby_partials_host(q1, unified_ds, within_uids=uids[:5])
     assert ra == wr
     for k in ("sums", "mins", "maxs"):
-        np.testing.assert_array_equal(sa[k], wa[k])
+        np.testing.assert_array_equal(getattr(sa, k), getattr(wa, k))
     sb, _ = unified_dist.groupby_partials_host(
         q1, unified_ds, within_uids=uids[5:]
     )
@@ -737,6 +739,39 @@ def test_unified_matrix_result_cache_states(unified_dist, unified_ds):
     before = len(unified_dist._spmd_cache)
     unified_dist.groupby_partials_host(q1, unified_ds, within_uids=uids[2:7])
     assert len(unified_dist._spmd_cache) == before
+
+
+def test_mesh_and_local_states_merge_to_the_whole_scope(
+    unified_dist, unified_ds
+):
+    """A state the mesh made over one half of a scope and one the local
+    engine made over the other ⊕ through the ONE `merge_groupby_states`
+    both engines share, either way round, and finalize to the whole
+    scope's answer."""
+    eng = Engine()
+    assert (
+        type(unified_dist).merge_groupby_states
+        is type(eng).merge_groupby_states
+    )
+    assert (
+        type(unified_dist).finalize_groupby_state
+        is type(eng).finalize_groupby_state
+    )
+    q1, _, _ = _unified_queries()
+    uids = [s.uid for s in unified_ds.segments]
+    mesh_half, _ = unified_dist.groupby_partials_host(
+        q1, unified_ds, within_uids=uids[:4]
+    )
+    local_half, _ = eng.groupby_partials_host(
+        q1, unified_ds, within_uids=uids[4:]
+    )
+    want = eng.execute(q1, unified_ds)
+    for a, b in ((mesh_half, local_half), (local_half, mesh_half)):
+        merged = eng.merge_groupby_states(q1, unified_ds, a, b)
+        _frames_identical(
+            unified_dist.finalize_groupby_state(q1, unified_ds, merged),
+            want, key=["d"],
+        )
 
 
 def test_unified_matrix_deadline_partials(unified_dist, unified_ds):

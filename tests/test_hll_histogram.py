@@ -282,7 +282,7 @@ def test_captured_state_keeps_registers_and_its_delta_merge_answers():
                      aggregations=(HyperUnique("u", "k"),))
     with ctx.engine.state_capture() as cap:
         ctx.engine.execute(q, ds)
-    regs = cap["state"]["sketches"]["u"]
+    regs = cap["state"].sketches["u"]
     assert regs.dtype == np.int32 and regs.shape[-1] == 2048
 
     sql = "SELECT d, APPROX_COUNT_DISTINCT(k) AS u FROM hc GROUP BY d ORDER BY d"

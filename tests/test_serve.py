@@ -32,6 +32,7 @@ import pytest
 
 import spark_druid_olap_tpu as sd
 from spark_druid_olap_tpu.config import SessionConfig
+from spark_druid_olap_tpu.exec.state import PartialState
 from spark_druid_olap_tpu.models.wire import query_from_druid
 from spark_druid_olap_tpu.resilience import injector, partial_scope
 from spark_druid_olap_tpu.server import OlapServer
@@ -152,7 +153,7 @@ def test_fused_mixed_batch_is_byte_identical_to_serial():
         # (serving-discipline GL1702)
         assert m.query_id == qid
         assert m.fused_batch == 3
-        assert state is not None and "sums" in state
+        assert isinstance(state, PartialState)
 
 
 def test_fused_concurrent_sql_matches_serial_and_counts():

@@ -122,8 +122,10 @@ def test_engine_registers_equal_the_reference(
     eng = Engine()
     eng.arena_execution = arena
     eng._pipeline.enabled = pipeline
-    dims, la, G, sums, mins, maxs, sk = eng._partials_for_query(q, ds)
-    got = np.asarray(sk["u"])
+    lowering = eng._lowering_for(q, ds)
+    dims, G = lowering.dims, lowering.num_groups
+    state = eng._partials_for_query(q, ds, lowering=lowering)
+    got = np.asarray(state.sketches["u"])
     assert got.shape == (G, 2048) and got.dtype == np.int32
 
     keep = _kept(cols, selector, interval)
