@@ -833,9 +833,12 @@ class TPUOlapContext:
         (the SQL-surface contract) and `QueryMetrics.receipt` hold the
         receipt of the CLOSED trace, the one the trace doc carries —
         built once.  A no-op outside a trace (direct engine use without
-        a context)."""
+        a context).  The answer's row count is `QueryMetrics.result_rows`."""
         from .obs import current_trace
 
+        m = self.last_metrics
+        if m is not None and hasattr(df, "__len__"):
+            m.result_rows = len(df)
         tr = current_trace()
         if tr is not None:
             tr.stamp_receipt_on(metrics=self.last_metrics, frame=df)

@@ -62,7 +62,7 @@ from ..obs import (
     span,
     span_around,
 )
-from ..plan.cost import presence_kernels, shape_kernel
+from ..plan.cost import compact_route, presence_kernels, shape_kernel
 from ..resilience import DeadlineExceeded, checkpoint, current_partial, fire
 from ..utils.log import get_logger
 from .finalize import finalize_groupby
@@ -75,15 +75,6 @@ from .lowering import (
 from .state import PartialState, estimable, sketch_nbytes, to_host
 
 log = get_logger("exec.adaptive")
-
-# Decline compaction when the compacted domain is still bigger than this:
-# past it the dense/one-hot inner gains nothing and phase A was the only
-# cost (one scan), which the decline memo makes one-time.
-ADAPTIVE_MAX_COMPACT_GROUPS = 1 << 17
-
-# ... and when the domain barely shrinks, compaction cannot pay for its
-# extra pass even once.
-ADAPTIVE_MIN_SHRINK = 0.5
 
 def _compare_chain_max() -> int:
     """Most RUNS of consecutive kept codes a dim's remap spells out as
@@ -408,10 +399,12 @@ class AdaptiveDomainMixin:
         return seg_fn
 
     def _adaptive_kept_codes(
-        self, q, ds, lowering: GroupByLowering, segs
+        self, q, ds, lowering: GroupByLowering, segs, cfg
     ) -> Optional[List[np.ndarray]]:
         """Phase A: measure (or recall) per-dim present code sets.  Returns
-        None when compaction should be declined for this query.
+        None when compaction should be declined for this query; where
+        `plan.cost.compact_route` sends it to the highcard route, the
+        engine's `_highcard` memo says so.
 
         The `adaptive_kept` span's own time is the memo lookup, the
         dictionary derivation and the host `nonzero` over the presence
@@ -487,12 +480,13 @@ class AdaptiveDomainMixin:
             Gc = 1
             for kd in kept:
                 Gc *= len(kd)
-            declined = Gc > ADAPTIVE_MAX_COMPACT_GROUPS or (
-                Gc > ADAPTIVE_MIN_SHRINK * lowering.num_groups
+            route = compact_route(
+                Gc, q, lowering, cfg, self._device_cache.budget_bytes
             )
+            declined = route != "adaptive"
             if sp is not None:
                 sp.attrs.update(
-                    source=source, compact_groups=Gc, declined=declined,
+                    source=source, compact_groups=Gc, route=route,
                     remap=[
                         remap_form(kd, d.cardinality)
                         for d, kd in zip(lowering.dims, kept)
@@ -500,10 +494,12 @@ class AdaptiveDomainMixin:
                 )
             if declined:
                 log.info(
-                    "adaptive compaction declined: G'=%d of G=%d",
-                    Gc, lowering.num_groups,
+                    "adaptive compaction declined (%s): G'=%d of G=%d",
+                    route, Gc, lowering.num_groups,
                 )
                 self._adaptive_declined.add(qkey)
+                if route == "highcard":
+                    self._highcard.add(qkey)
                 self._adaptive_kept.pop(qkey, None)
                 return None
             return kept
@@ -521,7 +517,7 @@ class AdaptiveDomainMixin:
         if not segs:
             return None
         try:
-            kept = self._adaptive_kept_codes(q, ds, lowering, segs)
+            kept = self._adaptive_kept_codes(q, ds, lowering, segs, cfg)
         except DeadlineExceeded as err:
             # the deadline expired during phase A: there are no aggregate
             # partials yet (presence counts are not an answer).  With a
@@ -583,7 +579,9 @@ class AdaptiveDomainMixin:
                 self._m.sketch_state_bytes = sketch_nbytes(host)
             t0 = time.perf_counter()
             with span(SPAN_FINALIZE):
-                out = finalize_groupby(q, clow.dims, clow.la, host)
+                out = finalize_groupby(
+                    q, clow.dims, clow.la, host, metrics=self._m
+                )
             if self._m is not None:
                 self._m.finalize_ms = (time.perf_counter() - t0) * 1e3
             return out

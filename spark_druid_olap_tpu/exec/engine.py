@@ -649,6 +649,8 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
         # per-query kept code sets and the decline memo
         self._adaptive_kept: Dict = {}
         self._adaptive_declined: set = set()
+        # queries phase A sent to the highcard route (plan.cost.compact_route)
+        self._highcard: set = set()
         # queries whose distinct-present count overflowed the one-hot slot
         # tier: remembered SLOTS_LADDER rung for the segmented-reduce tier
         # (sparse_exec.fetch_slot_laddered)
@@ -1428,9 +1430,11 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
         # graftlint: disable=checkpoint-coverage -- demux loop: all device states are already fetched; discarding finished answers at expiry would re-pay the whole batch
         for i, (q, inner, shape, lowering, segs) in enumerate(members):
             state = empties_h[i] if acc_h[i] is None else acc_h[i]
+            counted = QueryMetrics()
             with span(SPAN_FINALIZE, member=i):
                 df = shape(finalize_groupby(
-                    inner, lowering.dims, lowering.la, state
+                    inner, lowering.dims, lowering.la, state,
+                    metrics=counted,
                 ))
             try:
                 qt = q.to_druid().get("queryType", type(q).__name__)
@@ -1455,6 +1459,7 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
                 fused_batch=n,
                 program_cache_hit=batch_m.program_cache_hit,
                 theta_folds=theta_folds[i],
+                host_groups=counted.host_groups,
             )
             record_query_metrics(m, "ok")
             out.append((df, state, m))
@@ -1785,15 +1790,15 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
         qkey = memo_key(q, ds)
         # which tier gets the query: adaptive first (it covers sketch
         # aggs too, and repeats skip its presence pass via the kept-set
-        # memo), then sparse, else the dense partials path at `kernel`
+        # memo), then the highcard route where phase A sent the query
+        # there, then sparse, else the dense partials path at `kernel`
         with span(SPAN_ROUTE):
             strategy = strategy or self.strategy
             kernel = concrete_kernel(strategy, lowering.num_groups)
-            try_adaptive = bool(
-                segs
-                and lowered_tier_takes("adaptive", lowering, strategy)
-                and qkey not in self._adaptive_declined
+            eligible = bool(
+                segs and lowered_tier_takes("adaptive", lowering, strategy)
             )
+            try_adaptive = eligible and qkey not in self._adaptive_declined
             try_sparse = bool(
                 segs
                 and lowered_tier_takes("sparse", lowering, strategy)
@@ -1859,7 +1864,13 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
                 )
                 if adaptive_resolve is not None:
                     m.strategy = "adaptive"
-            if adaptive_resolve is None and try_sparse:
+            if adaptive_resolve is None and eligible and qkey in self._highcard:
+                m.strategy = "highcard"
+                dense_state = self._partials_for_query(
+                    q, ds, lowering=lowering, strategy_override=kernel,
+                    segs=segs, span_attrs={"tier": "highcard"},
+                )
+            elif adaptive_resolve is None and try_sparse:
                 m.strategy = "sparse"
                 sparse_resolve = self._dispatch_groupby_sparse(
                     q, ds, lowering, segs
@@ -1990,7 +2001,9 @@ class Engine(AdaptiveDomainMixin, SparseExecMixin, HostStateSurface):
                 frames = []
                 for (sq, sdims), part in zip(targets, parts):
                     with span(SPAN_FINALIZE):
-                        frames.append(finalize_groupby(sq, sdims, la, part))
+                        frames.append(finalize_groupby(
+                            sq, sdims, la, part, metrics=m
+                        ))
                 m.finalize_ms = (_time.perf_counter() - t0) * 1e3
                 return frames if rollup is not None else frames[0]
             except BaseException as err:

@@ -19,6 +19,7 @@ from ..models import aggregations as A
 from ..models import query as Q
 from ..obs import (
     SCOPE_SET_ROLLUP,
+    SPAN_ORDER_LIMIT,
     SPAN_SKETCH_ESTIMATE,
     device_scope,
     span,
@@ -60,9 +61,23 @@ def finalize_timeseries(df, q: Q.TimeseriesQuery, ds: DataSource):
     return df.reset_index(drop=True)
 
 
+def _order_limit_span(df, order_limit):
+    """`order_limit(df)` inside the one `order_limit` span an answer's
+    ORDER BY / LIMIT takes, attrs `rows_in` and `rows_out`."""
+    with span(SPAN_ORDER_LIMIT, rows_in=len(df)) as sp:
+        df = order_limit(df)
+        if sp is not None:
+            sp.attrs["rows_out"] = len(df)
+    return df
+
+
 def finalize_topn(df, q: Q.TopNQuery):
     """Shared TopN ranking, including per-bucket ranking under a non-'all'
-    granularity."""
+    granularity: a topN's ORDER BY and LIMIT."""
+    return _order_limit_span(df, lambda df: _rank_topn(df, q))
+
+
+def _rank_topn(df, q: Q.TopNQuery):
     df = df.sort_values(q.metric, ascending=not q.descending, kind="stable")
     if q.granularity not in ("all", None):
         df = (
@@ -287,13 +302,15 @@ def finalize_groupby(
     la: LoweredAggs,
     state: PartialState,
     slot_gids: Optional[np.ndarray] = None,
+    metrics=None,
 ):
     """Merged host partial state -> result DataFrame (decode, post-aggs,
     having, order/limit) — the broker-side finalization of SURVEY.md §3.3.
 
     `slot_gids` switches to sparse-state layout (ops/sparse_groupby.py):
     arrays are slot-indexed and slot_gids maps slot -> combined gid (-1 =
-    empty slot)."""
+    empty slot).  `metrics` (the request's QueryMetrics) counts the
+    groups decoded here in `host_groups`."""
     import pandas as pd
 
     sums, mins, maxs, sketch_states = state
@@ -313,6 +330,8 @@ def finalize_groupby(
         idx = sel.astype(np.int64)
         empty_group = rows_per_group[sel] == 0
 
+    if metrics is not None:
+        metrics.host_groups += len(sel)
     table: Dict[str, np.ndarray] = {}
     # decode combined gid -> per-dimension codes (row-major order)
     rem = idx
@@ -414,6 +433,10 @@ def apply_limit_spec(df, ls):
     by groupBy finalization and grouping-set combination (api.py); null
     keys (grouping-set rows that aggregate a sort dimension away) order
     last."""
+    return _order_limit_span(df, lambda df: _sort_offset_limit(df, ls))
+
+
+def _sort_offset_limit(df, ls):
     if ls.columns:
         df = df.sort_values(
             [c.dimension for c in ls.columns],

@@ -138,6 +138,12 @@ class QueryMetrics:
     # batch program and theta aggregation (each sorts the batch's rows
     # once); 0 for a query without a theta sketch
     theta_folds: int = 0
+    # the host's share of a groupBy answer: `host_groups`, the groups the
+    # host decoded into the frame it finalizes, before HAVING, ORDER BY and
+    # LIMIT (summed over a request's grouping sets); `result_rows`, the
+    # rows answered.  A LIMIT cuts the one to the other on the host
+    host_groups: int = 0
+    result_rows: int = 0
 
     @property
     def rows_per_sec(self) -> float:
@@ -203,7 +209,7 @@ _SUMMED = (
     "rows_scanned", "bytes_scanned", "segments", "h2d_bytes", "h2d_ms",
     "compile_ms", "device_ms", "finalize_ms", "total_ms", "retries",
     "sketch_state_bytes", "rows_seen", "delta_rows_seen",
-    "collective_bytes", "est_collective_ms", "theta_folds",
+    "collective_bytes", "est_collective_ms", "theta_folds", "host_groups",
 )
 
 

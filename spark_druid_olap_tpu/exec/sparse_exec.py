@@ -349,7 +349,7 @@ class SparseExecMixin:
             df = finalize_groupby(
                 q, lowering.dims, lowering.la,
                 PartialState(host["sums"], host["mins"], host["maxs"], {}),
-                slot_gids=host["gids"],
+                slot_gids=host["gids"], metrics=self._m,
             )
             return df, "ok"
 

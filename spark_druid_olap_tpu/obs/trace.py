@@ -114,6 +114,7 @@ SPAN_POST_PROCESS = "post_process"  # api: host-side shaping of the frame
 SPAN_HTTP_ACCEPT = "http_accept"  # server: accept() -> do_POST's first line
 SPAN_SKETCH_ESTIMATE = "sketch_estimate"  # finalize: sketch states -> estimates
 SPAN_GROUPING_SETS = "grouping_sets"  # api: a CUBE / ROLLUP / subtotals expansion
+SPAN_ORDER_LIMIT = "order_limit"  # finalize: a groupBy answer's ORDER BY / LIMIT
 
 SPAN_NAMES = frozenset(
     {
@@ -162,6 +163,7 @@ SPAN_NAMES = frozenset(
         SPAN_HTTP_ACCEPT,
         SPAN_SKETCH_ESTIMATE,
         SPAN_GROUPING_SETS,
+        SPAN_ORDER_LIMIT,
     }
 )
 

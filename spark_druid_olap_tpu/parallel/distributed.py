@@ -866,7 +866,9 @@ class DistributedEngine(HostStateSurface):
             prof.note_compile(dt, family="dense-state")
         t0 = _time.perf_counter()
         with span(SPAN_FINALIZE):
-            out = finalize_groupby(q, lowering.dims, lowering.la, state)
+            out = finalize_groupby(
+                q, lowering.dims, lowering.la, state, metrics=m
+            )
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
 
@@ -998,7 +1000,7 @@ class DistributedEngine(HostStateSurface):
         out = finalize_groupby(
             q, lowering.dims, lowering.la,
             PartialState(state["sums"], state["mins"], state["maxs"], {}),
-            slot_gids=state["gids"],
+            slot_gids=state["gids"], metrics=m,
         )
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
@@ -1011,11 +1013,13 @@ class DistributedEngine(HostStateSurface):
         program over the compacted lowering (phase B).  Returns None when
         declining — the caller re-routes among the remaining classes."""
         from ..exec.adaptive_exec import (
-            ADAPTIVE_MAX_COMPACT_GROUPS,
-            ADAPTIVE_MIN_SHRINK,
             compacted_lowering,
             filter_derived_kept,
             remap_form,
+        )
+        from ..plan.cost import (
+            ADAPTIVE_MAX_COMPACT_GROUPS,
+            ADAPTIVE_MIN_SHRINK,
         )
 
         # measured kept sets are only valid for the segment set they
@@ -1410,7 +1414,7 @@ class DistributedEngine(HostStateSurface):
             holder["state"] = state
         t0 = _time.perf_counter()
         with span(SPAN_FINALIZE):
-            out = finalize_groupby(q, lowering.dims, la, state)
+            out = finalize_groupby(q, lowering.dims, la, state, metrics=m)
         m.finalize_ms += (_time.perf_counter() - t0) * 1e3
         return out
 
@@ -1682,9 +1686,11 @@ class DistributedEngine(HostStateSurface):
                 state = empties[i]
             else:
                 state = PartialState(*states[i][:3], {})
+            counted = QueryMetrics()
             with span(SPAN_FINALIZE, member=i):
                 df = shape(finalize_groupby(
-                    inner, lowering.dims, lowering.la, state
+                    inner, lowering.dims, lowering.la, state,
+                    metrics=counted,
                 ))
             try:
                 qt = q.to_druid().get("queryType", type(q).__name__)
@@ -1710,6 +1716,7 @@ class DistributedEngine(HostStateSurface):
                 total_ms=elapsed_ms,
                 fused_batch=n,
                 program_cache_hit=batch_m.program_cache_hit,
+                host_groups=counted.host_groups,
             )
             record_query_metrics(mm, "ok")
             out.append((df, state, mm))

@@ -423,6 +423,46 @@ def lowered_tier_takes(tier: str, lowering, strategy: str) -> bool:
 ROLLUP_STATE_SHARE = 16
 
 
+# The adaptive tier (exec/adaptive_exec.py) declines a compacted domain
+# past this many groups: past it the dense/one-hot inner gains nothing and
+# phase A was the only cost (one scan), which the decline memo makes
+# one-time ...
+ADAPTIVE_MAX_COMPACT_GROUPS = 1 << 17
+
+# ... and one that barely shrinks: compaction cannot pay for its extra
+# pass even once.
+ADAPTIVE_MIN_SHRINK = 0.5
+
+
+def compact_route(
+    compact_groups: int, q: Q.GroupByQuery, lowering, cfg: SessionConfig,
+    budget_bytes: int,
+) -> str:
+    """Where phase A's kept count G' sends a query the adaptive tier
+    probed: `"adaptive"` (phase B over the compacted domain), `"highcard"`
+    or `"declined"` (the next tier, or the dense path).
+
+    "highcard" takes G' past ADAPTIVE_MAX_COMPACT_GROUPS: the dense path's
+    scatter over the whole domain and its one fetch of the merged state —
+    one program a query and scope, whatever the kept set, with no remap
+    and no slot ladder (on the v5e at 1.5 M groups and 11 segments 0.47 s
+    against 0.75 s for the scatter at G' behind a LUT remap and ~12 s for
+    the sparse tier's batch at its 2^21 rung; PERF.md section 6).  It needs
+    plain aggregations (a sketch state is [G, registers]) and a dense
+    state under `budget_bytes` / ROLLUP_STATE_SHARE, the share the
+    rollup's union state may hold."""
+    G = lowering.num_groups
+    if compact_groups > ADAPTIVE_MIN_SHRINK * G:
+        return "declined"
+    if compact_groups <= ADAPTIVE_MAX_COMPACT_GROUPS:
+        return "adaptive"
+    if not lowering.la.sketch_aggs and groupby_state_bytes(
+        q, G, cfg
+    ) <= budget_bytes // ROLLUP_STATE_SHARE:
+        return "highcard"
+    return "declined"
+
+
 def _has_rollup(agg) -> bool:
     """Does a coarser group's state of `agg` follow from its children's
     exactly?  Sums and counts add, mins and maxs fold, HLL registers take
@@ -486,6 +526,8 @@ def query_kernel_costs(
         for a in aggs
     )
     dims = getattr(q, "dimensions", ())
+    if isinstance(q, Q.TopNQuery):  # runs as a groupBy by its one dimension
+        dims = (q.dimension,)
     sparse_ok, adaptive_ok = (
         tier_takes(t, t, num_groups, bool(dims), has_sketch)
         for t in ("sparse", "adaptive")

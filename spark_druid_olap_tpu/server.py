@@ -1390,6 +1390,20 @@ class _OlapHTTPServer(ThreadingHTTPServer):
         super().shutdown_request(request)
 
 
+def _arrow_pool_here() -> None:
+    """Make pyarrow's default memory pool (mimalloc) in the thread that
+    builds the server.  pandas' string columns allocate from it; made
+    first inside a request thread, it is left broken when that thread
+    exits, and the next request's first string allocation segfaults (a
+    TPC-H answer's first frame in a process whose ingest built no
+    string column on the host)."""
+    try:
+        import pyarrow
+    except ImportError:  # fault-ok: pandas then holds no arrow strings
+        return
+    pyarrow.default_memory_pool()
+
+
 class OlapServer:
     """Threaded HTTP server over one TPUOlapContext.
 
@@ -1402,6 +1416,7 @@ class OlapServer:
         handler = type("BoundHandler", (_Handler,), {"ctx": ctx})
         self.httpd = _OlapHTTPServer((host, port), handler)
         self._thread: Optional[threading.Thread] = None
+        _arrow_pool_here()
 
     @property
     def port(self) -> int:

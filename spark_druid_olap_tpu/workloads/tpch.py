@@ -14,11 +14,17 @@ the star-schema JSON + functional dependencies declared in the DDL).  Here:
 * customer hangs off orders (`lineitem -> orders -> customer`) — the
   snowflake edge `StarRelationInfo(parent=...)` exists for exactly this.
 * `QUERIES`: Q1 (single-table agg incl. AVG rewrite), Q3 (high-cardinality
-  group by l_orderkey + ORDER BY revenue LIMIT 10 — the sparse-groupby
-  shape), Q5-class (regional supplier volume), Q6 (interval + expression
-  aggregate), Q12-class (shipmode CASE counts).  Q4/Q21-style EXISTS
-  semijoins are out of scope: the planner has no semijoin rewrite (neither
-  does the reference's — those queries fell back to Spark there too).
+  group by l_orderkey + ORDER BY revenue LIMIT 10), Q10-class (group by
+  customer attributes pruned to c_custkey by FDs + ORDER BY revenue LIMIT
+  20), Q5-class (regional supplier volume), Q6 (interval + bound filters
+  into an expression aggregate), Q12-class (shipmode CASE counts), Q7-class
+  (OR-of-ANDs across two branches, EXTRACT(YEAR) over the time column),
+  Q14-class (LIKE inside CASE, a ratio post-aggregation), Q19-class
+  (disjunction of conjunct blocks with metric bounds), Q8-class over the
+  materialized `o_orderdate_year` (`q8`) and over EXTRACT(YEAR FROM
+  o_orderdate) (`q8_extract`).  Q4/Q21-style EXISTS semijoins are out of
+  scope: the planner has no semijoin rewrite (neither does the
+  reference's — those queries fell back to Spark there too).
 * `oracle(tables, name)` computes each result in float64 pandas.
 
 Constants are adapted to this generator's value domains; query *shapes*
@@ -241,9 +247,14 @@ FLAT_DIMS = list(DIM_ATTRS)
 def register(ctx, scale: float = 0.01, seed: int = 13,
              rows_per_segment: int = 1 << 22, tables=None):
     """Register the flat fact (with snowflake star schema) + normalized
-    dims — the reference's orderLineItemPartSupplier DDL analog."""
+    dims — the reference's orderLineItemPartSupplier DDL analog.  The fact
+    is sorted by `l_shipdate` before it is segmented, as Druid partitions
+    by time: a segment then spans a short run of ship dates, and an
+    interval on them prunes whole segments."""
     tables = tables if tables is not None else gen_tables(scale, seed)
     cols, dicts = flat_columns(tables)
+    order = np.argsort(cols["l_shipdate"], kind="stable")
+    cols = {name: values[order] for name, values in cols.items()}
     ctx.register_table(
         "lineitem", cols,
         dimensions=FLAT_DIMS, metrics=FLAT_METRICS,

@@ -619,8 +619,11 @@ def ssb_sf10_shapes():
     of their sets, as the rollup scans them, planned against SF10's
     dictionaries: dimension tables at SF1 already hold every
     attribute value SF10 has (the fact is 4,096 rows), and the model is then
-    asked about SF10's own 60 M rows in 115 segments.  name -> (query,
-    datasource stand-in, G)."""
+    asked about SF10's own 60 M rows in 115 segments.  The `tpch-power`
+    mix's queries are planned at SF 0.01, where every value of a
+    non-key attribute is present, their key dimensions (`l_orderkey`,
+    `c_custkey`) taken at SF10's 15 M orders and 1.5 M customers.
+    name -> (query, datasource stand-in, G)."""
     import types
 
     from spark_druid_olap_tpu.exec.engine import groupby_family
@@ -653,6 +656,37 @@ def ssb_sf10_shapes():
             num_rows=_ROWS_1CHIP, segments=[None] * 115, dicts=ds.dicts
         )
         out[name] = (q, sf10, lower_groupby(q, ds).num_groups)
+    out.update(_tpch_sf10_shapes())
+    return out
+
+
+def _tpch_sf10_shapes():
+    import types
+
+    from spark_druid_olap_tpu.exec.engine import groupby_family
+    from spark_druid_olap_tpu.exec.lowering import lower_groupby
+    from spark_druid_olap_tpu.sql.parser import parse_sql
+    from spark_druid_olap_tpu.workloads import tpch
+
+    cfg = SessionConfig()
+    cfg.prefer_distributed = False
+    ctx = sd.TPUOlapContext(cfg)
+    tpch.register(ctx, scale=0.01, seed=7, rows_per_segment=1 << 16)
+    sf10_keys = {"l_orderkey": 15_000_000 + 1, "c_custkey": 1_500_000 + 1}
+    out = {}
+    with open(os.path.join(_ROOT, "benchmark", "traffic", "tpch-power.json")) as f:
+        for spec in json.load(f)["queries"]:
+            lp, _, _ = parse_sql(spec["sql"])
+            rw = ctx._planner().plan(lp)
+            ds = ctx.catalog.get(rw.datasource)
+            lowering = lower_groupby(groupby_family(rw.query, ds)[0], ds)
+            G = 1
+            for d in lowering.dims:
+                G *= sf10_keys.get(d.spec.dimension, d.cardinality)
+            sf10 = types.SimpleNamespace(
+                num_rows=60_012_150, segments=[None] * 115, dicts=ds.dicts
+            )
+            out[spec["name"]] = (rw.query, sf10, G)
     return out
 
 
@@ -679,8 +713,14 @@ def test_ssb_classes_are_the_cell_files(
     what its cell's file expects (`QueryMetrics.strategy`: the tier, or the
     kernel the dense class runs as), on one chip and on the (4, 1) mesh: a
     re-measured constant that moved one would print "routing differs from
-    the cell's file" in every benchmark run."""
+    the cell's file" in every benchmark run.  `highcard` is what the
+    adaptive class answers by where phase A keeps more groups than the
+    tier's cap (`plan.cost.compact_route`): a count of the data's, not the
+    model's."""
     from spark_druid_olap_tpu.plan.cost import concrete_kernel, route_query
+
+    if expected == "highcard":
+        expected = "adaptive"
 
     cfg = _file_cfg("tpu")
     q, ds, G = ssb_sf10_shapes[name]

@@ -231,3 +231,17 @@ def test_fd_pruning_respects_order_by_and_cube(ctx_tables, frame):
     )
     rw2 = ctx.plan_sql(cube)
     assert rw2.fd_restores == ()
+
+
+def test_lineitem_is_time_sorted_so_q6_prunes_segments(ctx_tables):
+    """`register` sorts lineitem by l_shipdate before segmenting: Q6's one
+    year of ship dates scans fewer segments than the table has."""
+    ctx, _ = ctx_tables
+    ds = ctx.catalog.get("lineitem")
+    ship = np.concatenate([np.asarray(s.time) for s in ds.segments])
+    assert (np.diff(ship[np.asarray(np.concatenate(
+        [np.asarray(s.valid) for s in ds.segments]), dtype=bool)]) >= 0).all()
+    from spark_druid_olap_tpu.exec.engine import segments_in_scope
+
+    scope = segments_in_scope(ctx.plan_sql(tpch.QUERIES["q6"]).query, ds)
+    assert 0 < len(scope) < len(ds.segments)
